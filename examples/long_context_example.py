@@ -136,4 +136,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from ray_lightning_tpu.util import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -89,9 +89,13 @@ class DelayedTPUAccelerator(TPUAccelerator):
 
     def on_train_start(self) -> None:
         if not TPUAccelerator.is_available():
+            import jax
             raise RuntimeError(
-                "DelayedTPUAccelerator: training started but no TPU device "
-                "is visible in this worker process.")
+                "use_tpu=True but no TPU device is visible in the process "
+                "that executes training (jax backend: "
+                f"{jax.default_backend()!r}); refusing to train on another "
+                "backend under a TPU strategy. Drop use_tpu to train on "
+                "this backend.")
 
 
 ACCELERATOR_REGISTRY: Dict[str, Type[Accelerator]] = {}

@@ -245,4 +245,6 @@ def main(argv: Optional[List[str]] = None) -> None:
 
 
 if __name__ == "__main__":
+    from ray_lightning_tpu.util import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -435,7 +435,6 @@ class EpochStatsCallback(Callback):
         self.epoch_times: list = []
         self.peak_memory_mib: list = []
         self._t0 = 0.0
-        self._stats_unavailable_logged = False
 
     def on_train_epoch_start(self, trainer, pl_module) -> None:
         self._t0 = time.perf_counter()
@@ -444,28 +443,18 @@ class EpochStatsCallback(Callback):
         trainer.block_until_ready()
         dt = time.perf_counter() - self._t0
         self.epoch_times.append(dt)
-        peaks = []
-        for d in trainer.devices:
-            try:
-                stats = d.memory_stats()
-                if stats and "peak_bytes_in_use" in stats:
-                    peaks.append(stats["peak_bytes_in_use"] / 2**20)
-            except Exception as exc:  # noqa: BLE001 - cpu has no stats
-                # expected on the CPU backend: note it ONCE per run, not
-                # per device per epoch — the suppressed-exception channel
-                # must stay readable for real failures
-                if not self._stats_unavailable_logged:
-                    from ray_lightning_tpu.reliability import \
-                        log_suppressed
-                    log_suppressed("callbacks.memory_stats", exc,
-                                   f"device {d} exposes no memory stats"
-                                   " (expected on CPU); reported once")
-                    self._stats_unavailable_logged = True
+        # the CPU backend reports no stats (None); a TPU reports
+        # peak_bytes_in_use, and a failure to read it there is a failure
+        stats = [d.memory_stats() for d in trainer.devices]
+        peaks = [s["peak_bytes_in_use"] / 2**20 for s in stats
+                 if s is not None]
         peak = float(np.mean(peaks)) if peaks else 0.0
         self.peak_memory_mib.append(peak)
         if self.print_stats and trainer.global_rank == 0:
+            shown = (f"{peak:.0f} MiB" if peaks else
+                     "n/a (backend reports no memory stats)")
             print(f"Epoch {trainer.current_epoch}: {dt:.2f}s, "  # tl-lint: allow-print — print_stats=True console UI
-                  f"avg peak HBM {peak:.0f} MiB")
+                  f"avg peak HBM {shown}")
 
 
 class EMAWeightAveraging(Callback):

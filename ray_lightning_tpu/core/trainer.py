@@ -429,6 +429,11 @@ class Trainer:
     def _fit_worker(self, module: TpuModule,
                     datamodule: Optional[TpuDataModule],
                     ckpt_path: Optional[str]) -> WorkerOutput:
+        # the delayed-accelerator gate: this runs in the process that
+        # executes (the worker, never a device-less driver), before any
+        # state is built — use_tpu=True with no TPU visible here raises
+        # instead of training on whatever backend jax fell back to
+        self.strategy.accelerator.on_train_start()
         self._attach(module, datamodule)
         self.should_stop = False
         getattr(self.profiler, "reset", lambda: None)()  # per-fit scope

@@ -512,20 +512,18 @@ def default_worker_env(seat: int,
                        per_seat_env: Optional[Callable[[int],
                                                        Dict[str, str]]]
                        = None) -> Dict[str, str]:
-    """Per-replica device/platform env for one spawn seat.
+    """Per-replica env for one spawn seat.
 
-    Each replica process owns its accelerator slice: the default pins
-    single-device CPU execution (the multi-replica win is one dispatch
-    PROCESS per replica, not one replica spanning devices); on a TPU
-    host, pass ``per_seat_env`` to map seats onto device slices (e.g.
-    ``lambda s: {"TPU_VISIBLE_DEVICES": str(s)}``).
+    The default names the seat and nothing else: it chooses no platform
+    and no XLA flags, so a replica process runs on whatever backend the
+    spawning environment selects (a test suite that exports
+    ``JAX_PLATFORMS=cpu`` gets CPU replicas; a TPU host gets TPU ones).
+    A chip belongs to one process at a time: on a TPU host every replica
+    needs its own chip — pass ``per_seat_env`` to map seats onto chips
+    (e.g. ``lambda s: {"TPU_VISIBLE_CHIPS": str(s)}``) — and the driver
+    process must then stay off the device (``docs/serving.md``).
     """
-    env = {
-        "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu"),
-        "XLA_FLAGS": "--xla_force_host_platform_device_count=1 "
-                     "--xla_backend_optimization_level=1",
-        SEAT_ENV_VAR: str(seat),
-    }
+    env = {SEAT_ENV_VAR: str(seat)}
     if per_seat_env is not None:
         env.update(per_seat_env(seat))
     return env

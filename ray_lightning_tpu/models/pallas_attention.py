@@ -25,33 +25,43 @@ PagedAttention (Kwon et al. 2023) with FlashAttention-style tiling
   materialized — the only full-precision K/V in existence is one
   page's worth of VMEM scratch per grid step.
 - **tiled softmax, f32 accumulators** — scores are computed blockwise
-  per page column (MXU dots with ``preferred_element_type=f32``) into
-  a VMEM-resident ``(H, T, max_seq_len)`` logits tile with the per-row
-  block-causal mask (``key_pos <= kv_positions[row, q]``) fused into
-  the same step; the softmax then runs ONCE, exactly, over the
+  per page column into a VMEM-resident ``(T, max_seq_len, Hp)`` f32
+  logits tile (key positions on sublanes, heads on lanes) with the
+  per-row block-causal mask (``key_pos <= kv_positions[row, q]``) fused
+  into the same step; the softmax then runs ONCE, exactly, over the
   completed tile (grid phase 2), and the output accumulates blockwise
   over V page columns in f32. Exact softmax — not the online
-  approximation — is deliberate: it keeps the kernel's math
-  term-for-term identical to the XLA page-native path, which is what
-  lets the serve tests ENFORCE greedy token identity rather than fall
-  back to an agreement gate (see ``docs/serving.md`` for which config
-  gets which contract).
+  approximation — is deliberate: operand roundings, mask and softmax
+  are the XLA page-native path's, so the two differ only by f32
+  summation order (``docs/serving.md`` says what that buys on the CPU
+  tier and on the chip).
+- **heads on the lane axis** — what makes it lower. Mosaic refuses a
+  ``(1, T)`` or ``(1, 1, H, 1)`` block and any dot that batches over a
+  middle axis, so the wrapper views ``q``/arena as ``(..., H*D)`` (free
+  reshapes), positions ride in SMEM beside the page table (scalar
+  prefetch), per-page scales arrive as one lane-padded ``(1, Hp)`` row,
+  and every in-kernel value is 2-D: per-head ``q.k`` is a lane-wise
+  product summed by an f32 one-hot selector dot ``(H*D, Hp)``, and the
+  per-head weight is broadcast back over its lanes by the transposed
+  selector before meeting V. Compiled for a v5e at GPT-2-small shapes by
+  ``tests/test_chip_compile.py``; run on the chip by ``chip_smoke.py``.
 
 Grid: ``(B, 2 * pages_per_slot)`` with the page axis innermost and
 sequential — steps ``0..pp-1`` score K pages, steps ``pp..2pp-1``
 accumulate V pages (the softmax fires on the first output step). The
-logits tile and the ``(H, T, D)`` accumulator live in VMEM scratch and
+logits tile and the ``(T, H*D)`` accumulator live in VMEM scratch and
 persist across the inner grid, exactly the scheme
 ``ops/pallas_flash.py`` uses. VMEM cost per slot is
-``H * T * max_seq_len`` f32 for the tile (a few hundred KB at serving
-shapes) — far under the ~16 MB budget.
+``T * max_seq_len * Hp`` f32 for the tile (0.5 MiB per query row at
+GPT-2-small serving shapes) — under the ~16 MB budget for decode and
+``spec_k + 1`` verify blocks.
 
-On hosts without a TPU the kernel runs under **pallas interpret mode**
-(the same lowering, executed by XLA CPU), which is how the CPU tier-1
-suite pins token identity; wall-clock there is honestly worse than the
-XLA path (interpretation tax), the byte floor is the claim
-(``bench.py`` ``extras["serve"]["pallas"]``, ``docs/performance.md``
-round 12).
+On a backend without a TPU the kernel runs under **pallas interpret
+mode** (the same kernel body, executed by XLA CPU), which is how the
+CPU tier-1 suite pins it; wall-clock there is honestly worse than the
+XLA path (interpretation tax). On a TPU backend it is compiled, always:
+a kernel that cannot lower raises the compiler's message — it never
+runs interpreted there and never gives way to the XLA path.
 
 Engines select this path with ``ServeEngine(...,
 attention_kernel="pallas")`` on top of ``page_native=True`` — see
@@ -66,6 +76,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -75,77 +86,110 @@ _BIG_NEG = float(jnp.finfo(jnp.float32).min)
 
 
 def interpret_default() -> bool:
-    """Run the kernel in pallas interpret mode off-TPU (the CPU tier-1
-    correctness path); compile it for real on TPU backends."""
+    """Interpret mode off-TPU (the CPU tier-1 correctness path); on a TPU
+    backend always False — the kernel compiles or the program raises."""
     return jax.default_backend() != "tpu"
 
 
-def _kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
-            logits_ref, acc_ref, *, page_size: int, pages_per_slot: int,
-            scale: float, compute_dtype):
+def _head_selectors(H: int, D: int):
+    """One-hot maps between the ``H * D`` lane axis (head ``h`` owns
+    lanes ``h*D .. h*D+D-1``) and a head axis padded to a lane multiple:
+    ``sel`` (H*D, Hp) sums each head's lanes into its column, ``sel.T``
+    (Hp, H*D) broadcasts a per-head value back over its lanes. Both are
+    exact under an f32 dot (one non-zero term per output element)."""
+    hp = -(-H // 128) * 128
+    sel = (np.arange(H * D)[:, None] // D
+           == np.arange(hp)[None, :]).astype(np.float32)
+    return jnp.asarray(sel), jnp.asarray(sel.T)
+
+
+def _kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, sel_ref, selt_ref,
+            ks_ref, vs_ref, o_ref, logits_ref, acc_ref, *,
+            page_size: int, pages_per_slot: int, scale: float,
+            compute_dtype):
     """One grid step; see the module docstring for the two-phase plan.
 
+    Heads ride the LANE axis (``H * D`` wide) so every value in the
+    kernel is 2-D and (8, 128)-tileable — Mosaic lowers no dot that
+    batches over a middle axis. Per-head reductions and broadcasts go
+    through the one-hot selectors (:func:`_head_selectors`).
     ``ks_ref``/``vs_ref`` are None on full-precision arenas (the plain
     wrapper below drops them from the signature — pallas passes refs
     positionally).
     """
+    del pt_ref  # consumed by the index maps
+    b = pl.program_id(0)
     j = pl.program_id(1)
     pp = pages_per_slot
     ps = page_size
     T = q_ref.shape[1]
+    hp = sel_ref.shape[1]
+    f32 = jnp.float32
+    hi = jax.lax.Precision.HIGHEST
 
     def load(ref, sref):
-        blk = ref[0]                                     # (ps, H, D)
+        blk = ref[0]                                     # (ps, H*D)
         if sref is None:
-            return blk
+            return blk.astype(f32)
         # kv_dequantize, blockwise: codes (int8) x per-page-per-head
-        # f32 scales -> compute dtype, on VMEM scratch only
-        return (blk.astype(jnp.float32) * sref[0]).astype(compute_dtype)
+        # f32 scales -> compute dtype, on VMEM scratch only. The (1, Hp)
+        # scale row is broadcast over its head's lanes by the selector
+        # (8 identical rows keep the dot sublane-aligned).
+        s_lane = jnp.dot(jnp.broadcast_to(sref[0], (8, hp)), selt_ref[...],
+                         preferred_element_type=f32, precision=hi)[:1]
+        return (blk.astype(f32) * s_lane).astype(compute_dtype).astype(f32)
 
     @pl.when(j < pp)
     def _scores():
-        kb = load(k_ref, ks_ref)
-        qb = q_ref[0]                                    # (T, H, D)
-        s = jax.lax.dot_general(
-            qb, kb, (((2,), (2,)), ((1,), (1,))),
-            preferred_element_type=jnp.float32)          # (H, T, ps)
-        s = s * scale
-        # per-row block-causal mask fused into the score step: page j
-        # covers absolute positions j*ps .. j*ps+ps-1
-        kpos = j * ps + jax.lax.broadcasted_iota(jnp.int32, (T, ps), 1)
-        pos = pos_ref[0]                                 # (T,)
-        bias = jnp.where(kpos <= pos[:, None], 0.0, _BIG_NEG)
-        logits_ref[:, :, pl.ds(j * ps, ps)] = s + bias[None]
+        kb = load(k_ref, ks_ref)                         # (ps, H*D) f32
+        qb = q_ref[0].astype(f32)                        # (T, H*D)
+        # page j covers absolute positions j*ps .. j*ps+ps-1
+        kpos = j * ps + jax.lax.broadcasted_iota(jnp.int32, (ps, hp), 0)
+        col = pl.multiple_of(j * ps, ps)
+        for t in range(T):
+            # per-head q.k: lane-wise products (exact in f32 for bf16
+            # operands), summed per head by the selector dot
+            s = jnp.dot(kb * qb[t:t + 1], sel_ref[...],
+                        preferred_element_type=f32, precision=hi)
+            # per-row block-causal mask fused into the score step
+            bias = jnp.where(kpos <= pos_ref[b, t], 0.0, _BIG_NEG)
+            logits_ref[t, pl.ds(col, ps), :] = s * scale + bias
 
     @pl.when(j == pp)
     def _softmax():
         # the tile is complete: ONE exact f32 softmax over every key
-        # position, term-for-term the XLA page-native path's
-        # jax.nn.softmax — weights overwrite the tile in place
-        lg = logits_ref[:]                               # (H, T, S)
-        w = jax.nn.softmax(lg, axis=-1)
-        all_masked = jnp.all(lg <= _BIG_NEG * 0.5, axis=-1, keepdims=True)
-        logits_ref[:] = jnp.where(all_masked, 0.0, w)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        # position, the XLA page-native path's jax.nn.softmax — weights
+        # overwrite the tile in place (key axis = sublanes here)
+        for t in range(T):
+            lg = logits_ref[t]                           # (S, Hp)
+            m = jnp.max(lg, axis=0, keepdims=True)
+            e = jnp.exp(lg - m)
+            w = e / jnp.sum(e, axis=0, keepdims=True)
+            logits_ref[t] = jnp.where(m <= _BIG_NEG * 0.5, 0.0, w)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
     @pl.when(j >= pp)
     def _accumulate():
-        jj = j - pp
-        vb = load(v_ref, vs_ref)
-        wb = logits_ref[:, :, pl.ds(jj * ps, ps)]        # (H, T, ps) f32
-        acc_ref[:] += jax.lax.dot_general(
-            wb.astype(compute_dtype), vb, (((2,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)          # (H, T, D)
+        vb = load(v_ref, vs_ref)                         # (ps, H*D) f32
+        col = pl.multiple_of((j - pp) * ps, ps)
+        for t in range(T):
+            wb = logits_ref[t, pl.ds(col, ps), :]        # (ps, Hp) f32
+            # weights round to compute dtype before meeting V, as the
+            # XLA path's ``weights.astype(q.dtype)`` does
+            wl = jnp.dot(wb.astype(compute_dtype).astype(f32),
+                         selt_ref[...], preferred_element_type=f32,
+                         precision=hi)                   # (ps, H*D)
+            acc_ref[t:t + 1, :] += jnp.sum(wl * vb, axis=0, keepdims=True)
 
     @pl.when(j == 2 * pp - 1)
     def _emit():
-        o_ref[0] = jnp.moveaxis(acc_ref[:], 0, 1).astype(o_ref.dtype)
+        o_ref[0] = acc_ref[...].astype(o_ref.dtype)
 
 
-def _kernel_plain(pt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, logits_ref,
-                  acc_ref, **kw):
-    _kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, None, None, o_ref,
-            logits_ref, acc_ref, **kw)
+def _kernel_plain(pt_ref, pos_ref, q_ref, k_ref, v_ref, sel_ref, selt_ref,
+                  o_ref, logits_ref, acc_ref, **kw):
+    _kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, sel_ref, selt_ref, None,
+            None, o_ref, logits_ref, acc_ref, **kw)
 
 
 def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
@@ -167,48 +211,60 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
       clamp to page 0; the mask never admits a position without a
       mapped page on any row whose output is consumed).
 
-    Returns (B, T, H, D) in ``q.dtype``, matching the XLA page-native
-    path's output bit-for-bit up to per-block dot scheduling.
+    Returns (B, T, H, D) in ``q.dtype``: the XLA page-native path's
+    output up to f32 summation order inside each score and each V
+    accumulation (same operand roundings, same exact softmax).
     """
     B, T, H, D = q.shape
-    ps = k_pages.shape[1]
+    P, ps = k_pages.shape[0], k_pages.shape[1]
     pp = page_table.shape[1]
+    HD = H * D
     quantized = k_scales is not None
     if interpret is None:
         interpret = interpret_default()
 
     page_table = page_table.astype(jnp.int32)
     kv_positions = kv_positions.astype(jnp.int32)
+    sel, selt = _head_selectors(H, D)
+    hp = sel.shape[1]
 
-    def q_map(b, j, pt):
-        return (b, 0, 0, 0)
+    def row_map(b, j, pt, pos):
+        return (b, 0, 0)
 
-    def pos_map(b, j, pt):
-        return (b, 0)
+    def const_map(b, j, pt, pos):
+        return (0, 0)
 
     # K streams pages during the score phase and parks on its last page
     # through the output phase (an unchanged block index is not
     # re-fetched); V parks on the first output page through the score
     # phase — each occupied page crosses HBM→VMEM once per pass.
-    def k_map(b, j, pt):
+    def k_map(b, j, pt, pos):
         col = jnp.minimum(j, pp - 1)
-        return (jnp.maximum(pt[b, col], 0), 0, 0, 0)
+        return (jnp.maximum(pt[b, col], 0), 0, 0)
 
-    def v_map(b, j, pt):
+    def v_map(b, j, pt, pos):
         col = jnp.maximum(j - pp, 0)
-        return (jnp.maximum(pt[b, col], 0), 0, 0, 0)
+        return (jnp.maximum(pt[b, col], 0), 0, 0)
 
+    # free reshapes: (H, D) are the arena's contiguous minor axes
     in_specs = [
-        pl.BlockSpec((1, T), pos_map),
-        pl.BlockSpec((1, T, H, D), q_map),
-        pl.BlockSpec((1, ps, H, D), k_map),
-        pl.BlockSpec((1, ps, H, D), v_map),
+        pl.BlockSpec((1, T, HD), row_map),
+        pl.BlockSpec((1, ps, HD), k_map),
+        pl.BlockSpec((1, ps, HD), v_map),
+        pl.BlockSpec((HD, hp), const_map),
+        pl.BlockSpec((hp, HD), const_map),
     ]
-    operands = [kv_positions, q, k_pages, v_pages]
+    operands = [q.reshape(B, T, HD), k_pages.reshape(P, ps, HD),
+                v_pages.reshape(P, ps, HD), sel, selt]
     if quantized:
-        in_specs += [pl.BlockSpec((1, 1, H, 1), k_map),
-                     pl.BlockSpec((1, 1, H, 1), v_map)]
-        operands += [k_scales, v_scales]
+        # per-page head scales as one lane-padded row each (P*Hp f32 —
+        # small next to the arena's P*ps*H*D codes)
+        def scale_rows(s):
+            return jnp.pad(s.reshape(P, 1, H), ((0, 0), (0, 0),
+                                                (0, hp - H)))
+        in_specs += [pl.BlockSpec((1, 1, hp), k_map),
+                     pl.BlockSpec((1, 1, hp), v_map)]
+        operands += [scale_rows(k_scales), scale_rows(v_scales)]
         kernel = _kernel
     else:
         kernel = _kernel_plain
@@ -217,18 +273,20 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         compute_dtype=q.dtype)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(B, 2 * pp),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, T, H, D), q_map),
+        out_specs=pl.BlockSpec((1, T, HD), row_map),
         scratch_shapes=[
-            pltpu.VMEM((H, T, pp * ps), jnp.float32),   # logits tile
-            pltpu.VMEM((H, T, D), jnp.float32),         # f32 accumulator
+            pltpu.VMEM((T, pp * ps, hp), jnp.float32),  # logits tile
+            pltpu.VMEM((T, HD), jnp.float32),           # f32 accumulator
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, T, H, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, T, HD), q.dtype),
         interpret=interpret,
-    )(page_table, *operands)
+        name="paged_attention",
+    )(page_table, kv_positions, *operands)
+    return out.reshape(B, T, H, D)

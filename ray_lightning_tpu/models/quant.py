@@ -320,8 +320,8 @@ def matmul_view(qt: "QTensor", transpose: bool = False):
       ``x @ E.T`` over the EMBEDDING's last axis): codes view
       ``(N, K)`` (int4: ``(N, K/2)``), int8 scales ``(1, K)`` (they
       ride the contraction axis — the kernel dequantizes element-wise
-      before the dot, never folds scales into activations, which is
-      what keeps it bitwise the dequantize-then-matmul path), int4
+      before the dot, never folds scales into activations, so its
+      operands are the dequantize-then-matmul path's exactly), int4
       scales ``(N, K/group_size)``.
 
     Returns ``(codes2d, scales2d, K, N)``.

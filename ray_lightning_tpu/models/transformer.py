@@ -80,8 +80,8 @@ class TransformerConfig:
     # VMEM tiles, no dense dequantized weight arena anywhere, so the
     # per-dispatch param byte stream drops to the codes+scales floor).
     # Selected via ServeEngine/ServeClient(matmul_kernel=...); runs
-    # under pallas interpret mode off-TPU, bitwise the "xla" path at
-    # the default tiling (docs/serving.md for the identity contract).
+    # under pallas interpret mode off-TPU, compiled on a TPU
+    # (docs/serving.md for the contract).
     matmul_kernel: str = "xla"       # xla | pallas
     # f32 (default) is the numerically-safe softmax; bf16 halves the
     # (B,H,T,T) score-tensor HBM traffic — +13% measured on the GPT-2
@@ -621,8 +621,8 @@ class MultiHeadAttention(nn.Module):
         if cfg.attention_kernel == "pallas":
             # fused read side: page-table-indexed block loads, int8
             # dequant, masked blockwise scores, exact tiled softmax and
-            # f32 V accumulation in one pallas_call — bitwise-matching
-            # the XLA read below on the CPU interpret tier (pinned by
+            # f32 V accumulation in one pallas_call — the XLA read
+            # below up to f32 summation order (pinned by
             # tests/test_pallas_attention.py)
             from ray_lightning_tpu.models.pallas_attention import (
                 paged_attention)

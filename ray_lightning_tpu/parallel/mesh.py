@@ -24,6 +24,7 @@ tightest ICI loops, matching the standard scaling-book recipe.
 from __future__ import annotations
 
 import dataclasses
+import logging
 import math
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -31,6 +32,8 @@ import jax
 import numpy as np
 from jax.experimental import mesh_utils
 from jax.sharding import Mesh
+
+_log = logging.getLogger(__name__)
 
 DP_AXIS = "dp"
 FSDP_AXIS = "fsdp"
@@ -162,14 +165,20 @@ def build_mesh(spec: MeshSpec,
     if spec.dcn_axes:
         return Mesh(_hybrid_device_array(spec, sizes, use),
                     spec.axis_names)
+    layout = "device-list order (plain reshape)"
+    dev_array = None
     if needed == len(devices) and use[0].platform == "tpu":
         try:
             dev_array = mesh_utils.create_device_mesh(
                 sizes, devices=np.asarray(use))
-        except (ValueError, AssertionError):
-            dev_array = np.asarray(use).reshape(sizes)
-    else:
+            layout = "topology-aware (mesh_utils.create_device_mesh)"
+        except (ValueError, AssertionError) as exc:
+            layout += f" — create_device_mesh refused: {exc}"
+    if dev_array is None:
         dev_array = np.asarray(use).reshape(sizes)
+    _log.info("mesh %s over %d %s device(s): %s",
+              dict(zip(spec.axis_names, sizes)), needed, use[0].platform,
+              layout)
     return Mesh(dev_array, spec.axis_names)
 
 

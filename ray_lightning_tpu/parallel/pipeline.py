@@ -32,9 +32,10 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ray_lightning_tpu._compat import axis_size, shard_map
 
 PP_AXIS_NAME = "pp"
 

@@ -26,9 +26,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ray_lightning_tpu._compat import axis_size, shard_map
 from ray_lightning_tpu.ops.attention import dot_product_attention
 from ray_lightning_tpu.ops.flash_attention import (_BIG_NEG, _block_update,
                                                    _finalize)

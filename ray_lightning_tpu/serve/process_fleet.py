@@ -256,9 +256,12 @@ class ProcessReplicaFleet(ReplicaFleet):
     backend selection guidance.
 
     Extra knobs over the in-process fleet: ``worker_env`` (static env
-    for every replica process, merged over the platform defaults),
-    ``per_seat_env`` (callable mapping a spawn seat to device-pinning
-    env — how a TPU host gives each replica its own chip slice),
+    for every replica process; the default names the seat and chooses
+    no platform — replicas run on the backend the spawning environment
+    selects), ``per_seat_env`` (callable mapping a spawn seat to
+    device-pinning env — on a TPU host every replica needs its own
+    chip, and this driver process must then stay off the device: a
+    chip belongs to one process at a time, ``docs/serving.md``),
     ``submit_timeout`` (seconds one admission RPC may take),
     ``scale_eval_interval`` (autoscaler evaluation cadence, wall
     seconds), ``orphan_grace_s`` (arm driver-death orphan reaping:

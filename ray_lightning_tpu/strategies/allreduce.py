@@ -4,9 +4,7 @@ The reference's ``HorovodRayStrategy`` (``ray_lightning/ray_horovod.py:32-
 183``) is DP where gradient sync is *explicit* — Horovod's
 ``DistributedOptimizer`` all-reduces on ``step()`` rather than DDP hooking
 backward. The TPU-native equivalent keeps that per-rank programming model:
-the step runs under ``shard_map`` (via ``ray_lightning_tpu._compat``, which
-absorbs the experimental→top-level jax migration) so each mesh slot computes
-grads on
+the step runs under ``jax.shard_map`` so each mesh slot computes grads on
 its local batch shard, then explicitly ``lax.pmean``-s them over ``dp``
 before the optimizer update — the direct analog of ``hvd.allreduce``
 lowered to an XLA collective on ICI.
@@ -23,9 +21,9 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import optax
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ray_lightning_tpu._compat import shard_map
 from ray_lightning_tpu.parallel.mesh import DP_AXIS, MeshSpec
 from ray_lightning_tpu.strategies.base import Strategy
 

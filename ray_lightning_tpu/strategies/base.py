@@ -165,9 +165,7 @@ class Strategy:
         if num_processes is None:
             num_processes = 1
         if coordinator_address is not None and num_processes > 1:
-            from ray_lightning_tpu._compat import distributed_is_initialized
-            already = distributed_is_initialized()
-            if not already:
+            if not jax.distributed.is_initialized():
                 jax.distributed.initialize(
                     coordinator_address=coordinator_address,
                     num_processes=num_processes,

@@ -9,12 +9,44 @@ polls executor futures while draining the driver-side callable queue.
 from __future__ import annotations
 
 import io
+import os
 import time
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import numpy as np
 from flax import serialization
+
+
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its
+    directory — the one place this repo decides where compiled programs
+    are kept (entry points call it: ``chip_smoke.py``, ``bench.py``, the
+    CLI, the examples; the library never does on import).
+
+    If ``JAX_COMPILATION_CACHE_DIR`` is set, that directory is used and
+    no other is set in code. If not, the cache lives at one fixed path
+    inside the checkout (``<repo>/.jax_cache``, git-ignored) — never a
+    temp name, pid or timestamp: the path is part of the cache key, so a
+    directory that moves never hits. Either way the choice is exported to
+    the environment so spawned workers share it.
+    """
+    path = os.environ.get(COMPILE_CACHE_ENV)
+    if not path:
+        path = os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            ".jax_cache")
+        os.environ[COMPILE_CACHE_ENV] = path
+    # jax reads the variable only at import; tell the live config too
+    jax.config.update("jax_compilation_cache_dir", path)
+    if "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS" not in os.environ:
+        # jax's default keeps only compiles over 1 s; a cold chip call
+        # pays for dozens of smaller programs too
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
+    return path
 
 
 class Unavailable:

@@ -4,9 +4,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ray_lightning_tpu._compat import shard_map
 from ray_lightning_tpu.ops.attention import dot_product_attention
 from ray_lightning_tpu.ops.flash_attention import flash_attention
 from ray_lightning_tpu.ops.pallas_flash import pallas_flash_attention

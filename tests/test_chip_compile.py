@@ -1,0 +1,171 @@
+"""Ask the chip's compiler before the chip: the Pallas kernels of the
+main path, at the GPT-2-small shapes the serve engine and the train step
+really pass them, compiled for a *described* TPU v5e (nothing attached).
+
+This is the only test file that describes the chip. The topology call
+lives in a module-scoped fixture — never at import, in a ``skipif``, in
+``parametrize`` or in ``conftest.py`` — because only one process may load
+the TPU's library: under xdist every worker imports this file, and only
+the worker that runs it may make the call. Compiles happen in the test's
+own process, with the persistent compile cache off (a described-chip
+executable is written but cannot be read back without a chip).
+
+A pass here is a compiler verdict, not a chip run: nothing executes, so
+it says nothing about results or times (``chip_smoke.py`` does that).
+A kernel the compiler refuses keeps its test as ``xfail(strict=True)`` —
+the day it lowers, the marker must go (none is refused today).
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+# GPT-2-small, the shapes of models/transformer.py's page-native call
+# site and quant.matmul_view: 8 slots, 64 pages of 16 per slot, 12 heads
+# of 64; T = 1 (decode) and spec_k + 1 = 5 (verify)
+B, H, D, PS, PP = 8, 12, 64, 16, 64
+D_MODEL, D_FF = 768, 3072
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compiled_text(fn, *args) -> str:
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _spec(one_chip):
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    return S
+
+
+# --------------------------------------------------------------------- #
+# flash attention (train path): ops/pallas_flash.py fwd + bwd
+# --------------------------------------------------------------------- #
+def test_flash_attention_fwd_bwd_compiles(one_chip):
+    from ray_lightning_tpu.ops.pallas_flash import pallas_flash_attention
+    S = _spec(one_chip)
+    qkv = [S((8, 1024, H, D), jnp.bfloat16)] * 3
+
+    def loss(q, k, v):
+        return pallas_flash_attention(q, k, v, causal=True).astype(
+            jnp.float32).sum()
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), *qkv)
+    # forward kernel + the two backward kernels (dq; dk, dv)
+    assert text.count("tpu_custom_call") >= 3
+
+
+# --------------------------------------------------------------------- #
+# paged attention (serve path): models/pallas_attention.py
+# --------------------------------------------------------------------- #
+@pytest.mark.parametrize("T", [1, 5], ids=["decode", "verify"])
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_paged_attention_compiles(one_chip, kv, T):
+    from ray_lightning_tpu.models.pallas_attention import paged_attention
+    S = _spec(one_chip)
+    P = B * PP
+    quantized = kv == "int8"
+    kd = jnp.int8 if quantized else jnp.bfloat16
+    scales = ([S((P, 1, H, 1), jnp.float32)] * 2 if quantized
+              else [None, None])
+    text = _compiled_text(
+        lambda *a: paged_attention(*a, interpret=False),
+        S((B, T, H, D), jnp.bfloat16), S((P, PS, H, D), kd),
+        S((P, PS, H, D), kd), *scales, S((B, T), jnp.int32),
+        S((B, PP), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+# --------------------------------------------------------------------- #
+# quantized matmul (serve path): models/pallas_matmul.py
+# --------------------------------------------------------------------- #
+def _qtensor(S, shape, bits, group_size=64):
+    """A QTensor of ShapeDtypeStructs laid out as quantize_params does."""
+    from ray_lightning_tpu.models.quant import QTensor
+    if bits == 8:
+        scale = (1,) * (len(shape) - 1) + (shape[-1],)
+        return QTensor(S(shape, jnp.int8), S(scale, jnp.float32), 8, None,
+                       shape, np.float32)
+    packed = shape[:-1] + (shape[-1] // 2,)
+    scale = shape[:-1] + (shape[-1] // group_size, 1)
+    return QTensor(S(packed, jnp.int8), S(scale, jnp.float32), 4,
+                   group_size, shape, np.float32)
+
+
+#: (leaf shape, transpose): the qkv DenseGeneral kernel, mlp up and down,
+#: and the tied LM head at the published and the 128-padded vocab
+_PROJECTIONS = {
+    "qkv": ((D_MODEL, 3, H, D), False),
+    "mlp_up": ((D_MODEL, D_FF), False),
+    "mlp_down": ((D_FF, D_MODEL), False),
+    "tied_head_50257": ((50257, D_MODEL), True),
+    "tied_head_50304": ((50304, D_MODEL), True),
+}
+
+
+@pytest.mark.parametrize("proj", sorted(_PROJECTIONS))
+@pytest.mark.parametrize("bits", [8, 4], ids=["int8", "int4"])
+def test_quantized_matmul_compiles(one_chip, bits, proj):
+    from ray_lightning_tpu.models.pallas_matmul import quantized_matmul
+    S = _spec(one_chip)
+    shape, transpose = _PROJECTIONS[proj]
+    K = shape[-1] if transpose else shape[0]
+    qt = _qtensor(S, shape, bits)
+    # M = 8 decode rows and the 8 x 5 verify block share one tiling rule
+    for M in (B, B * 5):
+        text = _compiled_text(
+            lambda x, q: quantized_matmul(x, q, transpose=transpose,
+                                          interpret=False),
+            S((M, K), jnp.bfloat16), qt)
+        assert "tpu_custom_call" in text
+
+
+# --------------------------------------------------------------------- #
+# the flagship forward the driver compile-checks: __graft_entry__.entry
+# --------------------------------------------------------------------- #
+def test_gpt2_small_forward_compiles_and_fits(one_chip):
+    """GPT-2-small forward at entry()'s shapes, from ``jax.eval_shape``
+    (no concrete params): compiles for one v5e and fits its 16 GB."""
+    from ray_lightning_tpu.models.gpt import gpt2_config
+    from ray_lightning_tpu.models.transformer import TransformerLM
+    cfg = gpt2_config("small", vocab_size=32768, max_seq_len=512,
+                      scan_layers=True)
+    model = TransformerLM(cfg)
+    tokens = jax.ShapeDtypeStruct((4, 512), jnp.int32, sharding=one_chip)
+    abstract = jax.eval_shape(
+        lambda t: model.init(jax.random.PRNGKey(0), t)["params"],
+        jax.ShapeDtypeStruct((4, 512), jnp.int32))
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        abstract)
+    compiled = jax.jit(
+        lambda p, t: model.apply({"params": p}, t)).lower(
+        params, tokens).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert total < 16e9, total

@@ -1,16 +1,17 @@
 """Pallas paged-attention kernel (`attention_kernel="pallas"`).
 
 The load-bearing assertion mirrors the page-native pins in
-``tests/test_paged.py``: under interpret mode on the CPU tier the
-kernel's read side is **bitwise** the XLA page-native math (same
-per-page dots, same fused mask, one exact softmax, same f32
-accumulation order — no online-softmax approximation), so greedy token
-identity vs the page-native engine is ENFORCED at 0 mismatches across
-page sizes, int8 arenas, scanned/unrolled layers, spec compose, crash
-replay, and fleet failover. That is the identity contract every
-f32-compute config gets here; on real-TPU Mosaic lowerings, tile-level
-scheduling may reorder the per-block dots, and the documented fallback
-is the PR 11 teacher-forced-agreement contract (``docs/serving.md``).
+``tests/test_paged.py``: the kernel's read side is the XLA page-native
+math (same operand roundings, same fused mask, one exact softmax, f32
+accumulation — no online-softmax approximation) up to f32 summation
+order: the kernel keeps heads on the lane axis so Mosaic can lower it
+(``tests/test_chip_compile.py``), and its per-head sums run in a
+different order than XLA's einsum. The unit test below holds it to a
+few f32 ulps; greedy token identity vs the page-native engine is
+ENFORCED at 0 mismatches on the pinned nano configs across page sizes,
+int8 arenas, scanned/unrolled layers, spec compose, crash replay, and
+fleet failover (empirical, like the int8 page-native pin — what the
+chip shows is ``chip_smoke.py``'s agreement report, ``docs/serving.md``).
 
 The unit test at the top pins the kernel directly against a jnp
 transcription of ``MultiHeadAttention._page_native_attention``'s read
@@ -65,7 +66,7 @@ def _tokens(out):
 
 
 # --------------------------------------------------------------------- #
-# kernel unit: bitwise vs the XLA page-native read-side math
+# kernel unit: a few f32 ulps of the XLA page-native read-side math
 # --------------------------------------------------------------------- #
 def _xla_read_reference(q, kp, vp, ks, vs, pos, pt):
     """jnp transcription of _page_native_attention's read side."""
@@ -105,11 +106,11 @@ def _xla_read_reference(q, kp, vp, ks, vs, pos, pt):
 @pytest.mark.parametrize("T", [1, 3], ids=["decode", "verify"])
 @pytest.mark.parametrize("quantized", [False, True],
                          ids=["f32", "int8"])
-def test_kernel_bitwise_matches_xla_read_side(T, quantized):
+def test_kernel_matches_xla_read_side(T, quantized):
     """Direct kernel call vs the jnp reference, with unmapped (−1)
     rows, ragged positions, and the spec verify's (B, k+1) block shape
-    — interpret mode must be BITWISE (array_equal, not allclose): the
-    engine identity pins below rest on it."""
+    — equal up to f32 summation order (the engine identity pins below
+    rest on argmax margins being far wider than this)."""
     rng = np.random.default_rng(7)
     B, H, D, P, ps, pp = 3, 4, 32, 10, 4, 8
     q = jnp.asarray(rng.normal(size=(B, T, H, D)), jnp.float32)
@@ -128,7 +129,8 @@ def test_kernel_bitwise_matches_xla_read_side(T, quantized):
         ks = vs = None
     ref = _xla_read_reference(q, kp, vp, ks, vs, pos, pt)
     out = paged_attention(q, kp, vp, ks, vs, pos, pt, interpret=True)
-    assert jnp.array_equal(ref, out)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-6, atol=2e-6)
 
 
 # --------------------------------------------------------------------- #

@@ -3,9 +3,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ray_lightning_tpu._compat import shard_map
 from ray_lightning_tpu.parallel.pipeline import (pipeline_apply,
                                                  split_microbatches)
 
