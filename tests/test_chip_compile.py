@@ -144,6 +144,22 @@ def test_quantized_matmul_compiles(one_chip, bits, proj):
         assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("proj", ["mlp_down", "tied_head_50257"])
+@pytest.mark.parametrize("bits", [8, 4], ids=["int8", "int4"])
+def test_quantized_matmul_k_tiled_compiles(one_chip, bits, proj):
+    """``tile_k < K``: partial dots accumulated in f32 VMEM scratch over
+    the innermost grid axis — the mode no engine default selects."""
+    from ray_lightning_tpu.models.pallas_matmul import quantized_matmul
+    S = _spec(one_chip)
+    shape, transpose = _PROJECTIONS[proj]
+    K = shape[-1] if transpose else shape[0]
+    text = _compiled_text(
+        lambda x, q: quantized_matmul(x, q, transpose=transpose, tile_k=256,
+                                      interpret=False),
+        S((B, K), jnp.bfloat16), _qtensor(S, shape, bits))
+    assert "tpu_custom_call" in text
+
+
 # --------------------------------------------------------------------- #
 # the flagship forward the driver compile-checks: __graft_entry__.entry
 # --------------------------------------------------------------------- #
