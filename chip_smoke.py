@@ -149,6 +149,8 @@ class Phase:
         return self.fields
 
     def __exit__(self, exc_type, exc, tb):
+        if exc is not None and not isinstance(exc, Exception):
+            return False   # an interrupt or exit is not a failed phase
         wall = time.perf_counter() - self.t0
         c1, h1 = self.meter.snapshot()
         stats = self.device.memory_stats()   # None on the CPU backend

@@ -189,21 +189,27 @@ def test_ktiled_accumulation_close_not_contracted():
 
 @pytest.mark.parametrize("transpose", [False, True],
                          ids=["dense", "attend"])
-def test_divisor_poor_output_axis_takes_ragged_final_tile(transpose):
+@pytest.mark.parametrize("bits", [8, 4])
+def test_divisor_poor_output_axis_takes_ragged_final_tile(bits, transpose):
     """An output axis longer than the tile cap with no lane-aligned
     divisor (an unpadded 50257-class vocab) gets a lane-aligned tile
     and a ragged, masked final block — the same derivation the chip
-    compiles (tests/test_chip_compile.py), not one full-width tile."""
+    compiles (tests/test_chip_compile.py), not one full-width tile. A
+    flattened token count over its cap goes ragged the same way."""
     rng = np.random.default_rng(8)
-    N, K = 1031, 32                       # prime > DEFAULT_TILE_N
+    # > DEFAULT_TILE_N with no aligned divisor; the dense int4 leaf's
+    # last axis must still divide into groups
+    N = GS * 263 if bits == 4 and not transpose else 1031
+    M, K = 261, 32                        # M > DEFAULT_TILE_M, prime
     w = jnp.asarray(rng.normal(size=(N, K) if transpose else (K, N)),
                     jnp.float32)
-    x = jnp.asarray(rng.normal(size=(5, K)), jnp.float32)
-    qt = _quantize_leaf_int8(w)
+    x = jnp.asarray(rng.normal(size=(M, K)), jnp.float32)
+    qt = (_quantize_leaf_int8(w) if bits == 8
+          else _quantize_leaf_int4(w, GS))
     deq = qt.dequantize()
     ref = x @ (deq.T if transpose else deq)
     out = jax.jit(lambda x: quantized_matmul(x, qt, transpose=transpose))(x)
-    assert out.shape == (5, N)
+    assert out.shape == (M, N)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
 
