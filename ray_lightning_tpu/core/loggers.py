@@ -112,6 +112,10 @@ class JaxProfilerCallback(Callback):
     starts ``jax.profiler`` at ``start_step`` and stops after
     ``num_steps``, writing a TensorBoard/Perfetto-compatible trace with
     device (MXU/HBM) timelines into ``<root>/profile``. Rank-0 only.
+    With a wall-clock ``Trainer(telemetry=)`` the trainer's ``trainer.*``
+    spans lie on the trace's host plane; ``python tools/trace_report.py
+    --profile <root>/profile`` prints device time by named scope and the
+    idle gaps by those spans (``docs/observability.md``).
     """
 
     def __init__(self, start_step: int = 5, num_steps: int = 3,

@@ -6,11 +6,14 @@ Trainer means owning that seat. Two profilers ship:
 
 - :class:`SimpleProfiler` (``profiler="simple"``): wall-clock per section
   (data wait, step dispatch, validation, callbacks), printed as a table at
-  fit end. Note the XLA async-dispatch caveat: "train_step" measures host
-  dispatch time — the host only blocks here when the device queue is full,
-  which is exactly when the device is the bottleneck, so a large
-  "train_step" share means device-bound and a large "get_train_batch"
-  share means input-bound.
+  fit end. The sections are the seats at which an armed
+  ``Trainer(telemetry=)`` opens its ``trainer.*`` spans, and in wall mode
+  the table reads the handle's clock: one timing system, two views (the
+  operator's table here, spans and profiles in ``obs/``). Note the XLA
+  async-dispatch caveat: "train_step" measures host dispatch time — the
+  host only blocks here when the device queue is full, which is exactly
+  when the device is the bottleneck, so a large "train_step" share means
+  device-bound and a large "get_train_batch" share means input-bound.
 - For device-side traces use
   :class:`ray_lightning_tpu.core.loggers.JaxProfilerCallback`, which
   captures an XLA trace viewable in TensorBoard/Perfetto.
@@ -19,7 +22,7 @@ from __future__ import annotations
 
 import contextlib
 import time
-from typing import Dict, Iterator, Tuple
+from typing import Callable, Dict, Tuple
 
 
 class PassThroughProfiler:
@@ -53,7 +56,10 @@ class SimpleProfiler(PassThroughProfiler):
     trainer resets the records at fit start so a reused Trainer reports
     each run separately)."""
 
-    def __init__(self):
+    def __init__(self, clock: Callable[[], float] = time.perf_counter):
+        #: seconds; the trainer points it at a wall-mode telemetry
+        #: handle's clock so the table and the spans read one clock
+        self.clock = clock
         self._records: Dict[str, Tuple[int, float]] = {}
 
     def reset(self) -> None:
@@ -61,11 +67,11 @@ class SimpleProfiler(PassThroughProfiler):
 
     @contextlib.contextmanager
     def profile(self, name: str):
-        t0 = time.perf_counter()
+        t0 = self.clock()
         try:
             yield
         finally:
-            dt = time.perf_counter() - t0
+            dt = self.clock() - t0
             count, total = self._records.get(name, (0, 0.0))
             self._records[name] = (count + 1, total + dt)
 

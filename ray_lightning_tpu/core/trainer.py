@@ -31,6 +31,7 @@ from flax import serialization
 
 from ray_lightning_tpu import util as _util
 from ray_lightning_tpu.core.callbacks import Callback, ModelCheckpoint
+from ray_lightning_tpu.obs.spans import NULL_SPAN
 from ray_lightning_tpu.reliability import faults as _faults
 from ray_lightning_tpu.reliability import log_suppressed
 from ray_lightning_tpu.parallel import sharding as shardlib
@@ -51,6 +52,19 @@ def _normalize_step_output(out: Any, prev_model_state: Any):
             f"training_step returned a {len(out)}-tuple; expected "
             "loss, (loss, logs) or (loss, logs, model_state)")
     return out, {}, prev_model_state
+
+
+def _spanned(tel, iterable, name: str):
+    """``iterable`` with a ``name`` span around every ``next()`` — the
+    armed trainer's data wait."""
+    it = iter(iterable)
+    while True:
+        with tel.span(name):
+            try:
+                item = next(it)
+            except StopIteration:
+                return
+        yield item
 
 
 class Trainer:
@@ -521,6 +535,8 @@ class Trainer:
 
         tel = self.telemetry
         if tel is not None:
+            if tel.clock is not None and hasattr(self.profiler, "clock"):
+                self.profiler.clock = tel.clock  # one clock, two views
             tel.event("worker.start", rank=self.global_rank,
                       world_size=self.world_size,
                       num_devices=self.num_devices)
@@ -584,10 +600,12 @@ class Trainer:
                 import itertools
                 feed = itertools.islice(iter(train_loader), skip, None)
             t0 = time.perf_counter()
+            batches = self._prefetch(feed, max(0, n_batches - skip))
+            if tel is not None:
+                batches = _spanned(tel, batches, "trainer.get_train_batch")
             for batch_idx, batch in enumerate(
                     self.profiler.profile_iterable(
-                        self._prefetch(feed, max(0, n_batches - skip)),
-                        "get_train_batch"), start=skip):
+                        batches, "get_train_batch"), start=skip):
                 # worker-class chaos sites fire before the step: "stall"
                 # wedges this loop (heartbeats stop, the driver's gang
                 # watchdog must notice), "exit" hard-kills the process
@@ -600,18 +618,23 @@ class Trainer:
                     batch = shardlib.put_global_batch(
                         poison_nan(jax.device_get(batch)),
                         self._batch_sharding)
-                module.on_train_batch_start(batch, batch_idx)
-                for cb in self.callbacks:
-                    cb.on_train_batch_start(self, module, batch, batch_idx)
-                module.on_before_optimizer_step(self._tx)
-                for cb in self.callbacks:
-                    cb.on_before_optimizer_step(self, module, self._tx)
-                with self.profiler.profile("train_step"):
+                with self._span("trainer.batch_hooks", when="start"):
+                    module.on_train_batch_start(batch, batch_idx)
+                    for cb in self.callbacks:
+                        cb.on_train_batch_start(self, module, batch,
+                                                batch_idx)
+                    module.on_before_optimizer_step(self._tx)
+                    for cb in self.callbacks:
+                        cb.on_before_optimizer_step(self, module, self._tx)
+                with self._span("trainer.train_step"), \
+                        self.profiler.profile("train_step"):
                     state, logs = self._train_step(state, batch)
-                if self.nonfinite_action is not None and \
-                        bool(np.asarray(jax.device_get(
-                            logs["nonfinite"]))):
-                    state = self._handle_nonfinite(state)
+                if self.nonfinite_action is not None:
+                    with self._span("trainer.nonfinite_sync"):
+                        nonfinite = bool(np.asarray(jax.device_get(
+                            logs["nonfinite"])))
+                    if nonfinite:
+                        state = self._handle_nonfinite(state)
                 self.train_state = state
                 self.global_step += 1
                 self._batch_in_epoch = batch_idx + 1
@@ -619,18 +642,20 @@ class Trainer:
                     _beat(self.global_step)
                 epoch_logs.append(logs)
                 self._last_logs = logs
-                module.on_train_batch_end(logs, batch, batch_idx)
-                for cb in self.callbacks:
-                    cb.on_train_batch_end(self, module, logs, batch,
-                                          batch_idx)
-                if hasattr(self._launcher, "drain_queue"):
-                    self._launcher.drain_queue()
+                with self._span("trainer.batch_hooks", when="end"):
+                    module.on_train_batch_end(logs, batch, batch_idx)
+                    for cb in self.callbacks:
+                        cb.on_train_batch_end(self, module, logs, batch,
+                                              batch_idx)
+                    if hasattr(self._launcher, "drain_queue"):
+                        self._launcher.drain_queue()
                 if val_every:
                     count = (batch_idx + 1 if isinstance(
                         self.val_check_interval, float)
                         else self.global_step)
                     if count % val_every == 0:
-                        with self.profiler.profile("validation"):
+                        with self._span("trainer.validation"), \
+                                self.profiler.profile("validation"):
                             self._run_validation(val_loader, module)
                 if 0 <= self.max_steps <= self.global_step:
                     stop = True
@@ -669,11 +694,13 @@ class Trainer:
                                                 float)
                                  and n_batches % val_every != 0)
             if run_epoch_val:
-                with self.profiler.profile("validation"):
+                with self._span("trainer.validation"), \
+                        self.profiler.profile("validation"):
                     self._run_validation(val_loader, module)
 
             module.on_train_epoch_end()
-            with self.profiler.profile("epoch_end_callbacks"):
+            with self._span("trainer.epoch_end_callbacks"), \
+                    self.profiler.profile("epoch_end_callbacks"):
                 for cb in self.callbacks:
                     cb.on_train_epoch_end(self, module)
             if tel is not None:
@@ -715,6 +742,13 @@ class Trainer:
         if self.strategy.global_rank == 0:
             self.profiler.describe()
         return self._collect_rank_zero_results()
+
+    def _span(self, name: str, **args: Any):
+        """A ``trainer.*`` host span on the armed handle, the shared
+        no-op context when disarmed. The seats are the profiler's
+        sections (``docs/observability.md``, "Spans")."""
+        tel = self.telemetry
+        return NULL_SPAN if tel is None else tel.span(name, **args)
 
     def _handle_nonfinite(self, state):
         """Apply ``nonfinite_action`` to a step whose loss/grads went

@@ -36,9 +36,10 @@ one channel):
   forwarding: events and metric updates re-emitted verbatim into the
   driver's Telemetry by the fleet (per-replica gauges keep their
   ``replica<id>_`` prefix, stamped worker-side).
-- ``(MSG_SPAN, replica_id, name, ts_us, dur_us, depth, args)`` — one
-  CLOSED worker-side span, stamped on the shared fleet timeline (µs
-  since the driver's epoch). Only shipped when the driver armed
+- ``(MSG_SPAN, replica_id, name, start, end, depth, args)`` — one
+  CLOSED worker-side span, stamped with raw ``time.time()`` readings
+  (the fleet's shared clock; the driver's recorder holds the epoch as
+  its origin). Only shipped when the driver armed
   telemetry at spawn (``forward_spans=True``) — a disarmed fleet's
   workers keep returning no-op spans, the zero-cost contract. The
   driver imports these into its SpanRecorder with the seat tagged
@@ -196,20 +197,21 @@ class _ForwardMetrics:
 
 class _NullSpan:
     def __enter__(self):
-        return self
+        return {}
 
     def __exit__(self, *exc_info):
         return False
 
 
 class _ForwardSpan:
-    """One worker-side REAL span: measures ``[ts, ts+dur]`` on the
-    shared fleet timeline (µs since the driver's epoch — the same
-    origin the worker's request stamps use) and appends the closed span
-    as one ``MSG_SPAN`` message when it exits, so it rides the next
-    turn's flush batch. Depth comes from the façade's own open-span
-    counter (the dispatch loop is single-threaded, LIFO by
-    construction)."""
+    """One worker-side REAL span: measures raw ``[start, end]``
+    readings of ``time.time()`` (the clock the worker's request stamps
+    subtract the driver's epoch from) and appends the closed span as
+    one ``MSG_SPAN`` message when it exits, so it rides the next turn's
+    flush batch. Depth comes from the façade's own open-span counter
+    (the dispatch loop is single-threaded, LIFO by construction). The
+    ``with`` target is the span's argument dict, as
+    ``SpanRecorder.span`` has it."""
 
     __slots__ = ("_tel", "_name", "_args", "_t0", "_depth")
 
@@ -219,20 +221,18 @@ class _ForwardSpan:
         self._name = name
         self._args = args
 
-    def __enter__(self) -> "_ForwardSpan":
+    def __enter__(self) -> Dict[str, Any]:
         self._depth = self._tel._depth
         self._tel._depth += 1
         self._t0 = time.time()
-        return self
+        return self._args
 
     def __exit__(self, *exc_info) -> bool:
         t1 = time.time()
         tel = self._tel
         tel._depth -= 1
-        tel._buf.append((MSG_SPAN, tel._rid, self._name,
-                         (self._t0 - tel._epoch) * 1e6,
-                         (t1 - self._t0) * 1e6, self._depth,
-                         self._args))
+        tel._buf.append((MSG_SPAN, tel._rid, self._name, self._t0, t1,
+                         self._depth, self._args))
         return False
 
 
@@ -245,12 +245,10 @@ class _ForwardTelemetry:
     fleet's workers keep the no-op span, preserving the zero-cost
     contract."""
 
-    def __init__(self, buf: List, rid: int, epoch: float = 0.0,
-                 forward_spans: bool = False):
+    def __init__(self, buf: List, rid: int, forward_spans: bool = False):
         self._buf = buf
         self.metrics = _ForwardMetrics(buf, rid)
         self._rid = rid
-        self._epoch = epoch
         self._forward_spans = forward_spans
         self._depth = 0
 
@@ -334,7 +332,7 @@ class ServeReplicaWorker:
         # — the single-timeline contract the in-process fleet gets from
         # clock_epoch=0.0 on a shared clock callable, kept across a
         # real process boundary by sharing the origin instead
-        self._tel = _ForwardTelemetry(self._buf, -1, epoch=epoch,
+        self._tel = _ForwardTelemetry(self._buf, -1,
                                       forward_spans=forward_spans)
         self.client = ServeClient(model, params, clock=time.time,
                                   clock_epoch=epoch, telemetry=self._tel,

@@ -124,20 +124,26 @@ def sample_logits_rows(logits: jax.Array, keys: jax.Array,
     top_k = jnp.asarray(top_k, jnp.int32)
 
     def rows_greedy():
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope("sample/greedy"):
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
     def rows_sampled(use_topk: bool):
         def row(l, k, t, tk):
-            greedy = jnp.argmax(l).astype(jnp.int32)
-            scaled = l / jnp.where(t > 0, t, 1.0)
+            with jax.named_scope("sample/greedy"):
+                greedy = jnp.argmax(l).astype(jnp.int32)
+            with jax.named_scope("sample/temperature"):
+                scaled = l / jnp.where(t > 0, t, 1.0)
             if use_topk:
-                order = jnp.argsort(-l)
-                ranks = jnp.zeros_like(order).at[order].set(
-                    jnp.arange(l.shape[0], dtype=order.dtype))
-                scaled = jnp.where((tk > 0) & (ranks >= tk),
-                                   jnp.finfo(jnp.float32).min, scaled)
-            sampled = jax.random.categorical(k, scaled).astype(jnp.int32)
-            return jnp.where(t > 0, sampled, greedy)
+                with jax.named_scope("sample/top_k"):
+                    order = jnp.argsort(-l)
+                    ranks = jnp.zeros_like(order).at[order].set(
+                        jnp.arange(l.shape[0], dtype=order.dtype))
+                    scaled = jnp.where((tk > 0) & (ranks >= tk),
+                                       jnp.finfo(jnp.float32).min, scaled)
+            with jax.named_scope("sample/draw"):
+                sampled = jax.random.categorical(
+                    k, scaled).astype(jnp.int32)
+                return jnp.where(t > 0, sampled, greedy)
 
         return jax.vmap(row)(logits, keys, temperature, top_k)
 

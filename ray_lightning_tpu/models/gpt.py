@@ -9,6 +9,7 @@ from __future__ import annotations
 
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
@@ -109,8 +110,10 @@ class GPTModule(TpuModule):
         rngs = {"dropout": rng} if self.cfg.dropout > 0 else None
         logits = model.apply(variables, inputs,
                              deterministic=deterministic, rngs=rngs)
-        loss = jnp.mean(optax.softmax_cross_entropy_with_integer_labels(
-            logits, targets))
+        with jax.named_scope("xent"):
+            loss = jnp.mean(
+                optax.softmax_cross_entropy_with_integer_labels(
+                    logits, targets))
         return loss, logits
 
     def training_step(self, model, variables, batch, rng):

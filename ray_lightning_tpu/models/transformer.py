@@ -478,8 +478,9 @@ class MultiHeadAttention(nn.Module):
             row_write = jax.vmap(
                 lambda c, u, i: jax.lax.dynamic_update_slice(c, u,
                                                              (i, 0, 0)))
-            ck.value = row_write(ck.value, k, start)
-            cv.value = row_write(cv.value, v, start)
+            with jax.named_scope("kv_write"):
+                ck.value = row_write(ck.value, k, start)
+                cv.value = row_write(cv.value, v, start)
             ci.value = ci.value + T
             # per-row, per-query: query q of the block attends keys at
             # positions <= pos[row, q] — block-causal, covering the
@@ -488,8 +489,11 @@ class MultiHeadAttention(nn.Module):
                              big_neg)                           # (B,1,T,S)
             return ck.value, cv.value, mask
         idx = ci.value
-        ck.value = jax.lax.dynamic_update_slice(ck.value, k, (0, idx, 0, 0))
-        cv.value = jax.lax.dynamic_update_slice(cv.value, v, (0, idx, 0, 0))
+        with jax.named_scope("kv_write"):
+            ck.value = jax.lax.dynamic_update_slice(ck.value, k,
+                                                    (0, idx, 0, 0))
+            cv.value = jax.lax.dynamic_update_slice(cv.value, v,
+                                                    (0, idx, 0, 0))
         ci.value = idx + T
         if T == 1:
             mask = jnp.where(key_pos <= idx, 0.0, big_neg)      # (1,1,1,S)
@@ -582,41 +586,42 @@ class MultiHeadAttention(nn.Module):
         # (key <= pos admits each query's own position), exactly like
         # the per-row mode of _decode_cache
         rows = jnp.arange(B)
-        for t in range(T):
-            col = pos[:, t] // ps
-            off = pos[:, t] % ps
-            pidx = jnp.take_along_axis(page_table, col[:, None],
-                                       axis=1)[:, 0]            # (B,)
-            widx = jnp.where(pidx >= 0, pidx, P)   # −1 = dropped write
-            if not quantized:
-                ck.value = ck.value.at[widx, off].set(k[:, t],
-                                                      mode="drop")
-                cv.value = cv.value.at[widx, off].set(v[:, t],
-                                                      mode="drop")
-                continue
-            # int8: read-modify-requantize the one page this token
-            # lands in. NOTE this rounds MORE often than the
-            # dense-gather path (scatter_pages dequantizes once per
-            # dispatch, accumulates every sub-step's writes in full
-            # precision, requantizes once at the end; here each token
-            # round-trips its page immediately, so multi-step dispatches
-            # re-round a page's other entries whenever its absmax
-            # carrier moves) — int8 page-native vs dense-gather token
-            # identity is therefore EMPIRICAL (bounded extra rounding
-            # vs argmax margins, pinned on the test/bench configs incl.
-            # steps_per_dispatch>1), not structural like the
-            # full-precision case
-            g = jnp.clip(pidx, 0, P - 1)
-            for store, scales, new in ((ck, sk, k), (cv, sv, v)):
-                page = kv_dequantize(
-                    jnp.take(store.value, g, axis=0),
-                    jnp.take(scales.value, g, axis=0), new.dtype)
-                page = page.at[rows, off].set(new[:, t])
-                ns = kv_scales(page, (1, 3))
-                store.value = store.value.at[widx].set(
-                    kv_quantize(page, ns), mode="drop")
-                scales.value = scales.value.at[widx].set(ns,
-                                                         mode="drop")
+        with jax.named_scope("kv_write"):
+            for t in range(T):
+                col = pos[:, t] // ps
+                off = pos[:, t] % ps
+                pidx = jnp.take_along_axis(page_table, col[:, None],
+                                           axis=1)[:, 0]            # (B,)
+                widx = jnp.where(pidx >= 0, pidx, P)   # −1 = dropped write
+                if not quantized:
+                    ck.value = ck.value.at[widx, off].set(k[:, t],
+                                                          mode="drop")
+                    cv.value = cv.value.at[widx, off].set(v[:, t],
+                                                          mode="drop")
+                    continue
+                # int8: read-modify-requantize the one page this token
+                # lands in. NOTE this rounds MORE often than the
+                # dense-gather path (scatter_pages dequantizes once per
+                # dispatch, accumulates every sub-step's writes in full
+                # precision, requantizes once at the end; here each token
+                # round-trips its page immediately, so multi-step dispatches
+                # re-round a page's other entries whenever its absmax
+                # carrier moves) — int8 page-native vs dense-gather token
+                # identity is therefore EMPIRICAL (bounded extra rounding
+                # vs argmax margins, pinned on the test/bench configs incl.
+                # steps_per_dispatch>1), not structural like the
+                # full-precision case
+                g = jnp.clip(pidx, 0, P - 1)
+                for store, scales, new in ((ck, sk, k), (cv, sv, v)):
+                    page = kv_dequantize(
+                        jnp.take(store.value, g, axis=0),
+                        jnp.take(scales.value, g, axis=0), new.dtype)
+                    page = page.at[rows, off].set(new[:, t])
+                    ns = kv_scales(page, (1, 3))
+                    store.value = store.value.at[widx].set(
+                        kv_quantize(page, ns), mode="drop")
+                    scales.value = scales.value.at[widx].set(ns,
+                                                             mode="drop")
 
         if cfg.attention_kernel == "pallas":
             # fused read side: page-table-indexed block loads, int8
@@ -642,20 +647,23 @@ class MultiHeadAttention(nn.Module):
                             preferred_element_type=jnp.float32)
             return None, sj
 
-        _, scores = jax.lax.scan(score_block, None, jnp.arange(pp))
-        # (pp, B, H, T, ps) -> (B, H, T, pp*ps): page-major key order
-        # IS absolute position order (column j covers j*ps .. j*ps+ps-1)
-        logits = jnp.moveaxis(scores, 0, 3).reshape(
-            B, cfg.n_heads, T, pp * ps) * scale
-        key_pos = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, pp * ps),
-                                           3)
-        big_neg = jnp.finfo(jnp.float32).min
-        logits = logits + jnp.where(key_pos <= pos[:, None, :, None],
-                                    0.0, big_neg)
-        weights = jax.nn.softmax(logits, axis=-1)
-        all_masked = jnp.all(logits <= big_neg * 0.5, axis=-1,
-                             keepdims=True)
-        weights = jnp.where(all_masked, 0.0, weights).astype(q.dtype)
+        with jax.named_scope("attention/scores"):
+            _, scores = jax.lax.scan(score_block, None, jnp.arange(pp))
+            # (pp, B, H, T, ps) -> (B, H, T, pp*ps): page-major key order
+            # IS absolute position order (column j covers
+            # j*ps .. j*ps+ps-1)
+            logits = jnp.moveaxis(scores, 0, 3).reshape(
+                B, cfg.n_heads, T, pp * ps) * scale
+            key_pos = jax.lax.broadcasted_iota(
+                jnp.int32, (1, 1, 1, pp * ps), 3)
+            big_neg = jnp.finfo(jnp.float32).min
+            logits = logits + jnp.where(key_pos <= pos[:, None, :, None],
+                                        0.0, big_neg)
+        with jax.named_scope("attention/softmax"):
+            weights = jax.nn.softmax(logits, axis=-1)
+            all_masked = jnp.all(logits <= big_neg * 0.5, axis=-1,
+                                 keepdims=True)
+            weights = jnp.where(all_masked, 0.0, weights).astype(q.dtype)
 
         # ---- output accumulated blockwise over V page columns (f32)
         def out_block(acc, j):
@@ -668,9 +676,10 @@ class MultiHeadAttention(nn.Module):
                 "bhqk,bkhd->bqhd", wj, vj,
                 preferred_element_type=jnp.float32), None
 
-        out, _ = jax.lax.scan(out_block,
-                              jnp.zeros((B, T, H, D), jnp.float32),
-                              jnp.arange(pp))
+        with jax.named_scope("attention/context"):
+            out, _ = jax.lax.scan(out_block,
+                                  jnp.zeros((B, T, H, D), jnp.float32),
+                                  jnp.arange(pp))
         return out.astype(q.dtype)
 
 

@@ -36,8 +36,14 @@ around the workload to capture them too::
 Clock contract (shared by bus and spans, mirroring ``ServeClient``):
 ``clock=None`` is the deterministic tick clock — events carry no wall
 time, so the same workload writes a byte-identical JSONL log every run;
-``clock=time.perf_counter`` gives real timestamps. See
-``docs/observability.md`` for the event schema and metric names table.
+``clock=time.perf_counter`` gives real timestamps (spans keep the raw
+reading and also lie in any running ``jax.profiler`` trace). Hand the
+client and the handle the same clock. See ``docs/observability.md`` for
+the clock modes, the event schema and the metric, span and scope tables.
+
+A reader in the same process that was not handed the handle (the
+benchmark's per-layer metric files) finds it with
+:func:`last_telemetry`.
 """
 from __future__ import annotations
 
@@ -64,6 +70,8 @@ class Telemetry:
                  jsonl_path: Optional[str] = None,
                  rotate_bytes: int = 4 << 20,
                  flush_every: int = 256):
+        global _LAST
+        _LAST = self
         self.clock = clock
         self.bus = EventBus(capacity=capacity, clock=clock,
                             jsonl_path=jsonl_path,
@@ -77,6 +85,10 @@ class Telemetry:
             "obs_events_dropped_total",
             help="events evicted from the in-memory ring before being "
                  "read (the JSONL sink, when armed, still has them)").inc
+        self.spans._drop_hook = self.metrics.counter(
+            "obs_spans_dropped_total",
+            help="closed spans evicted from the recorder (capacity "
+                 "reached, oldest first)").inc
 
     # ------------------------------------------------------ conveniences
     def event(self, site: str, /, **payload: Any) -> Event:
@@ -128,6 +140,15 @@ class _Activated:
 
 
 _GLOBAL: Optional[Telemetry] = None
+_LAST: Optional[Telemetry] = None
+
+
+def last_telemetry() -> Optional[Telemetry]:
+    """The handle constructed last in this process (``None`` before the
+    first), held until the next one is built. For in-process readers
+    that sit outside the call chain the handle was threaded through;
+    emission sites never use it."""
+    return _LAST
 
 
 def get_global() -> Optional[Telemetry]:
@@ -161,5 +182,5 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "DEFAULT_LATENCY_BUCKETS", "log_buckets",
     "Span", "SpanRecorder", "NULL_SPAN", "StepStatsCallback",
-    "get_global", "set_global", "emit_global",
+    "get_global", "set_global", "emit_global", "last_telemetry",
 ]

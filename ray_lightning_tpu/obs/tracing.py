@@ -40,8 +40,9 @@ from __future__ import annotations
 import dataclasses
 import json
 import math
-import os
 from typing import Any, Dict, Iterable, List, Optional, Tuple
+
+from ray_lightning_tpu.obs.spans import publish_json
 
 #: canonical decomposition columns, in report order
 SEGMENT_LABELS = ("queue", "prefill", "decode", "sync", "failover")
@@ -264,16 +265,14 @@ def fleet_chrome_trace(telemetry: Any,
     clock (stable sort, no wall time)."""
     if traces is None:
         traces = assemble_request_traces(telemetry.bus.events())
-    # segments are client clock units (ticks or SECONDS); Chrome wants
-    # µs in wall mode. Spans already come in µs (wall) or ticks (tick).
+    # one export, one time axis: spans are raw readings of the handle's
+    # clock, request segments are on the request clock, which reads zero
+    # at the recorder's published origin — both are laid out from there
+    # (µs in wall mode, Chrome's unit; ticks as they are)
+    rec = telemetry.spans
     scale = 1.0 if telemetry.clock is None else 1e6
-    events: List[Dict[str, Any]] = []
-    for s in telemetry.spans.spans():
-        events.append({"name": s.name, "ph": "X", "ts": s.ts,
-                       "dur": s.dur,
-                       "pid": int(s.args.get("seat", 0) or 0),
-                       "tid": int(s.args.get("slot", 0) or 0),
-                       "args": s.args})
+    events: List[Dict[str, Any]] = rec.chrome_events(
+        rec.export_origin(), tracks=True)
     for tr in traces.values():
         for seg in tr.segments:
             events.append({
@@ -295,18 +294,7 @@ def export_fleet_chrome_trace(path: str, telemetry: Any,
     """Atomically publish :func:`fleet_chrome_trace` (tmp +
     ``os.replace``, key-sorted JSON — stable bytes under the tick
     clock); returns ``path``."""
-    doc = fleet_chrome_trace(telemetry, traces)
-    d = os.path.dirname(os.path.abspath(path))
-    os.makedirs(d, exist_ok=True)
-    tmp = f"{path}.tmp-{os.getpid()}"
-    try:
-        with open(tmp, "w") as f:
-            json.dump(doc, f, sort_keys=True)
-        os.replace(tmp, path)
-    finally:
-        if os.path.exists(tmp):
-            os.remove(tmp)
-    return path
+    return publish_json(path, fleet_chrome_trace(telemetry, traces))
 
 
 # -------------------------------------------------------------- reports

@@ -45,27 +45,34 @@ def dot_product_attention(q: jax.Array,
     *_, num_heads, head_dim = q.shape
     del num_heads
     scale = head_dim ** -0.5
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                        preferred_element_type=softmax_dtype) * scale
-    if causal:
-        logits = logits + causal_mask(q.shape[1], k.shape[1],
-                                      dtype=softmax_dtype)
-    if mask is not None:
-        logits = logits + mask.astype(softmax_dtype)
-    weights = jax.nn.softmax(logits.astype(softmax_dtype), axis=-1)
-    if (causal and q.shape[1] > k.shape[1]) or mask is not None:
-        # Fully-masked rows (end-aligned causal with q_len > kv_len, or a
-        # user mask): softmax of all -inf is uniform garbage; emit exactly
-        # 0 instead — the same convention as the flash kernels, so impls
-        # are swappable. Statically impossible when q_len <= kv_len and no
-        # mask is given, so the hot path skips the reduction at trace time.
-        all_masked = jnp.all(logits <= jnp.finfo(softmax_dtype).min * 0.5,
-                             axis=-1, keepdims=True)
-        weights = jnp.where(all_masked, 0.0, weights)
-    weights = weights.astype(q.dtype)
-    if dropout_rate > 0.0 and dropout_rng is not None:
-        keep = jax.random.bernoulli(dropout_rng, 1.0 - dropout_rate,
-                                    weights.shape)
-        weights = jnp.where(keep, weights / (1.0 - dropout_rate), 0.0)
-    return jnp.einsum("bhqk,bkhd->bqhd", weights, v,
-                      preferred_element_type=q.dtype)
+    # the three scopes name the ops of this path in a device profile
+    # (docs/observability.md, "Device scopes"); they add no op
+    with jax.named_scope("attention/scores"):
+        logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                            preferred_element_type=softmax_dtype) * scale
+        if causal:
+            logits = logits + causal_mask(q.shape[1], k.shape[1],
+                                          dtype=softmax_dtype)
+        if mask is not None:
+            logits = logits + mask.astype(softmax_dtype)
+    with jax.named_scope("attention/softmax"):
+        weights = jax.nn.softmax(logits.astype(softmax_dtype), axis=-1)
+        if (causal and q.shape[1] > k.shape[1]) or mask is not None:
+            # Fully-masked rows (end-aligned causal with q_len > kv_len,
+            # or a user mask): softmax of all -inf is uniform garbage;
+            # emit exactly 0 instead — the same convention as the flash
+            # kernels, so impls are swappable. Statically impossible when
+            # q_len <= kv_len and no mask is given, so the hot path skips
+            # the reduction at trace time.
+            all_masked = jnp.all(
+                logits <= jnp.finfo(softmax_dtype).min * 0.5,
+                axis=-1, keepdims=True)
+            weights = jnp.where(all_masked, 0.0, weights)
+        weights = weights.astype(q.dtype)
+        if dropout_rate > 0.0 and dropout_rng is not None:
+            keep = jax.random.bernoulli(dropout_rng, 1.0 - dropout_rate,
+                                        weights.shape)
+            weights = jnp.where(keep, weights / (1.0 - dropout_rate), 0.0)
+    with jax.named_scope("attention/context"):
+        return jnp.einsum("bhqk,bkhd->bqhd", weights, v,
+                          preferred_element_type=q.dtype)

@@ -47,13 +47,15 @@ def lm_head_xent(hidden: jax.Array,
     if hidden.ndim == 3:
         hidden = hidden.reshape(-1, hidden.shape[-1])
         labels = labels.reshape(-1)
-    logits = jax.lax.dot_general(
-        hidden.astype(compute_dtype), embedding.astype(compute_dtype),
-        dimension_numbers=(((1,), (1,)), ((), ())))  # (N, V) bf16
-    lse = jax.scipy.special.logsumexp(logits.astype(jnp.float32), axis=-1)
-    label_logit = jnp.take_along_axis(
-        logits, labels[:, None], axis=-1)[:, 0].astype(jnp.float32)
-    return (lse - label_logit).mean()
+    with jax.named_scope("lm_head_xent"):
+        logits = jax.lax.dot_general(
+            hidden.astype(compute_dtype), embedding.astype(compute_dtype),
+            dimension_numbers=(((1,), (1,)), ((), ())))  # (N, V) bf16
+        lse = jax.scipy.special.logsumexp(logits.astype(jnp.float32),
+                                          axis=-1)
+        label_logit = jnp.take_along_axis(
+            logits, labels[:, None], axis=-1)[:, 0].astype(jnp.float32)
+        return (lse - label_logit).mean()
 
 
 def chunked_lm_head_xent(hidden: jax.Array,
@@ -114,5 +116,7 @@ def chunked_lm_head_xent(hidden: jax.Array,
         x_c, y_c, m_c = inp
         return total + chunk_loss(embedding, x_c, y_c, m_c), None
 
-    total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), (xs, ys, ms))
-    return total / n_tokens
+    with jax.named_scope("lm_head_xent"):
+        total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32),
+                                (xs, ys, ms))
+        return total / n_tokens

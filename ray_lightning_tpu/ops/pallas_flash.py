@@ -256,6 +256,7 @@ def _flash_fwd_impl(q, k, v, causal, block_q, block_k, interpret):
             pltpu.VMEM((bq, D), jnp.float32),   # f32 accumulator
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(qt, kt, vt)
     return out[:, :, :T].transpose(0, 2, 1, 3), lse
 
@@ -297,6 +298,7 @@ def _flash_bwd_impl(q, k, v, out, lse, do, causal, block_q, block_k,
             pltpu.VMEM((bk, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_bwd_dkv",
     )(qt, dot_, lse, delta, kt, vt)
 
     q_spec2 = pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0))
@@ -315,6 +317,7 @@ def _flash_bwd_impl(q, k, v, out, lse, do, causal, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct((B, H, Tp, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(kt, vt, dot_, lse, delta, qt)
 
     dq = dq[:, :, :T].transpose(0, 2, 1, 3)

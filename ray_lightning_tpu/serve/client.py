@@ -39,6 +39,7 @@ import math
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
+from ray_lightning_tpu.obs.spans import NULL_SPAN
 from ray_lightning_tpu.reliability import faults
 from ray_lightning_tpu.reliability.faults import SITE_SERVE_DRIVER
 from ray_lightning_tpu.serve.engine import ServeEngine
@@ -191,7 +192,20 @@ class ServeClient:
             return float(self._ops)
         if self._t0 is None:
             self._t0 = self._clock()
+            if self._tel is not None:
+                # this client zeroes the request clock: tell the span
+                # recorder where, so one export is one time axis
+                self._tel.spans.set_origin(self._t0)
         return self._clock() - self._t0
+
+    def _span(self, name: str, **args: Any):
+        """A host span on the armed handle (tagged with the replica seat
+        under a fleet), the shared no-op context when disarmed."""
+        tel = self._tel
+        if tel is None:
+            return NULL_SPAN
+        return tel.span(name, **args,
+                        **getattr(self.engine, "_span_extra", {}))
 
     # ----------------------------------------------------------- submit
     def submit(self, prompt: Sequence[int], max_new_tokens: int,
@@ -228,6 +242,12 @@ class ServeClient:
         ``replay_tokens``) must ride the request object untouched:
         ``arrival_time`` is only stamped when the request has never been
         admitted anywhere."""
+        if self._tel is None:
+            return self._submit_request(req)
+        with self._span("serve.submit", ids=[req.id]):
+            return self._submit_request(req)
+
+    def _submit_request(self, req: Request) -> int:
         self.engine.validate(req)
         now = self.now()
         tel = self._tel
@@ -370,7 +390,16 @@ class ServeClient:
     # ------------------------------------------------------------- loop
     def tick(self) -> List[Completion]:
         """One scheduling decision + engine dispatch. Returns completions
-        retired by this tick (including deadline expirations)."""
+        retired by this tick (including deadline expirations). Armed, the
+        tick is one root span (``serve.tick``, with the action it took)
+        over a child span for every stretch of host work — the tables in
+        ``docs/observability.md`` name them."""
+        if self._tel is None:
+            return self._tick(None)
+        with self._span("serve.tick") as root:
+            return self._tick(root)
+
+    def _tick(self, root: Optional[Dict[str, Any]]) -> List[Completion]:
         if self._fire_driver_site:
             # the driver-death site (docs/reliability.md): raise mode
             # propagates out of tick() — the deterministic mid-decode
@@ -378,53 +407,57 @@ class ServeClient:
             faults.fire(SITE_SERVE_DRIVER)
         now = self.now()
         done: List[Completion] = []
-        # queued requests past deadline never touch the accelerator — but
-        # a failover re-admission waiting here already streamed tokens on
-        # its dead replica (replay_tokens) and keeps them, plus its
-        # original first-token stamp (the PR 3 partial-tokens contract)
-        for req in self.scheduler.expire(now):
-            done.append(Completion(
-                request_id=req.id, prompt=list(req.prompt),
-                tokens=list(req.replay_tokens or []),
-                finish_reason=FINISH_TIMEOUT,
-                arrival_time=req.arrival_time,
-                first_token_time=req.first_token_time,
-                prefix_hit_tokens=req.prefix_hit_tokens,
-                tenant=req.tenant, adapter=req.adapter))
-        # sync driver: a prior tick's step crashed at its host copy and
-        # parked the handle in the engine's retry seat — drain it before
-        # anything that needs the synced frontier (deadline cancels,
-        # admission), or the loop would wedge behind _require_synced
-        if getattr(self.engine, "retry_pending", False):
-            try:
-                done.extend(self.engine.step())  # retry-drain only:
-                #   the retry branch syncs the parked dispatch without
-                #   enqueueing a new one
-            except Exception:
-                self._finalize(done)
-                raise
-        # in-flight requests past deadline free their slot mid-decode.
-        # With a dispatch in flight, sync it FIRST: the stale host view
-        # can't tell a row the pending dispatch already retired
-        # (eos/budget) from one that really ran out of time — cancelling
-        # on the stale view would flip its finish_reason and drop synced
-        # tokens (pinned: deadline expiry landing between enqueue and
-        # sync, tests/test_async_dispatch.py)
-        if ((self._pending is not None
-             or self._pending_front is not None) and any(
-                r.deadline is not None and now >= r.deadline
-                for r in self.engine.active_requests.values())):
-            try:
-                self._sync_pending(done)
-            except Exception:
-                self._finalize(done)
-                raise
-        for req in list(self.engine.active_requests.values()):
-            if req.deadline is not None and now >= req.deadline:
-                comp = self.engine.cancel(req.id)
-                if comp is not None:
-                    done.append(comp)
-        action, reqs = self.scheduler.next_action(self.engine)
+        with self._span("serve.sweep"):
+            # queued requests past deadline never touch the accelerator — but
+            # a failover re-admission waiting here already streamed tokens on
+            # its dead replica (replay_tokens) and keeps them, plus its
+            # original first-token stamp (the PR 3 partial-tokens contract)
+            for req in self.scheduler.expire(now):
+                done.append(Completion(
+                    request_id=req.id, prompt=list(req.prompt),
+                    tokens=list(req.replay_tokens or []),
+                    finish_reason=FINISH_TIMEOUT,
+                    arrival_time=req.arrival_time,
+                    first_token_time=req.first_token_time,
+                    prefix_hit_tokens=req.prefix_hit_tokens,
+                    tenant=req.tenant, adapter=req.adapter))
+            # sync driver: a prior tick's step crashed at its host copy and
+            # parked the handle in the engine's retry seat — drain it before
+            # anything that needs the synced frontier (deadline cancels,
+            # admission), or the loop would wedge behind _require_synced
+            if getattr(self.engine, "retry_pending", False):
+                try:
+                    done.extend(self.engine.step())  # retry-drain only:
+                    #   the retry branch syncs the parked dispatch without
+                    #   enqueueing a new one
+                except Exception:
+                    self._finalize(done)
+                    raise
+            # in-flight requests past deadline free their slot mid-decode.
+            # With a dispatch in flight, sync it FIRST: the stale host view
+            # can't tell a row the pending dispatch already retired
+            # (eos/budget) from one that really ran out of time — cancelling
+            # on the stale view would flip its finish_reason and drop synced
+            # tokens (pinned: deadline expiry landing between enqueue and
+            # sync, tests/test_async_dispatch.py)
+            if ((self._pending is not None
+                 or self._pending_front is not None) and any(
+                    r.deadline is not None and now >= r.deadline
+                    for r in self.engine.active_requests.values())):
+                try:
+                    self._sync_pending(done)
+                except Exception:
+                    self._finalize(done)
+                    raise
+            for req in list(self.engine.active_requests.values()):
+                if req.deadline is not None and now >= req.deadline:
+                    comp = self.engine.cancel(req.id)
+                    if comp is not None:
+                        done.append(comp)
+        with self._span("scheduler.plan"):
+            action, reqs = self.scheduler.next_action(self.engine)
+        if root is not None:
+            root["action"] = action
         if action == ACTION_PREFILL:
             # defer (don't crash on) requests whose seed collides with an
             # in-flight sample stream — the pool would refuse them at
@@ -482,20 +515,21 @@ class ServeClient:
                 t_first = self.now()
                 chunking = getattr(self.engine, "chunk_pending_ids",
                                    frozenset())
-                for req in admit:
-                    if req.id in chunking:
-                        # chunk-routed: still prefilling, no first token
-                        # yet — stamped by _dispatch_chunk on its final
-                        # chunk
-                        continue
-                    if req.first_token_time is not None:
-                        # failover re-admission of a request that had
-                        # already streamed tokens on its dead replica:
-                        # its first token happened THERE — re-stamping
-                        # would corrupt TTFT across the fleet's shared
-                        # clock
-                        continue
-                    self._stamp_first_token(req, t_first)
+                with self._span("serve.stamp"):
+                    for req in admit:
+                        if req.id in chunking:
+                            # chunk-routed: still prefilling, no first
+                            # token yet — stamped by _dispatch_chunk on
+                            # its final chunk
+                            continue
+                        if req.first_token_time is not None:
+                            # failover re-admission of a request that had
+                            # already streamed tokens on its dead
+                            # replica: its first token happened THERE —
+                            # re-stamping would corrupt TTFT across the
+                            # fleet's shared clock
+                            continue
+                        self._stamp_first_token(req, t_first)
             else:
                 # every popped request was seed-deferred: the tick must
                 # still advance the engine — the conflicting request may
@@ -544,15 +578,20 @@ class ServeClient:
             for req in self.engine.active_requests.values():
                 if req.first_token_time is None and req.id not in chunking:
                     self._stamp_first_token(req, t)
-        self._finalize(done)
-        journal = self._journal
-        if journal is not None:
-            # journal the synced frontier: snapshot_in_flight only ever
-            # reports tokens past step_sync (an in-flight pipelined
-            # dispatch is invisible), so a restart replays exactly what
-            # the driver had observed — never a half-committed step
-            for req, toks in self.engine.snapshot_in_flight():
-                journal.note_frontier(req.id, toks, req.first_token_time)
+        with (self._span("serve.finalize",
+                         ids=[c.request_id for c in done])
+              if self._tel is not None else NULL_SPAN):
+            self._finalize(done)
+            journal = self._journal
+            if journal is not None:
+                # journal the synced frontier: snapshot_in_flight only
+                # ever reports tokens past step_sync (an in-flight
+                # pipelined dispatch is invisible), so a restart replays
+                # exactly what the driver had observed — never a
+                # half-committed step
+                for req, toks in self.engine.snapshot_in_flight():
+                    journal.note_frontier(req.id, toks,
+                                          req.first_token_time)
         return done
 
     def _finalize(self, done: List[Completion]) -> None:

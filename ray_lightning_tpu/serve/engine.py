@@ -169,7 +169,10 @@ class PendingDispatch:
     accepted: object = None    # spec only: (rounds, B) draft credits
     rejected: object = None    # spec only: (rounds, B) real divergences
     asynchronous: bool = True  # False: the sync step() round-trip
-    enqueued_at: float = 0.0   # host perf_counter stamp (overlap metric)
+    # host perf_counter stamp for serve_dispatch_overlap_ms — read only
+    # by an ARMED engine (0.0 otherwise: a disarmed dispatch reads no
+    # clock)
+    enqueued_at: float = 0.0
     # client-clock enqueue stamp (ticks or seconds), set by an ARMED
     # ServeClient only — read back at step_sync to split decode time
     # from reconciliation in request traces (serve.retire `sync`)
@@ -193,20 +196,22 @@ def _advance_rows(model, last, cur, pos, active, remaining, temp, top_k,
     run the same math (static shapes) but their state is frozen: emitted
     is masked to −1 and ``pos``/``stepno`` don't advance.
     """
-    step_keys = _fold_rows(keys, stepno)
+    with jax.named_scope("sample/keys"):
+        step_keys = _fold_rows(keys, stepno)
     nxt = sample_logits_rows(last, step_keys, temp, top_k)
-    # per-row eos (−1 = disabled); done=False — finished rows leave the
-    # batch instead of repeating eos, the pool hands their slot on
-    _, eos_hit = latch_eos(nxt, jnp.zeros_like(active), eos)
-    act_i = active.astype(jnp.int32)
-    remaining = remaining - act_i
-    finished = active & (eos_hit | (remaining <= 0))
-    emitted = jnp.where(active, nxt, -1)
-    max_pos = model.cfg.max_seq_len - 1
-    cur = jnp.where(active[:, None], nxt[:, None], cur)
-    pos = jnp.minimum(pos + act_i[:, None], max_pos)
-    stepno = stepno + act_i
-    active = active & ~finished
+    with jax.named_scope("decode/advance_rows"):
+        # per-row eos (−1 = disabled); done=False — finished rows leave
+        # the batch instead of repeating eos, the pool hands their slot on
+        _, eos_hit = latch_eos(nxt, jnp.zeros_like(active), eos)
+        act_i = active.astype(jnp.int32)
+        remaining = remaining - act_i
+        finished = active & (eos_hit | (remaining <= 0))
+        emitted = jnp.where(active, nxt, -1)
+        max_pos = model.cfg.max_seq_len - 1
+        cur = jnp.where(active[:, None], nxt[:, None], cur)
+        pos = jnp.minimum(pos + act_i[:, None], max_pos)
+        stepno = stepno + act_i
+        active = active & ~finished
     return (cur, pos, active, remaining, stepno, emitted, finished)
 
 
@@ -221,7 +226,9 @@ def _engine_step_core(model, params, cache, cur, pos, active, remaining,
     without an adapter bank — the model never sees the kwarg, so
     unadapted programs are byte-for-byte the pre-LoRA ones.
     """
-    last, cache = decode_step(model, params, cache, cur, pos, adapter_ids)
+    with jax.named_scope("decode/forward"):
+        last, cache = decode_step(model, params, cache, cur, pos,
+                                  adapter_ids)
     (cur, pos, active, remaining, stepno, emitted, finished) = \
         _advance_rows(model, last, cur, pos, active, remaining, temp,
                       top_k, eos, keys, stepno)
@@ -298,9 +305,11 @@ def _prefill_inject_impl(model, params, pool_cache, prompts, lengths,
     storage = pool_cache
     pool_cache = dense_storage_values(model, storage)
     B_pf = prompts.shape[0]
-    pf_cache, last = _prefill_impl(model, params, prompts, lengths,
-                                   adapter_ids)
-    first_keys = _fold_rows(keys, startno)
+    with jax.named_scope("prefill/forward"):
+        pf_cache, last = _prefill_impl(model, params, prompts, lengths,
+                                       adapter_ids)
+    with jax.named_scope("sample/keys"):
+        first_keys = _fold_rows(keys, startno)
     first = sample_logits_rows(last, first_keys, temp, top_k)
 
     # cache leaves: cached_key/cached_value are (B, L, H, D) unrolled or
@@ -316,11 +325,6 @@ def _prefill_inject_impl(model, params, pool_cache, prompts, lengths,
     # pool row. Invalid (padding) rows scatter to a dropped out-of-range
     # index; valid slots are unique (pool invariant), so one gather +
     # select per leaf does the whole injection — no per-row update chain.
-    scatter_idx = jnp.where(valid, slots, num_slots)
-    slot_map = jnp.full((num_slots,), -1, jnp.int32).at[scatter_idx].set(
-        jnp.arange(B_pf, dtype=jnp.int32), mode="drop")
-    keep = slot_map < 0
-
     def inject(pool, pf):
         if pool.ndim < 4:
             return pool
@@ -329,7 +333,13 @@ def _prefill_inject_impl(model, params, pool_cache, prompts, lengths,
         mask_shape[batch_axis] = num_slots
         return jnp.where(keep.reshape(mask_shape), pool, gathered)
 
-    pool_cache = jax.tree_util.tree_map(inject, pool_cache, pf_cache)
+    with jax.named_scope("prefill/kv_inject"):
+        scatter_idx = jnp.where(valid, slots, num_slots)
+        slot_map = jnp.full((num_slots,), -1, jnp.int32).at[
+            scatter_idx].set(jnp.arange(B_pf, dtype=jnp.int32),
+                             mode="drop")
+        keep = slot_map < 0
+        pool_cache = jax.tree_util.tree_map(inject, pool_cache, pf_cache)
     return dense_storage_commit(model, storage, pool_cache), first
 
 
@@ -380,9 +390,11 @@ def _prefill_inject_paged_impl(model, params, arena, prompts, lengths,
     ``max_seq_len`` row (positions ≥ P are zeros), so every mapped page
     is overwritten — stale KV from the pages' previous tenants never
     leaks (the paged analog of the dense whole-row inject)."""
-    pf_cache, last = _prefill_impl(model, params, prompts, lengths,
-                                   adapter_ids)
-    first_keys = _fold_rows(keys, startno)
+    with jax.named_scope("prefill/forward"):
+        pf_cache, last = _prefill_impl(model, params, prompts, lengths,
+                                       adapter_ids)
+    with jax.named_scope("sample/keys"):
+        first_keys = _fold_rows(keys, startno)
     first = sample_logits_rows(last, first_keys, temp, top_k)
     # the prefill cache rows are already the dense per-slot view
     # (B_pf, max_seq_len, …) = (S, pp * page_size, …)
@@ -413,16 +425,20 @@ def _chunk_prefill_impl(model, params, arena, row_pages, tokens, offset,
         lambda leaf: (jnp.full(leaf.shape, offset, leaf.dtype)
                       if leaf.ndim < 4 else leaf), view)
     C = tokens.shape[1]
-    positions = offset + jax.lax.broadcasted_iota(jnp.int32, (1, C), 1)
-    outputs, updated = model.apply(
-        {"params": params, "cache": view}, tokens, positions=positions,
-        deterministic=True, mutable=["cache"], **_adapter_kw(adapter_ids))
-    logits = _logits_only(outputs)                      # (1, C, V)
-    last = jnp.take_along_axis(
-        logits, jnp.reshape(valid_len - 1, (1, 1, 1)).astype(jnp.int32),
-        axis=1)[:, 0]
-    first = sample_logits_rows(last, _fold_rows(keys, startno), temp,
-                               top_k)
+    with jax.named_scope("chunk/forward"):
+        positions = offset + jax.lax.broadcasted_iota(jnp.int32, (1, C), 1)
+        outputs, updated = model.apply(
+            {"params": params, "cache": view}, tokens,
+            positions=positions, deterministic=True, mutable=["cache"],
+            **_adapter_kw(adapter_ids))
+        logits = _logits_only(outputs)                      # (1, C, V)
+        last = jnp.take_along_axis(
+            logits,
+            jnp.reshape(valid_len - 1, (1, 1, 1)).astype(jnp.int32),
+            axis=1)[:, 0]
+    with jax.named_scope("sample/keys"):
+        first_keys = _fold_rows(keys, startno)
+    first = sample_logits_rows(last, first_keys, temp, top_k)
     arena = _scatter_pages(model, arena, updated["cache"], pt)
     return arena, first
 
@@ -451,8 +467,9 @@ def _page_native_step_impl(model, params, arena, page_table, cur, pos,
 
     def body(carry, _):
         arena, cur, pos, active, remaining, stepno = carry
-        last, arena = decode_step_paged(model, params, arena, cur, pos,
-                                        page_table, adapter_ids)
+        with jax.named_scope("decode/forward"):
+            last, arena = decode_step_paged(model, params, arena, cur,
+                                            pos, page_table, adapter_ids)
         (cur, pos, active, remaining, stepno, emitted, finished) = \
             _advance_rows(model, last, cur, pos, active, remaining,
                           temp, top_k, eos, keys, stepno)
@@ -1406,6 +1423,77 @@ class ServeEngine:
         """
         if not requests:
             return []
+        tel = self._tel
+        with (tel.span("engine.prefill.build", **self._span_extra)
+              if tel is not None else NULL_SPAN):
+            built = self._prefill_build(requests)
+        if built is None:
+            return []
+        (batched, prompts, lengths, valid, slots, inject_pt, keys, temp,
+         top_k, startno, adapter_row) = built
+        # None when disarmed: the kwargs guard (_adapter_kw) then keeps
+        # the traced programs byte-for-byte the pre-LoRA ones, and model
+        # families without the adapter_ids kwarg never see it
+        adapter_arg = adapter_row if self._registry is not None else None
+        counts = {}
+        if tel is not None:
+            # counted where the program is dispatched: what it was run
+            # over against what it was asked for
+            counts = dict(
+                ids=[r.id for r in batched], rows=len(batched),
+                tokens=int(lengths[:len(batched)].sum()),
+                program_tokens=self.prefill_batch * self.prefill_len)
+            m = tel.metrics
+            m.counter("serve_prefill_rows_total",
+                      help="requests admitted by batched prefill "
+                      "dispatches").inc(counts["rows"])
+            m.counter("serve_prefill_tokens_total",
+                      help="valid prompt (+ replayed) tokens fed to "
+                      "batched prefill dispatches").inc(counts["tokens"])
+            m.counter("serve_prefill_program_tokens_total",
+                      help="tokens the prefill program was run over: "
+                      "prefill_batch x prefill_len per dispatch"
+                      ).inc(counts["program_tokens"])
+        with (tel.span("engine.prefill.call", **counts, **self._span_extra)
+              if tel is not None else NULL_SPAN):
+            if self.paged:
+                fn = _pick(_prefill_paged_donated, _prefill_paged_plain)
+                self.pool.arena, first = fn(
+                    self.model, self.params, self.pool.arena, prompts,
+                    lengths, inject_pt, keys, temp, top_k, startno,
+                    adapter_arg)
+            else:
+                fn = _pick(_prefill_inject_donated, _prefill_inject_plain)
+                self.pool.cache, first = fn(
+                    self.model, self.params, self.pool.cache, prompts,
+                    lengths, slots, valid, keys, temp, top_k, startno,
+                    adapter_arg)
+        with (tel.span("engine.prefill.sync", **self._span_extra)
+              if tel is not None else NULL_SPAN):
+            first = np.asarray(first)   # THE blocking point of a prefill
+
+        done: List[Completion] = []
+        with (tel.span("engine.prefill.activate", ids=counts["ids"],
+                       **self._span_extra)
+              if tel is not None else NULL_SPAN):
+            if tel is not None:
+                tel.event("engine.prefill", n=len(batched),
+                          ids=counts["ids"],
+                          slots=[int(slots[r])
+                                 for r in range(len(batched))])
+            for r, req in enumerate(batched):
+                comp = self._activate(req, int(slots[r]), int(first[r]),
+                                      keys[r])
+                if comp is not None:
+                    done.append(comp)
+        self.prefills += 1
+        return done
+
+    def _prefill_build(self, requests: List[Request]):
+        """The host half of :meth:`prefill` before the dispatch: atomic
+        admission of the whole batch (slots, pages, adapters, chunk
+        seats) and the program's operand arrays. Returns ``None`` when
+        every request was chunk-routed (nothing to dispatch here)."""
         self._require_synced("prefill")
         faults.fire("serve.dispatch")
         n_batched = sum(not self._routes_chunked(r) for r in requests)
@@ -1521,47 +1609,14 @@ class ServeEngine:
                 ).inc(adopted)
 
         if not batched:
-            return []
+            return None
         # padding rows of the dense path target a real slot but carry
         # valid=False — the inject keeps the pool row, so they write
         # nowhere (paged padding rows are all-(−1) scatter drops)
         for r in range(len(batched), B_pf):
             slots[r] = acquired[0]
-
-        tel = self._tel
-        # None when disarmed: the kwargs guard (_adapter_kw) then keeps
-        # the traced programs byte-for-byte the pre-LoRA ones, and model
-        # families without the adapter_ids kwarg never see it
-        adapter_arg = adapter_row if self._registry is not None else None
-        with (tel.span("engine.prefill", n=len(batched),
-                       **self._span_extra)
-              if tel is not None else NULL_SPAN):
-            if self.paged:
-                fn = _pick(_prefill_paged_donated, _prefill_paged_plain)
-                self.pool.arena, first = fn(
-                    self.model, self.params, self.pool.arena, prompts,
-                    lengths, inject_pt, keys, temp, top_k, startno,
-                    adapter_arg)
-            else:
-                fn = _pick(_prefill_inject_donated, _prefill_inject_plain)
-                self.pool.cache, first = fn(
-                    self.model, self.params, self.pool.cache, prompts,
-                    lengths, slots, valid, keys, temp, top_k, startno,
-                    adapter_arg)
-            first = np.asarray(first)
-        if tel is not None:
-            tel.event("engine.prefill", n=len(batched),
-                      ids=[r.id for r in batched],
-                      slots=[int(slots[r]) for r in range(len(batched))])
-
-        done: List[Completion] = []
-        for r, req in enumerate(batched):
-            comp = self._activate(req, int(slots[r]), int(first[r]),
-                                  keys[r])
-            if comp is not None:
-                done.append(comp)
-        self.prefills += 1
-        return done
+        return (batched, prompts, lengths, valid, slots, inject_pt, keys,
+                temp, top_k, startno, adapter_row)
 
     def prefill_chunk_step(self) -> List[Completion]:
         """One chunk-program dispatch for the head of the chunk queue:
@@ -1595,13 +1650,25 @@ class ServeEngine:
         adapter_arg = (np.array([self._adapter_ids[st.slot]], np.int32)
                        if self._registry is not None else None)
         fn = _pick(_chunk_prefill_donated, _chunk_prefill_plain)
-        with (tel.span("engine.chunk", id=req.id, off=off, n=valid,
-                       slot=st.slot, **self._span_extra)
+        if tel is not None:
+            m = tel.metrics
+            m.counter("serve_chunk_tokens_total",
+                      help="valid prompt tokens fed to chunk-prefill "
+                      "dispatches").inc(valid)
+            m.counter("serve_chunk_program_tokens_total",
+                      help="tokens the chunk program was run over: "
+                      "prefill_chunk per dispatch").inc(C)
+        with (tel.span("engine.chunk.call", ids=[req.id], off=off,
+                       tokens=valid, program_tokens=C, slot=st.slot,
+                       **self._span_extra)
               if tel is not None else NULL_SPAN):
             self.pool.arena, first = fn(
                 self.model, self.params, self.pool.arena, row_pages,
                 tokens, np.int32(off), np.int32(valid), keys, temp,
                 top_k, startno, adapter_arg)
+        with (tel.span("engine.chunk.sync", slot=st.slot,
+                       **self._span_extra)
+              if tel is not None else NULL_SPAN):
             first = np.asarray(first)
         st.next_off = off + valid
         self.chunk_dispatches += 1
@@ -1717,10 +1784,12 @@ class ServeEngine:
         faults.fire("serve.dispatch")
         faults.poison_check(self.pool.active.values())
         tel = self._tel
-        with (tel.span("engine.step", active=int(self._active.sum()),
-                       **self._span_extra)
+        with (tel.span("engine.step.build", **self._span_extra)
               if tel is not None else NULL_SPAN):
             fn, args = self._step_call()
+        with (tel.span("engine.step.call", **self._count_step_rows(tel),
+                       **self._span_extra)
+              if tel is not None else NULL_SPAN):
             (store, cur, pos, active, remaining, stepno, emitted,
              finished) = fn(*args, steps=self.steps_per_dispatch)
             if self.paged:
@@ -1737,8 +1806,31 @@ class ServeEngine:
             kind="step", dispatch=self.steps,
             rounds=self.steps_per_dispatch, emitted=emitted,
             finished=finished, carry=self._carry,
-            owner=self._engine_token,
-            asynchronous=asynchronous, enqueued_at=time.perf_counter())
+            owner=self._engine_token, asynchronous=asynchronous,
+            enqueued_at=self._overlap_stamp(asynchronous))
+
+    def _overlap_stamp(self, asynchronous: bool) -> float:
+        """``PendingDispatch.enqueued_at``: a wall stamp for
+        ``serve_dispatch_overlap_ms`` when armed and pipelined, else 0.0
+        (no clock read)."""
+        if self._tel is None or not asynchronous:
+            return 0.0
+        return time.perf_counter()
+
+    def _count_step_rows(self, tel) -> Dict[str, int]:
+        """Armed only: the rows a step/spec dispatch advances against the
+        rows its program runs over, as span args and registry counters.
+        ``active`` is the synced frontier's view (one dispatch stale
+        under ``async_dispatch``)."""
+        active = int(self._active.sum())
+        m = tel.metrics
+        m.counter("serve_step_rows_total",
+                  help="active rows summed over step dispatches"
+                  ).inc(active)
+        m.counter("serve_step_slots_total",
+                  help="num_slots summed over step dispatches (the rows "
+                  "the step program runs over)").inc(self.num_slots)
+        return {"active": active, "slots": self.num_slots}
 
     def _step_call(self) -> tuple:
         """``(jitted step program, positional operands)`` of the next
@@ -1813,25 +1905,31 @@ class ServeEngine:
                 "once, in enqueue order, and a rebuilt engine's "
                 "outstanding handle must be discarded, not synced")
         tel = self._tel
-        overlap_ms = 1e3 * (time.perf_counter() - pending.enqueued_at)
+        # wall time by definition (docs/observability.md); only an armed
+        # engine's pipelined dispatch reads the clock for it
+        overlap_ms = (1e3 * (time.perf_counter() - pending.enqueued_at)
+                      if pending.enqueued_at else 0.0)
         # materialize EVERY fallible host copy into locals first: a
         # device error surfacing here must leave the engine untouched —
         # the caller keeps the handle and can retry this same sync (or
         # hit the loud out-of-order guard), instead of resuming past a
         # dispatch whose tokens were silently skipped
-        cur, pos, active, remaining, stepno = pending.carry
-        # np.array (copy): jax outputs view as read-only buffers, and
-        # the next prefill writes these rows in place
-        cur = np.array(cur)
-        pos = np.array(pos)
-        active = np.array(active)
-        remaining = np.array(remaining)
-        stepno = np.array(stepno)
-        emitted = np.asarray(pending.emitted)  # (steps, B), −1 = parked
-        finished = np.asarray(pending.finished)  # (steps, B)
-        if pending.kind == "spec":
-            accepted = np.asarray(pending.accepted)   # (rounds, B)
-            rejected = np.asarray(pending.rejected)   # (rounds, B)
+        with (tel.span("engine.step.sync", dispatch=pending.dispatch,
+                       **self._span_extra)
+              if tel is not None else NULL_SPAN):
+            cur, pos, active, remaining, stepno = pending.carry
+            # np.array (copy): jax outputs view as read-only buffers, and
+            # the next prefill writes these rows in place
+            cur = np.array(cur)
+            pos = np.array(pos)
+            active = np.array(active)
+            remaining = np.array(remaining)
+            stepno = np.array(stepno)
+            emitted = np.asarray(pending.emitted)  # (steps, B), −1 = parked
+            finished = np.asarray(pending.finished)  # (steps, B)
+            if pending.kind == "spec":
+                accepted = np.asarray(pending.accepted)   # (rounds, B)
+                rejected = np.asarray(pending.rejected)   # (rounds, B)
         # ---- commit point: everything below is host-side bookkeeping
         self._synced_dispatch = pending.dispatch
         self._cur, self._pos, self._active = cur, pos, active
@@ -1840,10 +1938,24 @@ class ServeEngine:
             # frontier caught up with the newest enqueue — barrier
             # dispatches may run again
             self._carry = None
-        if pending.kind == "spec":
-            return self._sync_spec(pending, emitted, accepted, rejected,
-                                   finished, overlap_ms)
+        with (tel.span("engine.step.retire", **self._span_extra)
+              if tel is not None else NULL_SPAN) as opened:
+            if pending.kind == "spec":
+                done = self._sync_spec(pending, emitted, accepted,
+                                       rejected, finished, overlap_ms)
+            else:
+                done = self._retire_rows(pending, emitted, finished,
+                                         overlap_ms)
+            if tel is not None:
+                opened["ids"] = [c.request_id for c in done]
+        return done
 
+    def _retire_rows(self, pending: PendingDispatch, emitted, finished,
+                     overlap_ms: float) -> List[Completion]:
+        """The plain-step half of :meth:`step_sync` below its commit
+        point: commit each slot's tokens, retire the rows that finished,
+        emit the dispatch's telemetry."""
+        tel = self._tel
         done: List[Completion] = []
         for slot in range(self.num_slots):
             toks = [int(t) for t in emitted[:, slot] if t >= 0]
@@ -1893,21 +2005,24 @@ class ServeEngine:
         faults.fire("serve.dispatch")
         faults.poison_check(self.pool.active.values())
         spec = self.spec
-        active_req = self.pool.active
-        for slot in spec.stale:
-            req = active_req.get(slot)
-            if req is None or not self._active[slot]:
-                spec.discard(slot)
-                continue
-            # draft KV must cover 0..pos-1: full context minus the
-            # current token (which the first draft feed supplies)
-            spec.refill(slot, list(req.prompt) + self._tokens[slot][:-1])
-        faults.fire("serve.verify")
         tel = self._tel
-        k, rounds = spec.k, self.steps_per_dispatch
-        cur, pos, act, remaining, stepno = self._carry_in()
-        with (tel.span("engine.spec_round", active=int(self._active.sum()),
-                       k=k, **self._span_extra)
+        with (tel.span("engine.spec.build", **self._span_extra)
+              if tel is not None else NULL_SPAN):
+            active_req = self.pool.active
+            for slot in spec.stale:
+                req = active_req.get(slot)
+                if req is None or not self._active[slot]:
+                    spec.discard(slot)
+                    continue
+                # draft KV must cover 0..pos-1: full context minus the
+                # current token (which the first draft feed supplies)
+                spec.refill(slot,
+                            list(req.prompt) + self._tokens[slot][:-1])
+            faults.fire("serve.verify")
+            k, rounds = spec.k, self.steps_per_dispatch
+            cur, pos, act, remaining, stepno = self._carry_in()
+        with (tel.span("engine.spec.call", k=k,
+                       **self._count_step_rows(tel), **self._span_extra)
               if tel is not None else NULL_SPAN):
             if self.paged and self.page_native:
                 # the widened verify reads/writes target K/V through
@@ -1957,7 +2072,8 @@ class ServeEngine:
             emitted=emitted, finished=finished, carry=self._carry,
             owner=self._engine_token,
             accepted=accepted, rejected=rejected,
-            asynchronous=asynchronous, enqueued_at=time.perf_counter())
+            asynchronous=asynchronous,
+            enqueued_at=self._overlap_stamp(asynchronous))
 
     def _sync_spec(self, pending: PendingDispatch, emitted, accepted,
                    rejected, finished,

@@ -669,6 +669,8 @@ class ReplicaFleet:
             return float(self._ticks)
         if self._t0 is None:
             self._t0 = self._clock()
+            if self._tel is not None:
+                self._tel.spans.set_origin(self._t0)
         return self._clock() - self._t0
 
     # --------------------------------------------------------- replicas

@@ -100,6 +100,7 @@ def quantize_dense_cache(model, values):
     return tm(q_leaf, values), tm(s_leaf, values)
 
 
+@jax.named_scope("kv_load")
 def dense_storage_values(model, storage):
     """Materialize compute-dtype KV values from dense storage: identity
     for plain storage, dequantize for the ``(q, s)`` int8 tuple (the
@@ -113,6 +114,7 @@ def dense_storage_values(model, storage):
         q, s)
 
 
+@jax.named_scope("kv_commit")
 def dense_storage_commit(model, storage, values):
     """Write updated compute-dtype values back into dense storage:
     identity for plain storage, re-quantize for int8 (untouched rows
@@ -159,6 +161,7 @@ def _page_reduce_axes(axis: int, leaf) -> Tuple[int, ...]:
     return (axis + 1, axis + 3)
 
 
+@jax.named_scope("page_gather")
 def gather_pages(model, arena, page_table):
     """Materialize the dense per-slot KV view from the arena: one gather
     per KV leaf, ``(S, pp)`` page table → ``(S, pp * page_size, …)``
@@ -198,6 +201,7 @@ def gather_pages(model, arena, page_table):
     return jax.tree_util.tree_map(gather_q, q, s)
 
 
+@jax.named_scope("page_scatter")
 def scatter_pages(model, arena, view, page_table):
     """Write the dense view's rows back to their arena pages (inverse of
     :func:`gather_pages`). Unmapped entries scatter to a dropped

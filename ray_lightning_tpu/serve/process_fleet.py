@@ -313,6 +313,10 @@ class ProcessReplicaFleet(ReplicaFleet):
         self._submit_timeout = float(submit_timeout)
         self.scale_eval_interval = float(scale_eval_interval)
         self._epoch = time.time()
+        if telemetry is not None:
+            # request stamps are time.time() - epoch fleet-wide, and
+            # workers ship raw time.time() span stamps: one axis
+            telemetry.spans.set_origin(self._epoch)
         self._ticks = 0
         self._next_id = 0
         self._next_replica_id = 0
@@ -751,14 +755,15 @@ class ProcessReplicaFleet(ReplicaFleet):
                         self._apply_metric(msg)
                 elif mk == MSG_SPAN:
                     if self._tel is not None:
-                        # a worker's closed span (fleet-timeline µs):
-                        # import seat-tagged so the stitched Chrome
+                        # a worker's closed span (raw time.time()
+                        # stamps; the recorder's origin is the fleet
+                        # epoch): import seat-tagged so the stitched Chrome
                         # trace puts each replica on its own pid track.
                         # A dead replica's last flushed spans land here
                         # too — _fail_replica drains before teardown.
-                        _mk, srid, name, ts, dur, depth, args = msg
+                        _mk, srid, name, start, end, depth, args = msg
                         self._tel.spans.record_closed(
-                            name, ts, dur, depth,
+                            name, start, end, depth,
                             dict(args, seat=srid))
                 elif mk == MSG_CRASH:
                     if rep is not None:

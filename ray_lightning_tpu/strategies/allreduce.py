@@ -50,14 +50,18 @@ class HorovodRayStrategy(Strategy):
             rank = jax.lax.axis_index(DP_AXIS)
             rng = jax.random.fold_in(
                 jax.random.fold_in(state.rng, state.step), rank)
-            grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
+            grad_fn = jax.value_and_grad(
+                jax.named_scope("loss")(loss_fn), has_aux=True)
             (loss, (logs, new_ms)), grads = grad_fn(
                 state.params, state.model_state, batch, rng)
             # The explicit allreduce — hvd.allreduce ≙ lax.pmean over ICI.
-            grads = jax.lax.pmean(grads, DP_AXIS)
-            loss = jax.lax.pmean(loss, DP_AXIS)
+            with jax.named_scope("grad_exchange"):
+                grads = jax.lax.pmean(grads, DP_AXIS)
+                loss = jax.lax.pmean(loss, DP_AXIS)
             if log_grad_norm:  # post-allreduce: the effective update norm
-                logs = {**logs, "grad_norm": optax.global_norm(grads)}
+                with jax.named_scope("grad_norm"):
+                    logs = {**logs,
+                            "grad_norm": optax.global_norm(grads)}
             logs = jax.tree_util.tree_map(
                 lambda x: jax.lax.pmean(x, DP_AXIS)
                 if jnp.issubdtype(jnp.asarray(x).dtype, jnp.floating) else x,
@@ -66,8 +70,10 @@ class HorovodRayStrategy(Strategy):
                 lambda x: jax.lax.pmean(x, DP_AXIS)
                 if jnp.issubdtype(jnp.asarray(x).dtype, jnp.floating) else x,
                 new_ms)
-            updates, new_opt = tx.update(grads, state.opt_state, state.params)
-            new_params = optax.apply_updates(state.params, updates)
+            with jax.named_scope("optimizer"):
+                updates, new_opt = tx.update(grads, state.opt_state,
+                                             state.params)
+                new_params = optax.apply_updates(state.params, updates)
             if guard_nonfinite:
                 # checked on the post-allreduce grads, so every rank
                 # reaches the same keep/skip verdict with no extra

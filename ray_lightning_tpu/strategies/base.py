@@ -249,24 +249,35 @@ class Strategy:
 
         from ray_lightning_tpu.reliability.guard import tree_all_finite
 
+        # device scopes (docs/observability.md): the forward reads
+        # ".../jvp(loss)/...", the gradient ".../transpose(jvp(loss))/..."
+        # in a profile; the exchange this path leaves to XLA carries the
+        # name of the op whose result is exchanged
+        scoped_loss = jax.named_scope("loss")(loss_fn)
+
         def step(state, batch):
             rng = jax.random.fold_in(state.rng, state.step)
-            grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
+            grad_fn = jax.value_and_grad(scoped_loss, has_aux=True)
             (loss, (logs, new_ms)), grads = grad_fn(
                 state.params, state.model_state, batch, rng)
             if log_grad_norm:
-                logs = {**logs, "grad_norm": optax.global_norm(grads)}
-            updates, new_opt = tx.update(grads, state.opt_state, state.params)
-            new_params = optax.apply_updates(state.params, updates)
+                with jax.named_scope("grad_norm"):
+                    logs = {**logs,
+                            "grad_norm": optax.global_norm(grads)}
+            with jax.named_scope("optimizer"):
+                updates, new_opt = tx.update(grads, state.opt_state,
+                                             state.params)
+                new_params = optax.apply_updates(state.params, updates)
             if guard_nonfinite:
-                ok = jnp.isfinite(loss) & tree_all_finite(grads)
-                keep = lambda new, old: jax.tree_util.tree_map(  # noqa: E731
-                    lambda n, o: jnp.where(ok, n, o), new, old)
-                new_params = keep(new_params, state.params)
-                new_opt = keep(new_opt, state.opt_state)
-                new_ms = keep(new_ms, state.model_state)
-                logs = {**logs,
-                        "nonfinite": (~ok).astype(jnp.float32)}
+                with jax.named_scope("nonfinite_guard"):
+                    ok = jnp.isfinite(loss) & tree_all_finite(grads)
+                    keep = lambda new, old: jax.tree_util.tree_map(  # noqa: E731,E501
+                        lambda n, o: jnp.where(ok, n, o), new, old)
+                    new_params = keep(new_params, state.params)
+                    new_opt = keep(new_opt, state.opt_state)
+                    new_ms = keep(new_ms, state.model_state)
+                    logs = {**logs,
+                            "nonfinite": (~ok).astype(jnp.float32)}
             new_state = state.replace(
                 step=state.step + 1, params=new_params, opt_state=new_opt,
                 model_state=new_ms)

@@ -360,10 +360,10 @@ def test_spec_obs_surfaces_pinned(nano):
     _run(dec, params, _trace(), draft_model=draft, draft_params=dparams,
          spec_k=2)
     assert not fresh.events()
-    # the only series on a fresh handle is the pre-registered
-    # ring-drop counter (PR 19), still at zero
+    # the only series on a fresh handle are the two pre-registered drop
+    # counters (event ring, PR 19; span recorder, PR 26), still at zero
     assert fresh.metrics.snapshot() == {
-        "obs_events_dropped_total": 0.0}
+        "obs_events_dropped_total": 0.0, "obs_spans_dropped_total": 0.0}
 
 
 # --------------------------------------------------------------------- #

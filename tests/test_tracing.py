@@ -418,7 +418,7 @@ def test_process_fleet_spans_forwarded_with_seat_tags(nano):
     assert spans, "no worker spans arrived over MSG_SPAN"
     seats = {s.args.get("seat") for s in spans}
     assert seats >= {0, 1}  # both replicas' spans, stitched
-    assert any(s.name == "engine.prefill" for s in spans)
+    assert any(s.name == "engine.prefill.call" for s in spans)
     assert all(s.dur >= 0 for s in spans)
     assert sorted(traces) == sorted(out)
     for tr in traces.values():
