@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from ray_lightning_tpu.models.quant import (kv_dequantize, kv_quantize,
                                             kv_scales)
 from ray_lightning_tpu.ops.attention import dot_product_attention
+from ray_lightning_tpu.parallel.sharding import constrain_batch
 
 
 @dataclasses.dataclass(frozen=True)
@@ -709,15 +710,19 @@ class TransformerBlock(nn.Module):
     def __call__(self, x, mask=None, deterministic=True, kv_positions=None,
                  page_table=None, adapter_ids=None):
         cfg = self.cfg
+        # the residual stream keeps its batch dim on the mesh's data axes
+        # (identity without a strategy's mesh): a weight's fsdp cut is
+        # then storage only, gathered here where the layer uses it
+        x = constrain_batch(x)
         h = nn.LayerNorm(dtype=cfg.dtype, name="ln1")(x)
-        x = x + MultiHeadAttention(cfg, name="attn")(
+        x = constrain_batch(x + MultiHeadAttention(cfg, name="attn")(
             h, mask=mask, deterministic=deterministic,
             kv_positions=kv_positions, page_table=page_table,
-            adapter_ids=adapter_ids)
+            adapter_ids=adapter_ids))
         h = nn.LayerNorm(dtype=cfg.dtype, name="ln2")(x)
         x = x + MlpBlock(cfg, name="mlp")(h, deterministic=deterministic,
                                           adapter_ids=adapter_ids)
-        return x
+        return constrain_batch(x)
 
 
 class _ScanBlock(nn.Module):
@@ -965,10 +970,14 @@ class TransformerLM(nn.Module):
                            matmul_kernel=cfg.matmul_kernel,
                            dtype=cfg.dtype,
                            param_dtype=cfg.param_dtype, name="wpe")(pos)
+        # batch-split into the stack and into the head (constrain_batch):
+        # the logits and the cross entropy stay split, and a wte stored
+        # along d (the head's contraction dim) is gathered, not computed on
         x = TransformerStack(cfg, name="stack")(
-            x, deterministic=deterministic, kv_positions=kv_positions,
-            page_table=page_table, adapter_ids=adapter_ids)
-        x = nn.LayerNorm(dtype=cfg.dtype, name="ln_f")(x)
+            constrain_batch(x), deterministic=deterministic,
+            kv_positions=kv_positions, page_table=page_table,
+            adapter_ids=adapter_ids)
+        x = constrain_batch(nn.LayerNorm(dtype=cfg.dtype, name="ln_f")(x))
         if return_hidden:
             return x
         if cfg.tie_embeddings:

@@ -11,6 +11,9 @@ optimizer state vs batch.
 """
 from __future__ import annotations
 
+import functools
+import math
+import threading
 from typing import Any, Callable, Optional, Sequence, Tuple
 
 import jax
@@ -27,6 +30,58 @@ def data_axis_names(mesh: Mesh) -> Tuple[str, ...]:
     """The mesh axes that carry batch-dim sharding (single source of
     truth for batch_sharding / pipelined_stack / sp_sharded_attention)."""
     return tuple(a for a in ("dp", "fsdp") if a in mesh.axis_names)
+
+
+# The mesh of the step being traced: a strategy runs its step's Python body
+# under ``under_mesh`` so model code can pin activations to the mesh's data
+# axes without threading the mesh through configs (the sp / pp meshes of
+# ring_attention / pipeline are handed over the same way, but this one
+# lives only for the length of a trace).
+_AMBIENT = threading.local()
+
+
+def under_mesh(mesh: Mesh, fn: Callable) -> Callable:
+    """``fn`` with ``mesh`` ambient — the one :func:`constrain_batch` sees —
+    while its body runs, which under ``jax.jit`` is while it is traced."""
+    @functools.wraps(fn)
+    def traced(*args, **kwargs):
+        prev = getattr(_AMBIENT, "mesh", None)
+        _AMBIENT.mesh = mesh
+        try:
+            return fn(*args, **kwargs)
+        finally:
+            _AMBIENT.mesh = prev
+    return traced
+
+
+def constrain_batch(x: jax.Array) -> jax.Array:
+    """Keep dim 0 of an activation split over the ambient mesh's data axes.
+
+    Every other dim is ``PartitionSpec.UNCONSTRAINED`` (not ``None``), so
+    ``tp`` / ``sp`` / ``ep`` cuts of those dims still propagate. This is
+    what makes a parameter's ``fsdp`` cut *storage only*: with the batch
+    dim owning the data axes through every block, the partitioner cannot
+    compute on a weight's stored shards (a contraction over a cut dim, its
+    partial results all-reduced at the global batch's size) and has to
+    all-gather the weight where the layer uses it and reduce the weight's
+    gradient back onto the stored cut.
+
+    The identity — the same object back, nothing lowered — when there is no
+    ambient mesh (the serve engine's programs, a bare ``model.apply``), when
+    the data axes' sizes multiply to 1, when dim 0 does not divide by them,
+    and inside a manual (``shard_map``) region, where the arrays are
+    already per-device blocks.
+    """
+    mesh = getattr(_AMBIENT, "mesh", None)
+    if mesh is None or not getattr(x, "ndim", 0):
+        return x
+    axes = data_axis_names(mesh)
+    size = math.prod(mesh.shape[a] for a in axes)
+    if size == 1 or x.shape[0] % size \
+            or jax.sharding.get_abstract_mesh().manual_axes:
+        return x
+    spec = P(axes, *[P.UNCONSTRAINED] * (x.ndim - 1))
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
 def compose_rules(*rules):
@@ -87,7 +142,9 @@ def largest_divisible_dim(shape: Tuple[int, ...], size: int) -> Optional[int]:
 
     Used for ZeRO-1 / FSDP parameter+optimizer-state sharding where no
     per-layer logical rule exists (flat sharding, matching FairScale's
-    greedy parameter bucketing semantics but resolved per-array).
+    greedy parameter bucketing semantics but resolved per-array). A
+    storage choice only: it may land on a contraction dim (``qkv/kernel``'s
+    ``d``, a bias's head dim) because nothing computes on the shards.
     """
     best, best_size = None, 0
     for i, d in enumerate(shape):
@@ -114,7 +171,11 @@ def shard_pytree_along_axis(tree: Any, mesh: Mesh, axis_name: str) -> Any:
 
     This is the FSDP/ZeRO rule: each array is split along its largest
     divisible dim over the axis; arrays too small to split stay replicated
-    (their memory is negligible by construction).
+    (their memory is negligible by construction). The dim chosen says where
+    a leaf is *stored* (checkpoints and ``params_sharding`` depend on it),
+    not how it is computed on: :func:`constrain_batch` keeps activations
+    split over the data axes, so a step gathers the leaf whole where it is
+    used, whichever dim was cut.
     """
     size = mesh.shape[axis_name]
 

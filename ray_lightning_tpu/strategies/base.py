@@ -292,8 +292,10 @@ class Strategy:
         # the host/HBM boundary, so donation there is both safe and the
         # memory win it exists for.
         donate = donate and jax.default_backend() != "cpu"
+        # traced with the mesh ambient: the model's constrain_batch seats
+        # keep activations on the data axes, parameter cuts are storage
         return jax.jit(
-            step,
+            shardlib.under_mesh(self.mesh, step),
             in_shardings=(state_shardings, batch_sharding),
             out_shardings=(state_shardings, self.scalar_sharding()),
             donate_argnums=(0,) if donate else ())
@@ -306,7 +308,7 @@ class Strategy:
             return eval_fn(state.params, state.model_state, batch, rng)
 
         return jax.jit(
-            step,
+            shardlib.under_mesh(self.mesh, step),
             in_shardings=(state_shardings, batch_sharding,
                           self.scalar_sharding()),
             out_shardings=self.scalar_sharding())

@@ -128,17 +128,42 @@ def test_fsdp_params_sharded(tmp_root, num_workers):
     assert sharded, "no parameter leaf was sharded under FSDP"
 
 
-def test_fsdp_matches_ddp(tmp_root):
-    def run(strategy):
-        model = BoringModel()
-        trainer = get_trainer(tmp_root, strategy=strategy, max_epochs=1,
-                              limit_train_batches=4, limit_val_batches=0,
-                              checkpoint_callback=False, seed=11)
-        trainer.fit(model)
-        return jax.device_get(trainer.train_state.params)
+def _five_head_gpt():
+    """A nano GPT whose 5 heads divide no mesh of 2 or 4 (GPT-2 XL's 25
+    over 4 chips at test size). Plain SGD: Adam would turn the key bias's
+    noise-level gradient into a full-size step of either sign."""
+    import jax.numpy as jnp
+    import optax
 
-    p_ddp = run(RayStrategy(num_workers=2))
-    p_fsdp = run(FSDPStrategy(num_workers=2))
+    from ray_lightning_tpu.models.gpt import GPTModule
+    from ray_lightning_tpu.models.transformer import TransformerConfig
+
+    class SgdGPT(GPTModule):
+        def configure_optimizers(self):
+            return optax.sgd(0.1)
+
+    cfg = TransformerConfig(vocab_size=257, max_seq_len=32, d_model=80,
+                            n_heads=5, n_layers=2, d_ff=320, causal=True,
+                            dtype=jnp.float32, scan_layers=True)
+    return SgdGPT(config=cfg, batch_size=8, seq_len=32, num_samples=64)
+
+
+@pytest.mark.parametrize("make_model,num_workers,steps", [
+    (BoringModel, 2, 4), (_five_head_gpt, 4, 3)],
+    ids=["boring", "gpt_heads_not_dividing_the_mesh"])
+def test_fsdp_matches_ddp(tmp_root, make_model, num_workers, steps):
+    def run(strategy):
+        trainer = get_trainer(tmp_root, strategy=strategy, max_epochs=1,
+                              limit_train_batches=steps,
+                              limit_val_batches=0,
+                              checkpoint_callback=False, seed=11)
+        trainer.fit(make_model())
+        return (jax.device_get(trainer.train_state.params),
+                float(trainer.callback_metrics["train_loss"]))
+
+    p_ddp, loss_ddp = run(RayStrategy(num_workers=num_workers))
+    p_fsdp, loss_fsdp = run(FSDPStrategy(num_workers=num_workers))
+    np.testing.assert_allclose(loss_fsdp, loss_ddp, rtol=1e-5, atol=1e-6)
     for a, b in zip(jax.tree_util.tree_leaves(p_ddp),
                     jax.tree_util.tree_leaves(p_fsdp)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),

@@ -9,8 +9,10 @@ prints device time by named scope and the idle gaps by the program's own
 spans. ``--arm-trainer`` hands ``Trainer`` a wall-clock ``Telemetry`` (the
 train kind arms none), so a train cell's ``trainer.*`` spans are in the
 profile too, and ``--trace 0 --arm-trainer`` against ``--trace 0`` is what
-the trainer's spans cost a step. Chip only (``--rehearse`` walks it on the
-CPU)::
+the trainer's spans cost a step. ``--census`` compiles a train cell's step
+once more after set-up and prints its collectives (``obs/census.py``: kind,
+result type, bytes, inside the layer loop or not, carrying the global batch
+or parameter-shaped). Chip only (``--rehearse`` walks it on the CPU)::
 
     python tools/chip_profile.py --workload gpt2-large.serve.closed40 \\
         --seed 11 --out chiprun_out/profile.serve
@@ -28,6 +30,30 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 
+def print_census_at_setup() -> None:
+    """Have ``Trainer._setup_state`` compile its train step for the batch it
+    was set up with and write that program's collectives to stderr."""
+    import jax
+
+    from ray_lightning_tpu.core.trainer import Trainer
+    from ray_lightning_tpu.obs.census import collective_census, format_census
+    from ray_lightning_tpu.parallel.sharding import put_global_batch
+    setup = Trainer._setup_state
+
+    def setup_then_census(self, sample_batch, *args, **kwargs):
+        state = setup(self, sample_batch, *args, **kwargs)
+        batch = put_global_batch(self._cast_batch(sample_batch),
+                                 self._batch_sharding)
+        compiled = self._train_step.lower(state, batch).compile()
+        rows = len(jax.tree_util.tree_leaves(batch)[0])
+        sys.stderr.write(
+            f"census of the train step (global batch {rows}):\n"
+            + format_census(collective_census(compiled, batch=rows)) + "\n")
+        return state
+
+    Trainer._setup_state = setup_then_census
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -37,6 +63,8 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default=None,
                     help="where the profile is kept (with --trace 1)")
     ap.add_argument("--arm-trainer", action="store_true")
+    ap.add_argument("--census", action="store_true",
+                    help="print the collectives of the Trainer's step")
     ap.add_argument("--depth", type=int, default=2)
     ap.add_argument("--top", type=int, default=15)
     ap.add_argument("--rehearse", action="store_true")
@@ -58,6 +86,9 @@ def main(argv=None) -> int:
         from ray_lightning_tpu.obs import Telemetry
         rlt.Trainer = functools.partial(
             rlt.Trainer, telemetry=Telemetry(clock=time.perf_counter))
+
+    if args.census:
+        print_census_at_setup()
 
     argv = ["--workload", args.workload, "--seed", str(args.seed),
             "--trace", str(args.trace)]
