@@ -66,6 +66,7 @@ not — recompilation is paid once per shape.
 """
 from __future__ import annotations
 
+import dataclasses
 from functools import partial
 from typing import Optional
 
@@ -74,6 +75,40 @@ import jax.numpy as jnp
 
 from ray_lightning_tpu.models.quant import materialize_for_program
 from ray_lightning_tpu.models.transformer import latch_eos
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheLeaf:
+    """What one leaf of a model's ``cache`` collection is — the model's
+    own declaration (``model.cache_leaf(path names)``), which the serve
+    engine reads instead of guessing from the leaf's rank.
+
+    ``slot_axis`` is the axis along which the leaf holds one entry per
+    batch row / engine slot (injected at prefill, handed on with the
+    slot); ``None`` marks shared bookkeeping that no per-row path reads
+    (the scalar ``cache_index``). ``kind`` says what a live row's bytes
+    grow with: ``"global"`` with its context (``seq_axis`` positions, of
+    which ``position + 1`` are live), ``"window"`` up to the leaf's
+    ``seq_axis`` length, ``"recurrent"`` with nothing."""
+    slot_axis: Optional[int]
+    kind: str = "global"
+    seq_axis: Optional[int] = None
+
+    @property
+    def per_slot(self) -> bool:
+        return self.slot_axis is not None
+
+
+def cache_layout(model, cache):
+    """``cache`` (the collection, or anything of its tree structure) ->
+    the same tree of :class:`CacheLeaf`, asked of the model leaf by
+    leaf."""
+    def declare(path, _leaf):
+        names = tuple(getattr(k, "key", getattr(k, "name", str(k)))
+                      for k in path)
+        return model.cache_leaf(names)
+
+    return jax.tree_util.tree_map_with_path(declare, cache)
 
 
 def sample_logits(logits: jax.Array, rng: jax.Array,
@@ -321,6 +356,17 @@ def _prefill_impl(model, params, prompt_tokens, prompt_lengths,
                        jnp.zeros((B, 1), jnp.int32),
                        positions=jnp.zeros((B, 1), jnp.int32))["cache"]
     positions = jax.lax.broadcasted_iota(jnp.int32, (1, P), 1)
+    if getattr(model, "recurrent_state", False):
+        # a recurrence or a ring would eat the pad tail: the model takes
+        # the row lengths, leaves every row's state as of its last valid
+        # position, and returns that position's logits only
+        lengths = (jnp.full((B,), P, jnp.int32) if prompt_lengths is None
+                   else jnp.asarray(prompt_lengths, jnp.int32))
+        outputs, updated = model.apply(
+            {"params": params, "cache": cache}, prompt_tokens,
+            positions=positions, lengths=lengths, deterministic=True,
+            mutable=["cache"], **_adapter_kw(adapter_ids))
+        return updated["cache"], _logits_only(outputs)[:, -1]
     outputs, updated = model.apply(
         {"params": params, "cache": cache}, prompt_tokens,
         positions=positions, deterministic=True, mutable=["cache"],
@@ -345,8 +391,16 @@ def prefill(model, params, prompt_tokens: jax.Array,
     logits at each row's final prompt position (``prompt_lengths[i]-1``
     when lengths are given, else ``P-1``) — sample the first generated
     token from them, then continue with per-token cached decode steps.
-    Causality makes them exact for left-aligned ragged rows: position
-    ``L-1`` never attends past itself, so the pad tail cannot leak in.
+    For a model whose every cache leaf is attention K/V at absolute
+    positions, causality makes them exact for left-aligned ragged rows:
+    position ``L-1`` never attends past itself, so the pad tail cannot
+    leak in. That holds for attention only. A recurrent state or a ring
+    of the last W positions is a function of *every* token fed, pad tail
+    included: a model with such state declares ``recurrent_state`` and
+    is called with ``lengths`` (B,) — the length contract: after the
+    call each row's recurrent state is as of position ``L-1``, its ring
+    holds positions ``max(0, L-W)..L-1``, and the call returns the
+    ``(B, 1, V)`` logits of position ``L-1`` itself (``models/sambay.py``).
 
     Ragged continuation contract: after a ragged prefill the cache slots
     ``lengths[i]..P-1`` of short rows hold pad-tail K/V, so the decode

@@ -32,6 +32,7 @@ from jax.sharding import PartitionSpec as P
 from ray_lightning_tpu.core.module import TpuModule
 from ray_lightning_tpu.data.loader import ArrayDataset, DataLoader
 from ray_lightning_tpu.models.transformer import (MultiHeadAttention,
+                                                  kv_cache_leaf,
                                                   TransformerConfig,
                                                   maybe_remat)
 
@@ -155,6 +156,9 @@ class MoeTransformerLM(nn.Module):
     functionally (layers are unrolled; MoE depth is small by design and
     routing differs per layer, so there is no scan win to chase)."""
     cfg: MoeConfig
+
+    def cache_leaf(self, names):
+        return kv_cache_leaf(self.cfg, names)
 
     @nn.compact
     def __call__(self, tokens, deterministic: bool = True, positions=None,

@@ -922,6 +922,18 @@ def stack_scan_params(params):
     return out
 
 
+def kv_cache_leaf(cfg, names):
+    """The declaration of :class:`MultiHeadAttention`'s decode cache
+    (``generate.CacheLeaf``): ``cached_key`` / ``cached_value`` hold one
+    ``(max_seq_len, H, D)`` row a slot — on axis 0, or axis 1 under the
+    layer scan's leading axis; ``cache_index`` is shared bookkeeping."""
+    from ray_lightning_tpu.models.generate import CacheLeaf
+    if names[-1] in ("cached_key", "cached_value"):
+        axis = 1 if cfg.scan_layers else 0
+        return CacheLeaf(axis, "global", seq_axis=axis + 1)
+    return CacheLeaf(None)
+
+
 class TransformerLM(nn.Module):
     """GPT-style causal language model (token + learned position embeds).
 
@@ -950,6 +962,9 @@ class TransformerLM(nn.Module):
     that never materializes the full ``(B*T, V)`` logits tensor.
     """
     cfg: TransformerConfig
+
+    def cache_leaf(self, names):
+        return kv_cache_leaf(self.cfg, names)
 
     @nn.compact
     def __call__(self, tokens, deterministic: bool = True, positions=None,

@@ -98,7 +98,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_lightning_tpu.models.generate import (_adapter_kw, _logits_only,
-                                               _prefill_impl,
+                                               _prefill_impl, cache_layout,
                                                decode_step,
                                                decode_step_paged,
                                                sample_logits_rows)
@@ -122,7 +122,7 @@ from ray_lightning_tpu.serve.pages import (PagePool, PrefixCache,
                                            dense_storage_values, fold_rows,
                                            gather_pages, pick_donated,
                                            quantize_dense_cache,
-                                           scatter_pages)
+                                           scatter_pages, slot_leaves)
 from ray_lightning_tpu.serve.spec import (SpecDecoder,
                                           _spec_page_native_donated,
                                           _spec_page_native_plain,
@@ -312,25 +312,29 @@ def _prefill_inject_impl(model, params, pool_cache, prompts, lengths,
         first_keys = _fold_rows(keys, startno)
     first = sample_logits_rows(last, first_keys, temp, top_k)
 
-    # cache leaves: cached_key/cached_value are (B, L, H, D) unrolled or
-    # (n_layers, B, L, H, D) scanned — the batch axis follows the layout.
-    # Sub-4d leaves (cache_index scalars/stacks) are shared-index
-    # bookkeeping the per-row kv_positions path never reads: keep pool's.
-    batch_axis = 1 if model.cfg.scan_layers else 0
-    num_slots = next(leaf.shape[batch_axis]
-                     for leaf in jax.tree_util.tree_leaves(pool_cache)
-                     if leaf.ndim >= 4)
+    # which leaf belongs to a slot, and on which axis, is the cache's own
+    # declaration (generate.cache_layout): GPT-2's cached_key/value are
+    # (B, L, H, D) unrolled or (n_layers, B, L, H, D) scanned, a
+    # recurrent state is (B, N, D) — every such leaf is injected whole.
+    # Shared bookkeeping (cache_index) the per-row kv_positions path
+    # never reads: keep pool's.
+    layout = cache_layout(model, pool_cache)
+    num_slots = next(
+        leaf.shape[decl.slot_axis] for leaf, decl in zip(
+            jax.tree_util.tree_leaves(pool_cache),
+            jax.tree_util.tree_leaves(layout)) if decl.per_slot)
 
     # slot_map[s] = the pf row writing pool slot s, or -1 to keep the
     # pool row. Invalid (padding) rows scatter to a dropped out-of-range
     # index; valid slots are unique (pool invariant), so one gather +
     # select per leaf does the whole injection — no per-row update chain.
-    def inject(pool, pf):
-        if pool.ndim < 4:
+    def inject(pool, pf, decl):
+        if not decl.per_slot:
             return pool
-        gathered = jnp.take(pf, jnp.maximum(slot_map, 0), axis=batch_axis)
+        gathered = jnp.take(pf, jnp.maximum(slot_map, 0),
+                            axis=decl.slot_axis)
         mask_shape = [1] * pool.ndim
-        mask_shape[batch_axis] = num_slots
+        mask_shape[decl.slot_axis] = num_slots
         return jnp.where(keep.reshape(mask_shape), pool, gathered)
 
     with jax.named_scope("prefill/kv_inject"):
@@ -339,7 +343,8 @@ def _prefill_inject_impl(model, params, pool_cache, prompts, lengths,
             scatter_idx].set(jnp.arange(B_pf, dtype=jnp.int32),
                              mode="drop")
         keep = slot_map < 0
-        pool_cache = jax.tree_util.tree_map(inject, pool_cache, pf_cache)
+        pool_cache = jax.tree_util.tree_map(inject, pool_cache, pf_cache,
+                                            layout)
     return dense_storage_commit(model, storage, pool_cache), first
 
 
@@ -422,8 +427,9 @@ def _chunk_prefill_impl(model, params, arena, row_pages, tokens, offset,
     pt = row_pages[None, :]
     view = _gather_pages(model, arena, pt)
     view = jax.tree_util.tree_map(
-        lambda leaf: (jnp.full(leaf.shape, offset, leaf.dtype)
-                      if leaf.ndim < 4 else leaf), view)
+        lambda leaf, kv: (leaf if kv else
+                          jnp.full(leaf.shape, offset, leaf.dtype)),
+        view, slot_leaves(model, view))
     C = tokens.shape[1]
     with jax.named_scope("chunk/forward"):
         positions = offset + jax.lax.broadcasted_iota(jnp.int32, (1, C), 1)
@@ -532,9 +538,11 @@ class KVSlotPool:
                  kv_dtype: Optional[str] = None):
         self.num_slots = num_slots
         self.kv_dtype = kv_dtype
-        cache = model.init(
+        # jitted, so that only the cache is ever made: run eagerly, init
+        # would draw a whole second set of weights beside the real one
+        cache = jax.jit(lambda: model.init(
             jax.random.PRNGKey(0), jnp.zeros((num_slots, 1), jnp.int32),
-            positions=jnp.zeros((num_slots, 1), jnp.int32))["cache"]
+            positions=jnp.zeros((num_slots, 1), jnp.int32))["cache"])()
         if check_kv_dtype(kv_dtype):
             # int8 storage: the (q, s) tuple the dense programs
             # dequantize/re-quantize inside each dispatch
@@ -584,6 +592,27 @@ class _ChunkState:
     fed: List[int]       # prompt + replayed tokens
     next_off: int        # first position not yet written (admission
     #                      seeds it past any adopted prefix pages)
+
+
+#: options only some model families carry, and what a config without
+#: the field runs: the engine reads them through ``_cfg_option``, so a
+#: model need not declare an option it does not have
+_CFG_DEFAULTS = {"attention_kernel": "xla", "matmul_kernel": "xla",
+                 "scan_layers": False, "lora": None}
+
+
+def _cfg_option(cfg, name: str):
+    return getattr(cfg, name, _CFG_DEFAULTS[name])
+
+
+def _with_cfg(model, **changes):
+    """``model`` rebuilt with config fields replaced; a family without
+    the option refuses by name."""
+    missing = [k for k in changes if not hasattr(model.cfg, k)]
+    if missing:
+        raise ValueError(
+            f"{type(model).__name__} has no {', '.join(missing)} option")
+    return model.clone(cfg=dataclasses.replace(model.cfg, **changes))
 
 
 class ServeEngine:
@@ -668,6 +697,27 @@ class ServeEngine:
             raise ValueError(
                 "page_native=True is a paged-KV mode (attention reads "
                 "K/V through the page table): pass page_size= too")
+        if getattr(model, "recurrent_state", False):
+            # a slot of such a model holds state that is no K/V row at
+            # absolute positions (a recurrence, a ring): what the engine
+            # cannot carry it through yet refuses here, by name, and
+            # never runs wrongly
+            refused = [name for name, on in (
+                ("page_size / page_native", page_size is not None),
+                ("kv_dtype='int8'", check_kv_dtype(kv_dtype)),
+                ("prefix_cache", prefix_cache),
+                ("prefill_chunk", prefill_chunk is not None),
+                ("draft_model (speculative decoding)",
+                 draft_model is not None),
+                ("max_resident_adapters (the LoRA bank)",
+                 max_resident_adapters is not None)) if on]
+            if refused:
+                raise ValueError(
+                    f"{type(model).__name__} declares recurrent state; "
+                    f"the engine cannot give it {', '.join(refused)} yet "
+                    "(pages, int8 storage, prefix reuse, chunked prefill "
+                    "and draft verification all assume K/V rows at "
+                    "absolute positions) — use the dense-slot engine")
         # attention_kernel selects the page-native read-side kernel
         # (models/pallas_attention.py): None inherits the model config
         # (default "xla"); "pallas" swaps in the hand-tiled paged
@@ -680,12 +730,11 @@ class ServeEngine:
             raise ValueError(
                 f"attention_kernel must be None, 'xla' or 'pallas', "
                 f"got {attention_kernel!r}")
-        if attention_kernel is not None \
-                and attention_kernel != cfg.attention_kernel:
-            model = model.clone(cfg=dataclasses.replace(
-                cfg, attention_kernel=attention_kernel))
+        if attention_kernel is not None and attention_kernel \
+                != _cfg_option(cfg, "attention_kernel"):
+            model = _with_cfg(model, attention_kernel=attention_kernel)
             cfg = model.cfg
-        self.attention_kernel = cfg.attention_kernel
+        self.attention_kernel = _cfg_option(cfg, "attention_kernel")
         if self.attention_kernel == "pallas" and not page_native:
             raise ValueError(
                 "attention_kernel='pallas' is the page-native paged-"
@@ -708,11 +757,10 @@ class ServeEngine:
                 f"matmul_kernel must be None, 'xla' or 'pallas', got "
                 f"{matmul_kernel!r}")
         if matmul_kernel is not None \
-                and matmul_kernel != cfg.matmul_kernel:
-            model = model.clone(cfg=dataclasses.replace(
-                cfg, matmul_kernel=matmul_kernel))
+                and matmul_kernel != _cfg_option(cfg, "matmul_kernel"):
+            model = _with_cfg(model, matmul_kernel=matmul_kernel)
             cfg = model.cfg
-        self.matmul_kernel = cfg.matmul_kernel
+        self.matmul_kernel = _cfg_option(cfg, "matmul_kernel")
         if self.matmul_kernel == "pallas":
             if weight_dtype is None and draft_weight_dtype is None:
                 raise ValueError(
@@ -721,20 +769,21 @@ class ServeEngine:
                     "pass weight_dtype='int8'|'int4' (or "
                     "draft_weight_dtype=) too, or drop the kernel — a "
                     "silently inert knob is a bug magnet")
-            if cfg.scan_layers and weight_dtype is not None:
+            if _cfg_option(cfg, "scan_layers") \
+                    and weight_dtype is not None:
                 raise ValueError(
                     "matmul_kernel='pallas' needs scan_layers=False: "
                     "nn.scan slices every param leaf along the layer "
                     "axis and QTensor scales have no such axis (serving "
                     "wants unrolled layers anyway — unstack_scan_params "
                     "the weights; docs/performance.md decode section)")
-        if draft_model is not None \
-                and draft_model.cfg.matmul_kernel != cfg.matmul_kernel:
-            draft_model = draft_model.clone(cfg=dataclasses.replace(
-                draft_model.cfg, matmul_kernel=cfg.matmul_kernel))
+        if draft_model is not None and _cfg_option(
+                draft_model.cfg, "matmul_kernel") != self.matmul_kernel:
+            draft_model = _with_cfg(draft_model,
+                                    matmul_kernel=self.matmul_kernel)
         if draft_model is not None and draft_weight_dtype is not None \
-                and cfg.matmul_kernel == "pallas" \
-                and draft_model.cfg.scan_layers:
+                and self.matmul_kernel == "pallas" \
+                and _cfg_option(draft_model.cfg, "scan_layers"):
             raise ValueError(
                 "matmul_kernel='pallas' needs the draft model unrolled "
                 "too (scan_layers=False) when its weights are "
@@ -833,7 +882,7 @@ class ServeEngine:
                 raise ValueError(
                     f"{len(adapters)} initial adapters exceed "
                     f"max_resident_adapters={max_resident_adapters}")
-            if cfg.scan_layers:
+            if _cfg_option(cfg, "scan_layers"):
                 raise ValueError(
                     "multi-LoRA serving needs scan_layers=False: the "
                     "bank graft walks unrolled layer scopes (serving "
@@ -841,9 +890,8 @@ class ServeEngine:
                     "the weights; docs/performance.md decode section)")
             lora_cfg = LoraConfig(rank=lora_rank,
                                   num_adapters=max_resident_adapters)
-            if cfg.lora != lora_cfg:
-                model = model.clone(
-                    cfg=dataclasses.replace(cfg, lora=lora_cfg))
+            if _cfg_option(cfg, "lora") != lora_cfg:
+                model = _with_cfg(model, lora=lora_cfg)
                 cfg = model.cfg
         self.model = model
         # weight-only quantization (models/quant.py): storage-only —
@@ -945,6 +993,9 @@ class ServeEngine:
             self.prefix = PrefixCache(self.pool)
         else:
             self.prefix = None
+        # at-rest bytes a row (``global``: a position) of each cache kind
+        # costs — filled by the first armed dispatch (_live_cache_bytes)
+        self._cache_units: Optional[Dict[str, float]] = None
         self._chunk_queue: Deque[_ChunkState] = deque()
         # the request whose FINAL chunk the last prefill_chunk_step
         # dispatch activated into decode (None otherwise) — the driving
@@ -1442,7 +1493,8 @@ class ServeEngine:
             counts = dict(
                 ids=[r.id for r in batched], rows=len(batched),
                 tokens=int(lengths[:len(batched)].sum()),
-                program_tokens=self.prefill_batch * self.prefill_len)
+                program_tokens=self.prefill_batch * self.prefill_len,
+                **self._live_cache_bytes(lengths[:len(batched)]))
             m = tel.metrics
             m.counter("serve_prefill_rows_total",
                       help="requests admitted by batched prefill "
@@ -1830,7 +1882,38 @@ class ServeEngine:
         m.counter("serve_step_slots_total",
                   help="num_slots summed over step dispatches (the rows "
                   "the step program runs over)").inc(self.num_slots)
-        return {"active": active, "slots": self.num_slots}
+        return {"active": active, "slots": self.num_slots,
+                **self._live_cache_bytes(
+                    self._pos[self._active, 0].astype(np.int64) + 1)}
+
+    def _live_cache_bytes(self, contexts) -> Dict[str, int]:
+        """Armed only: at-rest cache bytes that rows holding ``contexts``
+        positions keep live, by the kind each cache leaf declares —
+        ``recurrent`` and ``window`` are held whole by every row (a ring
+        is allocated, and read, at its full length whatever the
+        context), ``global`` grows with the context. From shapes and the
+        synced frontier alone: nothing is read from the device."""
+        if self._cache_units is None:
+            # bytes a row holds of each kind; for ``global``, a position
+            storage = self.pool.arena if self.paged else self.pool.cache
+            trees = storage if isinstance(storage, tuple) else (storage,)
+            leaves = jax.tree_util.tree_leaves
+            units = {"recurrent": 0.0, "window": 0.0, "global": 0.0}
+            for decl, *held in zip(leaves(cache_layout(self.model,
+                                                       trees[0])),
+                                   *(leaves(t) for t in trees)):
+                if not decl.per_slot:
+                    continue
+                per = held[0].shape[decl.slot_axis]
+                if decl.kind == "global":
+                    per *= held[0].shape[decl.seq_axis]
+                units[decl.kind] += sum(x.nbytes for x in held) / per
+            self._cache_units = units
+        contexts = np.asarray(contexts, np.int64)
+        units = self._cache_units
+        return {"recurrent_bytes": int(units["recurrent"] * len(contexts)),
+                "window_bytes": int(units["window"] * len(contexts)),
+                "global_bytes": int(units["global"] * contexts.sum())}
 
     def _step_call(self) -> tuple:
         """``(jitted step program, positional operands)`` of the next

@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_lightning_tpu.models.generate import cache_layout
 from ray_lightning_tpu.models.quant import (kv_dequantize, kv_quantize,
                                             kv_scales)
 from ray_lightning_tpu.serve.request import OccupancyError
@@ -63,11 +64,13 @@ def check_kv_dtype(kv_dtype) -> bool:
 
 # ---------------------------------------------------------------- int8 KV
 # Quantized KV storage is a 2-tuple ``(q_tree, s_tree)`` with the SAME
-# pytree structure as the plain cache: KV leaves (ndim >= 4) hold int8
+# pytree structure as the plain cache: per-slot KV leaves hold int8
 # codes in ``q_tree`` and f32 absmax scales (keepdims, reduced axes per
-# granularity) in ``s_tree``; sub-4d bookkeeping leaves (cache_index)
+# granularity) in ``s_tree``; shared bookkeeping leaves (cache_index)
 # live unchanged in ``q_tree`` with a zero-size placeholder in
-# ``s_tree``. The tuple flows through the jitted programs as an
+# ``s_tree``. Which leaf is which is the model's own declaration
+# (``generate.cache_layout``), never a guess from the leaf's rank. The
+# tuple flows through the jitted programs as an
 # ordinary pytree — dequantize on the way in, re-quantize on the way
 # out, both fused into the dispatch.
 #
@@ -75,6 +78,14 @@ def check_kv_dtype(kv_dtype) -> bool:
 # (imported above): the page-native attention path inside the model
 # needs the identical functions, and models must not depend on serve —
 # re-exported here so existing callers keep their import site.
+
+
+def slot_leaves(model, tree):
+    """``tree`` (of the cache collection's structure) -> the same tree of
+    booleans: True where the model declares the leaf per-slot K/V, False
+    for shared bookkeeping."""
+    return jax.tree_util.tree_map(lambda leaf: leaf.per_slot,
+                                  cache_layout(model, tree))
 
 
 def _dense_reduce_axes(leaf) -> Tuple[int, ...]:
@@ -86,18 +97,19 @@ def _dense_reduce_axes(leaf) -> Tuple[int, ...]:
 def quantize_dense_cache(model, values):
     """Plain dense cache tree → the ``(q, s)`` storage tuple
     (per-position-per-head scales)."""
-    def q_leaf(leaf):
-        if leaf.ndim < 4:
+    def q_leaf(leaf, kv):
+        if not kv:
             return leaf
         return kv_quantize(leaf, kv_scales(leaf, _dense_reduce_axes(leaf)))
 
-    def s_leaf(leaf):
-        if leaf.ndim < 4:
+    def s_leaf(leaf, kv):
+        if not kv:
             return jnp.zeros((), jnp.float32)
         return kv_scales(leaf, _dense_reduce_axes(leaf))
 
     tm = jax.tree_util.tree_map
-    return tm(q_leaf, values), tm(s_leaf, values)
+    kv = slot_leaves(model, values)
+    return tm(q_leaf, values, kv), tm(s_leaf, values, kv)
 
 
 @jax.named_scope("kv_load")
@@ -110,8 +122,8 @@ def dense_storage_values(model, storage):
     q, s = storage
     dt = model.cfg.dtype
     return jax.tree_util.tree_map(
-        lambda ql, sl: ql if ql.ndim < 4 else kv_dequantize(ql, sl, dt),
-        q, s)
+        lambda ql, sl, kv: kv_dequantize(ql, sl, dt) if kv else ql,
+        q, s, slot_leaves(model, q))
 
 
 @jax.named_scope("kv_commit")
@@ -125,18 +137,19 @@ def dense_storage_commit(model, storage, values):
         return values
     q, s = storage
 
-    def commit_q(ql, vl):
-        if ql.ndim < 4:
+    def commit_q(ql, vl, kv):
+        if not kv:
             return vl   # updated bookkeeping lives in the q tree
         return kv_quantize(vl, kv_scales(vl, _dense_reduce_axes(vl)))
 
-    def commit_s(sl, vl):
-        if vl.ndim < 4:
+    def commit_s(sl, vl, kv):
+        if not kv:
             return sl
         return kv_scales(vl, _dense_reduce_axes(vl))
 
     tm = jax.tree_util.tree_map
-    return tm(commit_q, q, values), tm(commit_s, s, values)
+    kv = slot_leaves(model, q)
+    return tm(commit_q, q, values, kv), tm(commit_s, s, values, kv)
 
 
 # --------------------------------------------------- arena gather/scatter
@@ -144,15 +157,15 @@ def page_axis(model) -> int:
     """Arena/cache leaves are ``(pages|B, seq, H, D)`` unrolled or
     ``(n_layers, pages|B, seq, H, D)`` scanned — page axis == batch
     axis."""
-    return 1 if model.cfg.scan_layers else 0
+    return 1 if getattr(model.cfg, "scan_layers", False) else 0
 
 
 def arena_num_pages(model, arena) -> int:
     axis = page_axis(model)
     tree = arena[0] if isinstance(arena, tuple) else arena
-    return next(leaf.shape[axis]
-                for leaf in jax.tree_util.tree_leaves(tree)
-                if leaf.ndim >= 4)
+    leaves = jax.tree_util.tree_leaves
+    return next(leaf.shape[axis] for leaf, kv in zip(
+        leaves(tree), leaves(slot_leaves(model, tree))) if kv)
 
 
 def _page_reduce_axes(axis: int, leaf) -> Tuple[int, ...]:
@@ -181,24 +194,25 @@ def gather_pages(model, arena, page_table):
         return pages.reshape(shape)
 
     if not isinstance(arena, tuple):
-        def gather(leaf):
-            if leaf.ndim < 4:
+        def gather(leaf, kv):
+            if not kv:
                 return leaf
             return to_view(jnp.take(leaf, idx, axis=axis))
 
-        return jax.tree_util.tree_map(gather, arena)
+        return jax.tree_util.tree_map(gather, arena,
+                                      slot_leaves(model, arena))
 
     q, s = arena
     dt = model.cfg.dtype
 
-    def gather_q(ql, sl):
-        if ql.ndim < 4:
+    def gather_q(ql, sl, kv):
+        if not kv:
             return ql
         pages = kv_dequantize(jnp.take(ql, idx, axis=axis),
                               jnp.take(sl, idx, axis=axis), dt)
         return to_view(pages)
 
-    return jax.tree_util.tree_map(gather_q, q, s)
+    return jax.tree_util.tree_map(gather_q, q, s, slot_leaves(model, q))
 
 
 @jax.named_scope("page_scatter")
@@ -230,30 +244,32 @@ def scatter_pages(model, arena, view, page_table):
         return arena_leaf.at[:, idx].set(pages, mode="drop")
 
     if not isinstance(arena, tuple):
-        def scatter(arena_leaf, view_leaf):
-            if arena_leaf.ndim < 4:
+        def scatter(arena_leaf, view_leaf, kv):
+            if not kv:
                 return arena_leaf
             return write(arena_leaf, to_pages(arena_leaf, view_leaf))
 
-        return jax.tree_util.tree_map(scatter, arena, view)
+        return jax.tree_util.tree_map(scatter, arena, view,
+                                      slot_leaves(model, arena))
 
     q, s = arena
 
-    def scatter_q(ql, sl, vl):
-        if ql.ndim < 4:
+    def scatter_q(ql, sl, vl, kv):
+        if not kv:
             return ql
         pages = to_pages(ql, vl)
         return write(ql, kv_quantize(
             pages, kv_scales(pages, _page_reduce_axes(axis, pages))))
 
-    def scatter_s(ql, sl, vl):
-        if ql.ndim < 4:
+    def scatter_s(ql, sl, vl, kv):
+        if not kv:
             return sl
         pages = to_pages(ql, vl)
         return write(sl, kv_scales(pages, _page_reduce_axes(axis, pages)))
 
     tm = jax.tree_util.tree_map
-    return tm(scatter_q, q, s, view), tm(scatter_s, q, s, view)
+    kv = slot_leaves(model, q)
+    return tm(scatter_q, q, s, view, kv), tm(scatter_s, q, s, view, kv)
 
 
 class SlotPoolFull(OccupancyError):
@@ -369,8 +385,8 @@ class PagePool:
         template = init["cache"]
         axis = page_axis(model)
 
-        def to_arena(leaf):
-            if leaf.ndim < 4:
+        def to_arena(leaf, kv):
+            if not kv:
                 return leaf
             shape = list(leaf.shape)
             shape[axis] = self.num_pages
@@ -379,7 +395,8 @@ class PagePool:
                 return jax.ShapeDtypeStruct(tuple(shape), leaf.dtype)
             return jnp.zeros(shape, leaf.dtype)
 
-        return jax.tree_util.tree_map(to_arena, template)
+        return jax.tree_util.tree_map(to_arena, template,
+                                      slot_leaves(model, template))
 
     @property
     def arena(self):
@@ -388,13 +405,13 @@ class PagePool:
             if self._quantized:
                 axis = page_axis(self._model)
 
-                def q_leaf(leaf):
-                    if leaf.ndim < 4:
+                def q_leaf(leaf, kv):
+                    if not kv:
                         return leaf
                     return jnp.zeros(leaf.shape, jnp.int8)
 
-                def s_leaf(leaf):
-                    if leaf.ndim < 4:
+                def s_leaf(leaf, kv):
+                    if not kv:
                         # placeholder mirrors the bookkeeping leaf's
                         # SHAPE (not a scalar): the page-native path
                         # ships the scales tree as a flax collection,
@@ -407,7 +424,8 @@ class PagePool:
                     return jnp.ones(shape, jnp.float32)
 
                 tm = jax.tree_util.tree_map
-                self._arena = (tm(q_leaf, plain), tm(s_leaf, plain))
+                kv = slot_leaves(self._model, plain)
+                self._arena = (tm(q_leaf, plain, kv), tm(s_leaf, plain, kv))
             else:
                 self._arena = plain
         return self._arena
@@ -424,9 +442,11 @@ class PagePool:
         bench/tests) never allocate the arena."""
         axis = page_axis(self._model)
         total = 0
-        for leaf in jax.tree_util.tree_leaves(
-                self._arena_template(shapes_only=True)):
-            if leaf.ndim < 4:
+        template = self._arena_template(shapes_only=True)
+        leaves = jax.tree_util.tree_leaves
+        for leaf, kv in zip(leaves(template),
+                            leaves(slot_leaves(self._model, template))):
+            if not kv:
                 continue
             numel = 1
             for d, n in enumerate(leaf.shape):

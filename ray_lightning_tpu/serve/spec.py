@@ -75,7 +75,8 @@ from ray_lightning_tpu.models.quant import materialize_for_program
 from ray_lightning_tpu.serve.pages import (dense_storage_commit,
                                            dense_storage_values,
                                            fold_rows, gather_pages,
-                                           pick_donated, scatter_pages)
+                                           pick_donated, scatter_pages,
+                                           slot_leaves)
 
 __all__ = ["SpecDecoder"]
 
@@ -376,15 +377,16 @@ def _draft_refill_impl(draft_model, draft_params, pool_cache, tokens,
     tenant or from parked spec rounds never survives an activation."""
     pf_cache, _last = _prefill_impl(draft_model, draft_params, tokens,
                                     length)
-    batch_axis = 1 if draft_model.cfg.scan_layers else 0
+    batch_axis = 1 if getattr(draft_model.cfg, "scan_layers", False) else 0
 
-    def inject(pool, pf):
-        if pool.ndim < 4:
+    def inject(pool, pf, kv):
+        if not kv:
             return pool
         return jax.lax.dynamic_update_slice_in_dim(pool, pf, slot,
                                                    axis=batch_axis)
 
-    return jax.tree_util.tree_map(inject, pool_cache, pf_cache)
+    return jax.tree_util.tree_map(inject, pool_cache, pf_cache,
+                                  slot_leaves(draft_model, pool_cache))
 
 
 _STATICS = ("model", "draft_model", "k", "rounds")

@@ -185,3 +185,56 @@ def test_gpt2_small_forward_compiles_and_fits(one_chip):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
     assert total < 16e9, total
+
+
+# --------------------------------------------------------------------- #
+# SambaY (Phi-4-mini-flash-reasoning) at its published widths: the two
+# engine programs of benchmark cell phi4-mini-flash-reasoning.serve.reason48
+# --------------------------------------------------------------------- #
+@pytest.mark.parametrize("program", ["step", "prefill"])
+def test_sambay_engine_programs_compile_and_fit(one_chip, program):
+    """Hidden 2560, 40 / 20 heads of 64, MLP 10240, window 512, the whole
+    200064-row vocabulary, 48 slots of 2048 positions — at 8 layers (one
+    period: every one of the five mixers, the memory, the shared K/V), a
+    quarter of the published depth, to keep the compile short. The
+    selective scan, the ring gather and the rank-3 state's injection must
+    lower for a v5e; the program must fit its 16 GB."""
+    from ray_lightning_tpu.models.sambay import SambaYConfig, SambaYLM
+    from ray_lightning_tpu.serve import engine as E
+    S = _spec(one_chip)
+    slots, rows, plen = 48, 4, 256
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(lambda a: S(a.shape, a.dtype), tree)
+
+    cfg = SambaYConfig(num_hidden_layers=8, decode=True)
+    model = SambaYLM(cfg)
+    init = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((slots, 1), jnp.int32),
+        positions=jnp.zeros((slots, 1), jnp.int32)))
+    params, cache = abstract(init["params"]), abstract(init["cache"])
+    if program == "step":
+        compiled = jax.jit(
+            E._engine_step_impl, static_argnames=("model", "steps"),
+            donate_argnums=(2,)).lower(
+                model, params, cache, S((slots, 1), jnp.int32),
+                S((slots, 1), jnp.int32), S((slots,), jnp.bool_),
+                S((slots,), jnp.int32), S((slots,), jnp.float32),
+                S((slots,), jnp.int32), S((slots,), jnp.int32),
+                S((slots, 2), jnp.uint32), S((slots,), jnp.int32), None,
+                steps=1).compile()
+    else:
+        compiled = jax.jit(
+            E._prefill_inject_impl, static_argnames=("model",),
+            donate_argnums=(2,)).lower(
+                model, params, cache, S((rows, plen), jnp.int32),
+                S((rows,), jnp.int32), S((rows,), jnp.int32),
+                S((rows,), jnp.bool_), S((rows, 2), jnp.uint32),
+                S((rows,), jnp.float32), S((rows,), jnp.int32),
+                S((rows,), jnp.int32), None).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    # 8 layers: 1.9 GB of bf16 weights + 0.9 GB of cache; the published
+    # depth reads 9.4 GB + 1.0 GB of temporaries (PERF.md section 6)
+    assert total < 6e9, total
