@@ -58,6 +58,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_lightning_tpu.models.generate import CacheLeaf
+from ray_lightning_tpu.ops.cache_write import write_rows
 
 MAMBA, SWA, FULL, GMU, CROSS = "mamba", "swa", "full", "gmu", "cross"
 
@@ -186,12 +187,6 @@ def _gather_rows(x, index):
         row, i, 1, axis=0))(x, index)
 
 
-def _row_write(cache, new, start):
-    """Per-row ``dynamic_update_slice`` along axis 1 at ``start (B,)``."""
-    return jax.vmap(lambda c, u, i: jax.lax.dynamic_update_slice_in_dim(
-        c, u, i, axis=0))(cache, new.astype(cache.dtype), start)
-
-
 def _mask(allowed):
     return jnp.where(allowed, 0.0, jnp.finfo(jnp.float32).min)
 
@@ -306,16 +301,17 @@ class SelfAttention(nn.Module):
                         ck.value = take(k, src).astype(cfg.dtype)
                         cv.value = take(v, src).astype(cfg.dtype)
                     else:
-                        zero = jnp.zeros((B,), jnp.int32)
-                        ck.value = _row_write(ck.value, k, zero)
-                        cv.value = _row_write(cv.value, v, zero)
+                        ck.value = jax.lax.dynamic_update_slice_in_dim(
+                            ck.value, k.astype(cfg.dtype), 0, axis=1)
+                        cv.value = jax.lax.dynamic_update_slice_in_dim(
+                            cv.value, v.astype(cfg.dtype), 0, axis=1)
                 kv_all = (ck.value, cv.value)
             else:                               # decode step
                 pos = kv_positions[:, 0].astype(jnp.int32)
                 with jax.named_scope(write_scope):
-                    at = pos % W if windowed else pos
-                    ck.value = _row_write(ck.value, k, at)
-                    cv.value = _row_write(cv.value, v, at)
+                    ck.value, cv.value = write_rows(
+                        (ck.value, cv.value), (k, v),
+                        pos % W if windowed else pos)
                 with jax.named_scope(attend_scope):
                     # a ring index j <= pos has been written by this
                     # request (all of them once pos >= W - 1)

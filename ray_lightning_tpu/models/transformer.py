@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from ray_lightning_tpu.models.quant import (kv_dequantize, kv_quantize,
                                             kv_scales)
 from ray_lightning_tpu.ops.attention import dot_product_attention
+from ray_lightning_tpu.ops.cache_write import write_rows
 from ray_lightning_tpu.parallel.sharding import constrain_batch
 
 
@@ -449,11 +450,11 @@ class MultiHeadAttention(nn.Module):
           per-row decode step, T>1 is the speculative-decode verify
           program scoring a row's draft block in one pass). Positions
           must be the contiguous run ``kv_positions[row, 0] + 0..T-1``
-          — the write is one vmapped ``dynamic_update_slice`` per row
-          at that start (a batched scatter); the mask is per-row,
-          per-query ``key <= kv_positions[row, q]`` (block-causal over
-          the cache, the ragged sibling of the shared-index block
-          mode).
+          — the write is :func:`ops.cache_write.write_rows` at that
+          start (in place: one kernel call a position for K and V
+          together); the mask is per-row, per-query
+          ``key <= kv_positions[row, q]`` (block-causal over the
+          cache, the ragged sibling of the shared-index block mode).
 
         The scalar ``cache_index`` advances by ``T`` either way; in the
         per-row mode it is bookkeeping only (positions come from the
@@ -475,13 +476,9 @@ class MultiHeadAttention(nn.Module):
         big_neg = jnp.finfo(jnp.float32).min
         if kv_positions is not None:
             pos = kv_positions.astype(jnp.int32)                # (B, T)
-            start = pos[:, 0]                                   # (B,)
-            row_write = jax.vmap(
-                lambda c, u, i: jax.lax.dynamic_update_slice(c, u,
-                                                             (i, 0, 0)))
             with jax.named_scope("kv_write"):
-                ck.value = row_write(ck.value, k, start)
-                cv.value = row_write(cv.value, v, start)
+                ck.value, cv.value = write_rows(
+                    (ck.value, cv.value), (k, v), pos[:, 0])
             ci.value = ci.value + T
             # per-row, per-query: query q of the block attends keys at
             # positions <= pos[row, q] — block-causal, covering the

@@ -112,6 +112,7 @@ from ray_lightning_tpu.models.quant import (DEFAULT_GROUP_SIZE,
                                             param_bytes, quantize_params)
 from ray_lightning_tpu.models.transformer import latch_eos
 from ray_lightning_tpu.obs.spans import NULL_SPAN
+from ray_lightning_tpu.ops.cache_write import inject_rows
 from ray_lightning_tpu.reliability import faults
 from ray_lightning_tpu.serve.adapters import (AdapterRegistry,
                                               UnknownAdapter)
@@ -288,9 +289,11 @@ def _prefill_inject_impl(model, params, pool_cache, prompts, lengths,
     ``(B_pf, P)`` shape (rows left-aligned, ``lengths`` raggedness — the
     same contract as generate()'s ragged prefill), samples each row's
     first token with its own key/params, then writes each valid row's
-    whole KV row into its assigned pool slot. Invalid (padding) rows are
-    computed but written nowhere — the pool row is read back and kept, so
-    one compiled program covers every fill level of the prefill batch.
+    whole KV row into its assigned pool slot, in place
+    (:func:`ops.cache_write.inject_rows`: a leaf's slot row a loop trip).
+    Invalid (padding) rows are computed but written nowhere — the pool
+    row is read back and kept, so one compiled program covers every fill
+    level of the prefill batch.
 
     ``startno`` (B,) is each row's sampling-step offset: 0 for a fresh
     request (fold_in(key, 0), the original behavior), k for a
@@ -304,7 +307,6 @@ def _prefill_inject_impl(model, params, pool_cache, prompts, lengths,
     """
     storage = pool_cache
     pool_cache = dense_storage_values(model, storage)
-    B_pf = prompts.shape[0]
     with jax.named_scope("prefill/forward"):
         pf_cache, last = _prefill_impl(model, params, prompts, lengths,
                                        adapter_ids)
@@ -318,33 +320,10 @@ def _prefill_inject_impl(model, params, pool_cache, prompts, lengths,
     # recurrent state is (B, N, D) — every such leaf is injected whole.
     # Shared bookkeeping (cache_index) the per-row kv_positions path
     # never reads: keep pool's.
-    layout = cache_layout(model, pool_cache)
-    num_slots = next(
-        leaf.shape[decl.slot_axis] for leaf, decl in zip(
-            jax.tree_util.tree_leaves(pool_cache),
-            jax.tree_util.tree_leaves(layout)) if decl.per_slot)
-
-    # slot_map[s] = the pf row writing pool slot s, or -1 to keep the
-    # pool row. Invalid (padding) rows scatter to a dropped out-of-range
-    # index; valid slots are unique (pool invariant), so one gather +
-    # select per leaf does the whole injection — no per-row update chain.
-    def inject(pool, pf, decl):
-        if not decl.per_slot:
-            return pool
-        gathered = jnp.take(pf, jnp.maximum(slot_map, 0),
-                            axis=decl.slot_axis)
-        mask_shape = [1] * pool.ndim
-        mask_shape[decl.slot_axis] = num_slots
-        return jnp.where(keep.reshape(mask_shape), pool, gathered)
-
     with jax.named_scope("prefill/kv_inject"):
-        scatter_idx = jnp.where(valid, slots, num_slots)
-        slot_map = jnp.full((num_slots,), -1, jnp.int32).at[
-            scatter_idx].set(jnp.arange(B_pf, dtype=jnp.int32),
-                             mode="drop")
-        keep = slot_map < 0
-        pool_cache = jax.tree_util.tree_map(inject, pool_cache, pf_cache,
-                                            layout)
+        pool_cache = inject_rows(pool_cache, pf_cache,
+                                 cache_layout(model, pool_cache), slots,
+                                 valid)
     return dense_storage_commit(model, storage, pool_cache), first
 
 

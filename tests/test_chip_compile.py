@@ -161,6 +161,41 @@ def test_quantized_matmul_k_tiled_compiles(one_chip, bits, proj):
 
 
 # --------------------------------------------------------------------- #
+# the decode step's cache write (serve path): ops/cache_write.py
+# --------------------------------------------------------------------- #
+#: (B, L, H, D, T): closed40's K/V, reason48's rings and layer 17's K/V
+#: (bf16 pools as the cells hold them), a float32 pool, a verify block
+_POOLS = {
+    "closed40": (40, 1024, 20, 64, 1, jnp.bfloat16),
+    "reason48_ring": (48, 512, 20, 64, 1, jnp.bfloat16),
+    "reason48_full": (48, 2048, 20, 64, 1, jnp.bfloat16),
+    "f32_verify": (8, 1024, 12, 64, 5, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("pool", sorted(_POOLS))
+def test_cache_write_rows_compiles_in_place(one_chip, pool, monkeypatch):
+    """One kernel call a position for K and V together, the donated
+    leaves aliased through it: the program holds no second copy of a
+    leaf (the transposes around the kernel are bitcasts of the chip's
+    position-minor layout)."""
+    from ray_lightning_tpu.ops.cache_write import write_rows
+    # the helper asks the backend whether to interpret; the test steers
+    # it (on-chip-measurement guide), the program has no option for it
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    S = _spec(one_chip)
+    Bp, L, Hp, Dp, T, dtype = _POOLS[pool]
+    leaf, block = S((Bp, L, Hp, Dp), dtype), S((Bp, T, Hp, Dp), dtype)
+    compiled = jax.jit(write_rows, donate_argnums=0).lower(
+        (leaf, leaf), (block, block), S((Bp,), jnp.int32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= T
+    mem = compiled.memory_analysis()
+    leaf_bytes = Bp * L * Hp * Dp * jnp.dtype(dtype).itemsize
+    assert mem.alias_size_in_bytes == 2 * leaf_bytes
+    assert mem.temp_size_in_bytes < leaf_bytes // 8
+
+
+# --------------------------------------------------------------------- #
 # the flagship forward the driver compile-checks: __graft_entry__.entry
 # --------------------------------------------------------------------- #
 def test_gpt2_small_forward_compiles_and_fits(one_chip):
@@ -192,7 +227,8 @@ def test_gpt2_small_forward_compiles_and_fits(one_chip):
 # engine programs of benchmark cell phi4-mini-flash-reasoning.serve.reason48
 # --------------------------------------------------------------------- #
 @pytest.mark.parametrize("program", ["step", "prefill"])
-def test_sambay_engine_programs_compile_and_fit(one_chip, program):
+def test_sambay_engine_programs_compile_and_fit(one_chip, program,
+                                                monkeypatch):
     """Hidden 2560, 40 / 20 heads of 64, MLP 10240, window 512, the whole
     200064-row vocabulary, 48 slots of 2048 positions — at 8 layers (one
     period: every one of the five mixers, the memory, the shared K/V), a
@@ -201,6 +237,9 @@ def test_sambay_engine_programs_compile_and_fit(one_chip, program):
     lower for a v5e; the program must fit its 16 GB."""
     from ray_lightning_tpu.models.sambay import SambaYConfig, SambaYLM
     from ray_lightning_tpu.serve import engine as E
+    # the step's cache write (ops/cache_write.py) compiles as the chip's
+    # kernel, not as its interpretation
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     S = _spec(one_chip)
     slots, rows, plen = 48, 4, 256
 
@@ -232,6 +271,11 @@ def test_sambay_engine_programs_compile_and_fit(one_chip, program):
                 S((rows,), jnp.bool_), S((rows, 2), jnp.uint32),
                 S((rows,), jnp.float32), S((rows,), jnp.int32),
                 S((rows,), jnp.int32), None).compile()
+    # these 8 layers hold two windowed and the full self-attention
+    # layer: each writes its K and V through one kernel call; the
+    # prefill writes whole rows and holds none
+    assert compiled.as_text().count("tpu_custom_call") == \
+        (3 if program == "step" else 0)
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
