@@ -45,8 +45,9 @@ def main():
                             num_samples=128, lr=args.lr)
         epochs, workers = 1, args.num_workers or 2
     else:
-        # bf16 activations + remat: the measured-fastest BERT-base config
-        # on v5e (see bench.py) — full fp32 master weights in the opt state
+        # bf16 activations + remat: the BERT-base config the round-4/5
+        # sweeps settled on (docs/performance.md; no cell measures it) —
+        # full fp32 master weights in the opt state
         cfg = bert_config("base", vocab_size=30522,
                           max_seq_len=args.seq_len, dtype=jnp.bfloat16,
                           remat=True,

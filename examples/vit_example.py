@@ -3,9 +3,9 @@
 Model-zoo breadth beyond the reference (its examples cover MLP/CNN/GPT
 seats; see ``ray_lightning/examples/``): a ViT classifier on the shared
 ``TransformerStack``, data-parallel over the mesh. Ships the round-5
-measured defaults — ``vit_config`` rematerializes with the ``save_attn``
-policy (+30% samples/s at base/224 on v5e; ``docs/performance.md``
-"Model-zoo lever sweep").
+defaults — ``vit_config`` rematerializes with the ``save_attn`` policy
+(why: ``docs/performance.md`` "Model-zoo lever sweep"; no cell of the
+benchmark measures it).
 
     python examples/vit_example.py --num-workers 4 --max-epochs 3
 

@@ -2,8 +2,8 @@
 
 The in-process :class:`~ray_lightning_tpu.serve.fleet.ReplicaFleet`
 interleaves every replica's dispatch turns on ONE driver thread, so N
-replicas time-slice one core's worth of dispatch — measured fleet
-throughput is ~0.5× a single engine (``docs/performance.md``). This
+replicas time-slice one core's worth of dispatch and never beat one
+engine on one host (``docs/performance.md``, round 16). This
 module is the replica body for the **process backend**
 (``ReplicaFleet(backend="process")``): the same launcher/actor machinery
 the training gangs use (:class:`~...launchers.process_backend.ProcessRay`
@@ -310,7 +310,7 @@ class ServeReplicaWorker:
         if fault_plan is not None:
             # the driver's armed FaultPlan crosses the construct pickle
             # so worker-side engines fire the same sites (chaos drills
-            # and the bench's poison leg hold on this backend); arming
+            # and the containment tests hold on this backend); arming
             # here is per-process — it cannot leak into other workers
             from ray_lightning_tpu.reliability import faults
             faults.ensure_armed(fault_plan)

@@ -8,9 +8,9 @@ production LM server (vLLM, TGI, JetStream) made canonical:
    block lands in the KV cache via ``dynamic_update_slice`` under an
    intra-prompt causal mask, and the last-position logits come back.
    Prompt cost is one matmul-rich pass instead of P sequential
-   ~per-token dispatches (measured ≥5× at P=512; see
-   ``docs/performance.md`` decode section and ``bench.py``'s
-   ``prefill_tokens_per_sec``).
+   ~per-token dispatches (history and reasons: the
+   ``docs/performance.md`` decode section; today's levels: ``PERF.md``,
+   the ``*.serve.*`` cells).
 2. **Decode** (tokens-only ``lax.scan``): exactly ``max_new_tokens - 1``
    cached single-token steps (the first new token is sampled from the
    prefill logits), jitted with ``donate_argnums`` on the cache and
@@ -150,7 +150,7 @@ def sample_logits_rows(logits: jax.Array, keys: jax.Array,
 
     The expensive machinery is gated at the BATCH level with ``lax.cond``
     (outside the vmap, so XLA executes one branch at runtime): an
-    all-greedy batch — the tracked serving bench, and any temperature=0
+    all-greedy batch — the greedy identity tests, and any temperature=0
     deployment — pays one argmax, no per-row categorical; the full-vocab
     argsort additionally engages only when some row actually restricts
     top_k. Per-row greedy/sampled mixing stays inside the sampled branch.

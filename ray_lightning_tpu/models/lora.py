@@ -386,7 +386,8 @@ def zero_adapter(params, index: int):
 def adapter_bytes(params) -> int:
     """Exact bytes ONE resident adapter occupies across every bank in
     ``params`` (total bank bytes / num_adapters — the registry's
-    accounting unit and the bench's enforced floor)."""
+    accounting unit; ``tests/test_lora.py`` holds the bank to
+    capacity times this)."""
     total = 0
     slots = None
     for _path, proj in _walk_targets(params, LORA_TARGETS):

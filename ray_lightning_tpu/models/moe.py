@@ -236,11 +236,11 @@ class MoeModule(TpuModule):
         return MoeTransformerLM(self.cfg)
 
     def configure_optimizers(self):
-        # ``optimizer="adafactor"`` measured +15.6% samples/s on the chip
-        # for an 8-expert/8-layer MoE LM (interleaved A/B, tools/
-        # ab_sweep.py): top-k routing touches 1/k of the expert FLOPs per
-        # step but the optimizer updates EVERY expert param, so state
-        # traffic is a larger share than on dense models. Kept opt-in
+        # ``optimizer="adafactor"`` is the lever for MoE: top-k routing
+        # touches 1/k of the expert FLOPs per step but the optimizer
+        # updates EVERY expert param, so state traffic is a larger share
+        # than on dense models (round-5 sweep: docs/performance.md
+        # "Model-zoo lever sweep"; no cell measures it). Kept opt-in
         # (default adamw) because switching optimizer families is a
         # modeling decision — see core/optim.py.
         from ray_lightning_tpu.core.optim import make_optimizer

@@ -89,9 +89,9 @@ __all__ = ["quantized_matmul", "unpack_int4_block", "kernel_calls"]
 DEFAULT_TILE_N = 512
 DEFAULT_TILE_M = 256
 
-#: trace-time counter of kernel instantiations — the bench's witness
-#: that a "fused" leg actually armed the kernel (a cached program does
-#: not retrace, so snapshot it before the first compile of the leg)
+#: trace-time counter of kernel instantiations — the tests' witness
+#: that a "fused" engine actually armed the kernel (a cached program does
+#: not retrace, so snapshot it before the engine's first compile)
 _KERNEL_CALLS = 0
 
 

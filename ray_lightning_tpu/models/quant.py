@@ -1,7 +1,7 @@
 """Weight-only int8/int4 quantization: cut the decode param stream.
 
 Decode is parameter-bandwidth-bound: every target pass streams the full
-parameter set once (the bench's param-bandwidth honesty floor measures
+parameter set once (``serve.decode_step_roofline`` in ``PERF.md`` counts
 exactly this), so at-rest weight bytes ARE per-token bytes. This module
 shrinks them with **storage-only** quantization — the same contract as
 the int8 KV arena (``serve/pages.py``): weights live in HBM as integer
@@ -48,8 +48,8 @@ the stream and their precision is disproportionately load-bearing.
 :func:`param_bytes` is the exact at-rest byte accounting for either
 representation, computed from shapes/dtypes only (works on
 ``jax.eval_shape`` outputs — pure accounting callers never allocate),
-and is what the bench's equal-byte comparisons and param-bandwidth
-honesty floor are required to cite instead of dtype arithmetic.
+and is what equal-byte comparisons and a param-bandwidth
+roofline are required to cite instead of dtype arithmetic.
 
 KV-cache quantization (:func:`kv_scales` / :func:`kv_quantize` /
 :func:`kv_dequantize`) lives here too: it is the same absmax machinery
@@ -384,7 +384,7 @@ def param_bytes(params) -> int:
     """Exact at-rest parameter bytes for a plain OR quantized tree,
     from shapes/dtypes only (no device reads — pass ``jax.eval_shape``
     structs for configs that were never materialized). This is the
-    number the bench's param-bandwidth honesty floor and equal-byte
+    number a param-bandwidth roofline and equal-byte
     comparisons must cite: dtype arithmetic (``2 * n_params``) goes
     stale the moment storage and compute dtypes diverge."""
     total = 0

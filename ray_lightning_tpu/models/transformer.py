@@ -86,8 +86,8 @@ class TransformerConfig:
     # (docs/serving.md for the contract).
     matmul_kernel: str = "xla"       # xla | pallas
     # f32 (default) is the numerically-safe softmax; bf16 halves the
-    # (B,H,T,T) score-tensor HBM traffic — +13% measured on the GPT-2
-    # bench step (v5e) at ~1% attention-weight rounding. Only the 'dot'
+    # (B,H,T,T) score-tensor HBM traffic (round-3 history:
+    # docs/performance.md) at ~1% attention-weight rounding. Only the 'dot'
     # and 'ulysses' impls consume it; flash/ring keep f32 accumulators
     # by construction (their running max/denominator live in registers,
     # not HBM, so there is nothing to save).
@@ -606,7 +606,7 @@ class MultiHeadAttention(nn.Module):
                 # re-round a page's other entries whenever its absmax
                 # carrier moves) — int8 page-native vs dense-gather token
                 # identity is therefore EMPIRICAL (bounded extra rounding
-                # vs argmax margins, pinned on the test/bench configs incl.
+                # vs argmax margins, pinned on the test configs incl.
                 # steps_per_dispatch>1), not structural like the
                 # full-precision case
                 g = jnp.clip(pidx, 0, P - 1)

@@ -43,12 +43,12 @@ def vit_config(size: str = "tiny", image_size: int = 32,
                 max_seq_len=n_patches + 1,  # +1 CLS
                 d_model=d_model, n_heads=n_heads, n_layers=n_layers,
                 d_ff=4 * d_model, causal=False,
-                # remat + save_attn ships as the ViT default: measured
-                # +30% samples/s at base/224/bs32 on v5e (interleaved
-                # A/B, tools/ab_sweep.py — saving every activation costs
-                # more HBM write traffic than the backward recompute) and
-                # is semantics-preserving. Override with remat=False to
-                # trade throughput for compile simplicity.
+                # remat + save_attn ships as the ViT default: saving
+                # every activation costs more HBM write traffic than the
+                # backward recompute (round-5 sweep at base/224/bs32:
+                # docs/performance.md "Model-zoo lever sweep"; no cell
+                # measures it) and is semantics-preserving. Override with
+                # remat=False to trade throughput for compile simplicity.
                 remat=True,
                 remat_policy="dots_with_no_batch_dims_save_attn")
     base.update(overrides)

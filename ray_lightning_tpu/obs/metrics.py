@@ -12,8 +12,8 @@ Prometheus trinity:
   distributions, PLUS a bounded reservoir of raw samples so quantiles are
   *exact* (numpy-``percentile``-identical linear interpolation) until the
   reservoir cap, and bucket-interpolated after it. This is the single
-  quantile implementation in the repo: ``bench.py``'s serve p50/p99/TTFT
-  and the production serving metrics report through the same class.
+  quantile implementation in the package: every serving metric that
+  reports a p50/p99/TTFT goes through this class.
 
 :class:`MetricsRegistry` is the name → instrument map with ``snapshot()``
 (plain dict for tests/driver transport) and ``prometheus_text()`` (the

@@ -2,8 +2,8 @@
 
 The naive path materializes the full ``(B*T, V)`` logits tensor — at GPT-2
 scale (vocab 50k) that is gigabytes per step and becomes the batch-size
-wall long before the transformer blocks do (measured on a v5e chip: the
-flagship bench OOMs at batch 32 x seq 512 with materialized logits, while
+wall long before the transformer blocks do (round 3 on a v5e chip:
+GPT-2-small OOMs at batch 32 x seq 512 with materialized logits, while
 the blocks alone fit comfortably at batch 64).
 
 This op scans over token chunks: each chunk computes its logits slice on

@@ -8,7 +8,7 @@ production stack needs *between* "error raised" and "request failed":
 - :mod:`~ray_lightning_tpu.reliability.faults` — a seedable
   :class:`FaultPlan` that injects failures (raise / NaN-poison / stall)
   at named sites by dispatch index, so chaos paths are exercised
-  deterministically from tests and the bench. Zero overhead when no plan
+  deterministically from tests. Zero overhead when no plan
   is armed.
 - :mod:`~ray_lightning_tpu.reliability.retry` — :class:`RetryPolicy`
   (bounded attempts, exponential backoff, deterministic jitter, optional

@@ -1,9 +1,9 @@
 """Elastic gang recovery: warm standbys and in-memory checkpoint tiers.
 
 PR 5's gang supervision made distributed-fit failures *detected* in
-bounded time, but recovery stayed respawn-dominated (~9 s in
-``BENCH_r05`` ``gang_recovery_ms``, almost all of it actor spawn +
-interpreter + jax import + backend init) and locked to a fixed world
+bounded time, but recovery stayed respawn-dominated (almost all of it
+actor spawn + interpreter + jax import + backend init) and locked to a
+fixed world
 size: losing one worker of N cost a full cold restart at exactly N.
 This module supplies the two recovery tiers that take both costs off
 the critical path (ROADMAP item 4 — TorchElastic / Elastic Horovod in

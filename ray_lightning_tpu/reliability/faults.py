@@ -5,8 +5,7 @@ Chaos testing on XLA's terms: failures must be *replayable*. A
 ``(site, tick)`` — the ``tick`` is the 0-based count of times that site
 has fired since the plan was armed, NOT wall time — so the same plan
 against the same workload injects the same failures at the same program
-points every run. Tests pin exact recovery behavior; the chaos bench
-pins recovery cost.
+points every run. Tests pin exact recovery behavior.
 
 Sites are woven into the hot paths as a single ``fire(site)`` call:
 
@@ -47,8 +46,7 @@ Sites are woven into the hot paths as a single ``fire(site)`` call:
                       ``ProcessReplicaFleet.tick()`` — ``raise``
                       crashes the DRIVER itself (the propagating
                       exception is the deterministic mid-decode driver
-                      kill the warm-restart tests and the
-                      ``driver_restart`` chaos bench replay from a
+                      kill the warm-restart tests replay from a
                       journal), ``stall`` wedges one driver tick.
                       Fleet-member clients and spawned serve workers
                       never fire it: their ticks are replica turns,

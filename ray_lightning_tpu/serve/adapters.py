@@ -33,7 +33,8 @@ OccupancyError` base.
 Eviction is **deterministic**: least-recently-*bound* resident with a
 zero refcount, ties broken by load order (an :class:`collections.
 OrderedDict` walk). Same load/bind sequence → same evictee, always —
-pinned by the bench's eviction-under-pressure check.
+pinned by ``test_registry_lru_eviction_is_deterministic``
+(``tests/test_lora.py``).
 """
 from __future__ import annotations
 

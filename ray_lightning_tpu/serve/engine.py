@@ -1076,8 +1076,8 @@ class ServeEngine:
     def adapter_bank_bytes(self) -> int:
         """Exact at-rest device bytes of the full adapter bank
         (``capacity * per-adapter slice`` from
-        :func:`~ray_lightning_tpu.models.lora.adapter_bytes`) — the
-        bench's enforced accounting floor."""
+        :func:`~ray_lightning_tpu.models.lora.adapter_bytes`) — held
+        to that product by ``tests/test_lora.py``."""
         if self._registry is None:
             return 0
         return self._registry.capacity * self._registry.bytes_per_adapter
@@ -1905,7 +1905,7 @@ class ServeEngine:
             # the dense-gather path up to reduction-order rounding
             # (int8 arenas: plus per-token page requant rounding —
             # docs/serving.md caveat); pinned by tests/test_paged.py
-            # and the bench's enforced 0-mismatch gate. The write
+            # ::test_page_native_matches_dense_gather. The write
             # mask comes from the SYNCED frontier: a row that
             # retired inside a still-pending dispatch keeps its
             # entries one extra dispatch and re-writes its frozen

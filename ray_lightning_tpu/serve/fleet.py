@@ -628,7 +628,7 @@ class ReplicaFleet:
         # it
         self._target_replicas = num_replicas
 
-        # reliability accounting (the bench's failover cost source)
+        # reliability accounting (failover counts)
         self.failovers = 0
         self.readmitted = 0
         self.readmit_failed = 0

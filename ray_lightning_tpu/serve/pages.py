@@ -332,7 +332,7 @@ class PagePool:
     ``kv_positions`` path never reads them, and the chunk program
     overrides them per dispatch. The arena is built lazily on first
     access so pure accounting users (admission planning, the capacity
-    bench) never allocate device memory.
+    tests) never allocate device memory.
 
     ``page_table`` is the ``(num_slots, pages_per_slot)`` int32 gather
     index (−1 = unmapped); refcounts make pages shareable: an adopted
@@ -439,7 +439,7 @@ class PagePool:
         """At-rest bytes one arena page costs across every KV leaf
         (int8: codes + the per-page-per-head f32 scales). Computed from
         shapes only — pure accounting callers (the equal-byte capacity
-        bench/tests) never allocate the arena."""
+        tests) never allocate the arena."""
         axis = page_axis(self._model)
         total = 0
         template = self._arena_template(shapes_only=True)

@@ -12,7 +12,7 @@ gangs use:
   :class:`~ray_lightning_tpu.launchers.process_backend.ProcessRay`
   actor, driving its OWN dispatch loop — N replicas really dispatch N
   engines concurrently (the in-process fleet time-slices one thread,
-  which is why its measured throughput is ~0.5× a single engine);
+  which is why it never beats a single engine on one host);
 - submits are RPCs returning structured verdicts; completions, token
   progress, occupancy mirrors, and obs events flow back over ONE
   manager-hosted queue (the existing queue transport — it pickles by
@@ -251,7 +251,7 @@ class ProcessReplicaFleet(ReplicaFleet):
     switch in ``ReplicaFleet.__new__`` lands here; ``isinstance(fleet,
     ReplicaFleet)`` holds). Same public surface: ``submit`` /
     ``serve_trace`` / ``run_until_idle`` / ``tick`` / ``shutdown`` plus
-    the reliability counters the bench reads. See the module docstring
+    the reliability counters the tests read. See the module docstring
     for the transport/failover design and ``docs/serving.md`` for
     backend selection guidance.
 
@@ -442,8 +442,8 @@ class ProcessReplicaFleet(ReplicaFleet):
 
     @property
     def replica_steps(self) -> Dict[int, int]:
-        """Per-replica dispatch-turn counts from the latest beats — the
-        bench's per-replica utilization source."""
+        """Per-replica dispatch-turn counts from the latest beats — a
+        per-replica utilization source."""
         return {rep.id: rep.last_step for rep in self._replicas}
 
     @property
@@ -479,7 +479,7 @@ class ProcessReplicaFleet(ReplicaFleet):
             heartbeat_interval=hb_interval,
             # ship the driver's armed fault plan (if any) so worker-side
             # engines fire the same sites — chaos drills (and the
-            # poison leg of the bench) hold identically on this backend
+            # containment tests) hold identically on this backend
             fault_plan=faults.get_armed(),
             # real worker-side spans (MSG_SPAN) only when the driver is
             # armed: a disarmed fleet's workers keep the no-op span

@@ -10,8 +10,8 @@ retires the moment it hits eos or exhausts its budget, and its KV slot is
 handed to the next queued request mid-flight.
 
 A :class:`Completion` is the retired request: the generated tokens (eos
-included when sampled), why it stopped, and the latency breakdown the
-serving bench aggregates into p50/p99.
+included when sampled), why it stopped, and the latency breakdown a
+load harness aggregates into p50/p99.
 """
 from __future__ import annotations
 
@@ -152,7 +152,7 @@ class Completion:
     # prompt tokens served from the shared-prefix KV cache (paged
     # engines with prefix_cache=True; 0 otherwise)
     prefix_hit_tokens: int = 0
-    # the retiring request's tenant class (per-tenant obs + bench
+    # the retiring request's tenant class (per-tenant obs
     # aggregation key; DEFAULT_TENANT without tenancy configured)
     tenant: str = DEFAULT_TENANT
     # the adapter this request actually decoded under (after any

@@ -24,8 +24,8 @@ decoding first drains in-flight requests sooner but leaves slots idle.
 Chunk interleaving is deliberately NOT a knob: while decode rows are
 active, chunk and decode dispatches strictly alternate, so an in-flight
 request's worst decode stall is ONE chunk-sized dispatch (that bound is
-the whole point of chunked prefill — ``decode_stall_p99_ms`` in the
-bench); with nothing decoding, chunks stream back-to-back.
+the whole point of chunked prefill); with nothing decoding, chunks
+stream back-to-back.
 
 Admission is **page-aware** on paged engines: the scheduler pops only the
 queue-head prefix the engine can actually seat

@@ -128,7 +128,7 @@ def _spec_accept(L, draft_toks, draft_logits, cur, pos, active, remaining,
 
     def greedy_only():
         # all-greedy batch (temperature=0 everywhere — the default and
-        # the tracked bench regime): accept is an exact argmax match
+        # the identity tests' regime): accept is an exact argmax match
         # and every fix IS the argmax — no distributions, no draws.
         # Batch-level lax.cond, the same gate sample_logits_rows uses,
         # so the full-vocab softmax/argsort machinery below never

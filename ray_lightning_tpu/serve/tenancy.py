@@ -11,7 +11,7 @@ admitted, never *what tokens* it receives — every request's sample-key
 stream is ``fold_in(fold_in(engine_base, req.seed), step)``, a pure
 function of no scheduler state, so a request's tokens are identical to
 a solo run whatever classes ride the queue next to it (enforced by
-``tests/test_tenancy.py`` and the bench's tenancy gates).
+``tests/test_tenancy.py``).
 
 - :class:`TenantClass` — one traffic class: a priority **tier**
   (``interactive`` tiers drain before ``batch`` tiers), a fair-share

@@ -24,8 +24,9 @@ COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 def enable_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache and return its
     directory — the one place this repo decides where compiled programs
-    are kept (entry points call it: ``chip_smoke.py``, ``bench.py``, the
-    CLI, the examples; the library never does on import).
+    are kept (entry points call it: ``chip_smoke.py``,
+    ``benchmark/run.py``, the CLI, the examples; the library never does
+    on import).
 
     If ``JAX_COMPILATION_CACHE_DIR`` is set, that directory is used and
     no other is set in code. If not, the cache lives at one fixed path

@@ -35,8 +35,8 @@ if "xla_force_host_platform_device_count" not in flags:
 # (level 0 / JAX_DISABLE_MOST_OPTIMIZATIONS is NOT better: it also kills
 # fusion, and exec-heavy gates like test_bert_trains pay +70%). All 284
 # tests pass identically — the level changes schedule, not semantics.
-# Real-hardware tiers (tests/test_tpu.py, bench.py) restore the original
-# env and compile at full optimization.
+# The real-hardware tier (tests/test_tpu.py) restores the original env
+# and compiles at full optimization.
 if "xla_backend_optimization_level" not in flags:
     flags = (flags + " --xla_backend_optimization_level=1").strip()
 os.environ["XLA_FLAGS"] = flags

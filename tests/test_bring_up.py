@@ -9,11 +9,10 @@ device it runs on.
   one fixed in-checkout path, exported for spawned workers.
 - the process-fleet worker default names no platform and no XLA flags.
 - Pallas kernels choose interpret mode from the backend and never on a
-  TPU one; ``bench.py`` refuses a device it has no published peak for.
+  TPU one.
 - ``ServeEngine.lowered_step_text`` shows which kernels a step program
   really holds (``chip_smoke.py`` reads ``tpu_custom_call`` from it).
 """
-import importlib.util
 import logging
 import os
 
@@ -98,7 +97,7 @@ def test_no_other_cache_directory_is_set_in_code():
     """One helper owns the decision: nothing else in the program names a
     compilation-cache directory (tests/conftest.py keeps its setdefault)."""
     sources = [os.path.join(REPO, f) for f in
-               ("bench.py", "chip_smoke.py", "__graft_entry__.py")]
+               ("chip_smoke.py", "__graft_entry__.py")]
     for root in ("ray_lightning_tpu", "examples", "tools"):
         for dirpath, _, files in os.walk(os.path.join(REPO, root)):
             sources += [os.path.join(dirpath, f) for f in files
@@ -126,7 +125,7 @@ def test_default_worker_env_names_no_platform_or_optimisation_level():
 
 
 # --------------------------------------------------------------------- #
-# kernels and bench: no quiet fallback on a TPU backend
+# kernels: no quiet fallback on a TPU backend
 # --------------------------------------------------------------------- #
 @pytest.mark.parametrize("backend,interpret", [("tpu", False),
                                                ("cpu", True)])
@@ -135,51 +134,6 @@ def test_pallas_interpret_mode_follows_the_backend(monkeypatch, backend,
     from ray_lightning_tpu.models import pallas_attention
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     assert pallas_attention.interpret_default() is interpret
-
-
-@pytest.fixture(scope="module")
-def bench():
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(REPO, "bench.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_bench_refuses_a_device_without_a_published_peak(bench):
-    cpu = jax.devices()[0]
-    for lookup in (bench._chip_peak_flops, bench._hbm_bandwidth):
-        with pytest.raises(bench.MeasurementError,
-                           match="unknown device_kind"):
-            lookup(cpu)
-    assert bench._device_fields(cpu) == {"platform": "cpu",
-                                         "device_kind": cpu.device_kind}
-
-
-def test_bench_failed_leg_is_recorded_and_named(bench):
-    """A leg that raises is named in ``failed`` (main exits 1 on a
-    non-empty list); the legs after it still run."""
-    legs = bench._Legs()
-
-    def boom():
-        raise bench.MeasurementError("timing collapsed")
-    assert legs.run("decode", boom) is False
-    assert legs.run("obs", lambda: {"ok": 1}) is True
-    sub = {}
-    assert legs.run("spec", boom, sub) is False
-    assert legs.failed == ["decode", "spec"]
-    assert legs.extras == {
-        "decode": {"error": "MeasurementError: timing collapsed"},
-        "obs": {"ok": 1}}
-    assert "error" in sub["spec"]
-
-
-def test_bench_step_flops_does_not_swallow_failures(bench):
-    class Broken:
-        def lower(self, *a):
-            raise ValueError("lowering failed")
-    with pytest.raises(ValueError, match="lowering failed"):
-        bench._step_flops(Broken(), None, None)
 
 
 # --------------------------------------------------------------------- #

@@ -362,48 +362,44 @@ def test_worker_cap_leaves_consumer_core(monkeypatch):
         assert loader.uses_ring is True
 
 
-class _SleepyLoader:
-    """Producer work modeled as GIL-releasing sleep (decode/IO stand-in):
-    overlap across producer processes hides it; in-process it serializes.
-    Module-level so the spawn context can pickle it."""
+class _PidStampLoader:
+    """Yields ``(x, y, pid)`` batches, ``pid`` being the process that
+    assembled the batch. Module-level so a spawn context could pickle
+    it."""
 
-    def __init__(self, n_batches: int = 8, delay: float = 0.05):
+    def __init__(self, n_batches: int = 16):
         self.n_batches = n_batches
-        self.delay = delay
 
     def __len__(self):
         return self.n_batches
 
     def __iter__(self):
-        import time as _t
         for i in range(self.n_batches):
-            _t.sleep(self.delay)
             yield (np.full((4, 4), i, dtype=np.float32),
-                   np.full((4,), i, dtype=np.int32))
+                   np.full((4,), i, dtype=np.int32),
+                   np.full((1,), os.getpid(), dtype=np.int64))
 
 
 @needs_native
-@pytest.mark.skipif((os.cpu_count() or 1) < 3,
-                    reason="overlap needs >= 3 host cores (2 producers + "
-                           "consumer); CI runners have them")
-def test_ring_overlap_beats_inprocess_on_multicore():
-    """The ring's reason to exist: with spare cores, producer processes
-    overlap the per-batch work and beat in-process loading. Sleep-based
-    work keeps the measurement robust on loaded CI machines."""
-    import time as _t
-
-    def rate(loader):
-        t0 = _t.perf_counter()
-        n = sum(1 for _ in loader)
-        return n / (_t.perf_counter() - t0)
-
-    inline = rate(_SleepyLoader(n_batches=16))
-    # fork, like the bench: spawn would re-import jax in each producer and
-    # count ~seconds of startup against the 0.8s workload; the children
-    # touch only the ring + numpy, the documented fork-safe envelope
-    mp_loader = MultiprocessDataLoader(_SleepyLoader(n_batches=16),
-                                       num_workers=2, mp_context="fork")
-    assert mp_loader.uses_ring
-    ring = rate(mp_loader)
-    # 2 producers hide ~half the sleep; demand a clear win, not 2x exactly
-    assert ring > inline * 1.3, (inline, ring)
+def test_ring_two_producers_stripe_the_inprocess_sequence():
+    """What the ring holds without a clock: two producer processes each
+    assemble their stripe (``_worker_batches``: worker ``w`` takes batches
+    ``w, w+2, …``), and the consumer's round-robin hands back the
+    in-process loader's sequence, bit-equal and in order — the ordering
+    ``MultiprocessDataLoader``'s module docstring documents."""
+    inline = list(_PidStampLoader())
+    # fork: spawn would re-import jax in each producer; the children touch
+    # only the ring + numpy, the documented fork-safe envelope.
+    # auto_fallback=False: two workers whatever the host's core count
+    mp_loader = MultiprocessDataLoader(_PidStampLoader(), num_workers=2,
+                                       mp_context="fork",
+                                       auto_fallback=False)
+    assert mp_loader.uses_ring and mp_loader.num_workers == 2
+    ring = list(mp_loader)
+    assert len(ring) == len(inline) == 16
+    for (rx, ry, _), (ix, iy, _) in zip(ring, inline):
+        np.testing.assert_array_equal(rx, ix)
+        np.testing.assert_array_equal(ry, iy)
+    pids = [int(b[2][0]) for b in ring]
+    assert pids[0] != pids[1] and os.getpid() not in pids
+    assert pids == [pids[0], pids[1]] * 8

@@ -270,6 +270,9 @@ def test_matmul_matches_xla_engine(nano, weight_dtype):
                          matmul_kernel="pallas", **kw)
     assert is_quantized(client.engine.params)
     assert param_bytes(client.engine.params) < 0.6 * param_bytes(params)
+    # ... and exactly the bytes of the codes+scales the xla engine holds
+    assert param_bytes(client.engine.params) == param_bytes(quantize_params(
+        params, weight_dtype, group_size=kw.get("weight_group_size")))
     out = client.serve_trace(list(TRACE))
     client.shutdown()
     # trace-witness binds on the first in-process compile of these
