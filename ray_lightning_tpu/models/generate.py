@@ -129,6 +129,72 @@ def sample_logits(logits: jax.Array, rng: jax.Array,
     return jax.random.categorical(rng, logits, axis=-1).astype(jnp.int32)
 
 
+#: How many candidates a row's ``top_k`` mask is built from. A batch
+#: whose largest ``top_k`` is at most this takes ``lax.top_k(l, K)`` and
+#: keeps the first ``top_k`` of the candidates; a batch with a wider row
+#: ranks the whole vocabulary (:func:`processed_logits_row`). 64 covers
+#: HF ``generate``'s default 50 and GPT-2's published 40.
+TOP_K_CANDIDATES = 64
+
+
+def processed_logits_row(l: jax.Array, t: jax.Array, tk: jax.Array,
+                         top_k_path: Optional[str]) -> jax.Array:
+    """What one row's categorical draws from: ``l`` (V,) scaled by the
+    row's temperature ``t`` (0 = greedy: left unscaled) with everything
+    outside the row's ``tk`` highest logits masked to ``finfo.min``
+    (``tk == 0`` = unrestricted). The one definition shared by
+    :func:`sample_logits_rows` and the speculative accept test
+    (``serve/spec.py::_row_probs``), so p and q are what the sampler drew
+    from.
+
+    ``tk`` is traced, so ``lax.top_k`` cannot take it. ``top_k_path``
+    (static; :func:`top_k_dispatch` picks it from the batch) says how
+    the keep set is found. ``"narrow"`` takes the ``TOP_K_CANDIDATES``
+    highest (valid while ``tk <= TOP_K_CANDIDATES``) and keeps what lies
+    above the ``tk``-th of them, plus the logits equal to it up to its
+    index — ``lax.top_k`` puts the lower index first among equal values,
+    so the ``tk``-th candidate is the last tie kept. Written as compares
+    over ``[V]``, not as a scatter of the candidates' indices: inside
+    the decode step program the scatter costs 5 ms of a 23 ms step on a
+    v5e (``PERF.md`` section 6, PR 31). ``"wide"`` ranks the whole
+    vocabulary by a stable descending argsort; ``None`` applies no mask.
+    Both keep exactly ``tk`` tokens, the lower index first among equal
+    logits, so they return the same row wherever both apply."""
+    with jax.named_scope("sample/temperature"):
+        scaled = l / jnp.where(t > 0, t, 1.0)
+    if top_k_path is None:
+        return scaled
+    V = l.shape[0]
+    with jax.named_scope("sample/top_k"):
+        if top_k_path == "narrow":
+            K = min(TOP_K_CANDIDATES, V)
+            vals, idx = jax.lax.top_k(l, K)
+            last = jnp.clip(tk - 1, 0, K - 1)
+            kth, kth_at = vals[last], idx[last]
+            keep = (l > kth) | ((l == kth) & (jnp.arange(V) <= kth_at))
+        else:
+            order = jnp.argsort(-l)
+            ranks = jnp.zeros_like(order).at[order].set(
+                jnp.arange(V, dtype=order.dtype))
+            keep = ranks < tk
+        return jnp.where((tk > 0) & ~keep, jnp.finfo(jnp.float32).min,
+                         scaled)
+
+
+def top_k_dispatch(top_k: jax.Array, body):
+    """``body(top_k_path)`` under the batch-level ``lax.cond``s that pick
+    :func:`processed_logits_row`'s path from the input (outside any vmap,
+    so one branch executes): no mask when no row restricts ``top_k``, the
+    candidates when every row asks for at most ``TOP_K_CANDIDATES``, the
+    whole-vocabulary ranking only when some row asks for more."""
+    return jax.lax.cond(
+        jnp.any(top_k > 0),
+        lambda: jax.lax.cond(jnp.max(top_k) > TOP_K_CANDIDATES,
+                             lambda: body("wide"),
+                             lambda: body("narrow")),
+        lambda: body(None))
+
+
 def sample_logits_rows(logits: jax.Array, keys: jax.Array,
                        temperature: jax.Array,
                        top_k: jax.Array) -> jax.Array:
@@ -142,18 +208,18 @@ def sample_logits_rows(logits: jax.Array, keys: jax.Array,
     reproducible across slot assignments and batch compositions, and never
     shared between co-resident slots). ``temperature`` (B,) with 0 = greedy
     argmax for that row (bit-identical to :func:`sample_logits`'s greedy).
-    ``top_k`` (B,) int with 0 = unrestricted; a *traced* per-row k cannot
-    use ``lax.top_k`` (static k), so the mask comes from ranks of a
-    descending argsort — same "keep the k highest" semantics with k dynamic
-    (ties broken by sort order rather than kept, which only reweights
-    exactly-tied tail logits).
+    ``top_k`` (B,) int with 0 = unrestricted; exactly k tokens are kept
+    (ties at the k-th place broken by index rather than kept, which only
+    reweights exactly-tied tail logits) — :func:`processed_logits_row`.
 
     The expensive machinery is gated at the BATCH level with ``lax.cond``
     (outside the vmap, so XLA executes one branch at runtime): an
     all-greedy batch — the greedy identity tests, and any temperature=0
-    deployment — pays one argmax, no per-row categorical; the full-vocab
-    argsort additionally engages only when some row actually restricts
-    top_k. Per-row greedy/sampled mixing stays inside the sampled branch.
+    deployment — pays one argmax, no per-row categorical; the top_k mask
+    engages only when some row actually restricts top_k, and costs
+    ``TOP_K_CANDIDATES`` candidates a row unless a row asks for more
+    (:func:`top_k_dispatch`). Per-row greedy/sampled mixing stays inside
+    the sampled branch.
     """
     temperature = jnp.asarray(temperature, jnp.float32)
     top_k = jnp.asarray(top_k, jnp.int32)
@@ -162,19 +228,11 @@ def sample_logits_rows(logits: jax.Array, keys: jax.Array,
         with jax.named_scope("sample/greedy"):
             return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-    def rows_sampled(use_topk: bool):
+    def rows_sampled(top_k_path):
         def row(l, k, t, tk):
             with jax.named_scope("sample/greedy"):
                 greedy = jnp.argmax(l).astype(jnp.int32)
-            with jax.named_scope("sample/temperature"):
-                scaled = l / jnp.where(t > 0, t, 1.0)
-            if use_topk:
-                with jax.named_scope("sample/top_k"):
-                    order = jnp.argsort(-l)
-                    ranks = jnp.zeros_like(order).at[order].set(
-                        jnp.arange(l.shape[0], dtype=order.dtype))
-                    scaled = jnp.where((tk > 0) & (ranks >= tk),
-                                       jnp.finfo(jnp.float32).min, scaled)
+            scaled = processed_logits_row(l, t, tk, top_k_path)
             with jax.named_scope("sample/draw"):
                 sampled = jax.random.categorical(
                     k, scaled).astype(jnp.int32)
@@ -182,12 +240,9 @@ def sample_logits_rows(logits: jax.Array, keys: jax.Array,
 
         return jax.vmap(row)(logits, keys, temperature, top_k)
 
-    return jax.lax.cond(
-        jnp.any(temperature > 0.0),
-        lambda: jax.lax.cond(jnp.any(top_k > 0),
-                             lambda: rows_sampled(True),
-                             lambda: rows_sampled(False)),
-        rows_greedy)
+    return jax.lax.cond(jnp.any(temperature > 0.0),
+                        lambda: top_k_dispatch(top_k, rows_sampled),
+                        rows_greedy)
 
 
 def _check_decode_model(model, P: int, max_new_tokens: int = 0) -> None:

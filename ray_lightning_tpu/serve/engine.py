@@ -97,7 +97,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_lightning_tpu.models.generate import (_adapter_kw, _logits_only,
+from ray_lightning_tpu.models.generate import (TOP_K_CANDIDATES,
+                                               _adapter_kw, _logits_only,
                                                _prefill_impl, cache_layout,
                                                decode_step,
                                                decode_step_paged,
@@ -1473,7 +1474,8 @@ class ServeEngine:
                 ids=[r.id for r in batched], rows=len(batched),
                 tokens=int(lengths[:len(batched)].sum()),
                 program_tokens=self.prefill_batch * self.prefill_len,
-                **self._live_cache_bytes(lengths[:len(batched)]))
+                **self._live_cache_bytes(lengths[:len(batched)]),
+                **self._count_topk_wide(tel, top_k))
             m = tel.metrics
             m.counter("serve_prefill_rows_total",
                       help="requests admitted by batched prefill "
@@ -1691,6 +1693,7 @@ class ServeEngine:
                       "prefill_chunk per dispatch").inc(C)
         with (tel.span("engine.chunk.call", ids=[req.id], off=off,
                        tokens=valid, program_tokens=C, slot=st.slot,
+                       **self._count_topk_wide(tel, top_k),
                        **self._span_extra)
               if tel is not None else NULL_SPAN):
             self.pool.arena, first = fn(
@@ -1863,7 +1866,20 @@ class ServeEngine:
                   "the step program runs over)").inc(self.num_slots)
         return {"active": active, "slots": self.num_slots,
                 **self._live_cache_bytes(
-                    self._pos[self._active, 0].astype(np.int64) + 1)}
+                    self._pos[self._active, 0].astype(np.int64) + 1),
+                **self._count_topk_wide(tel, self._top_k)}
+
+    def _count_topk_wide(self, tel, top_k) -> Dict[str, int]:
+        """Armed only: whether the ``top_k`` operand of this dispatch
+        holds a row above ``TOP_K_CANDIDATES`` — the host's reading of
+        the predicate under which the program ranks the whole vocabulary
+        (``generate.top_k_dispatch``) — as a span arg and a counter."""
+        wide = int(top_k.max() > TOP_K_CANDIDATES)
+        tel.metrics.counter(
+            "serve_sample_topk_wide_total",
+            help="dispatches whose sampling ranked the whole vocabulary: "
+            "some row's top_k above generate.TOP_K_CANDIDATES").inc(wide)
+        return {"topk_wide": wide}
 
     def _live_cache_bytes(self, contexts) -> Dict[str, int]:
         """Armed only: at-rest cache bytes that rows holding ``contexts``
@@ -2258,6 +2274,9 @@ class ServeEngine:
                 st for st in self._chunk_queue if st.slot != slot)
         req = self.pool.release(slot)
         self._active[slot] = False
+        # a freed row keeps running the step's math: it must not hold
+        # the batch on the whole-vocabulary ranking (top_k_dispatch)
+        self._top_k[slot] = 0
         self._unbind_adapter(slot)
         if self.spec is not None:
             # a cancel between activation and the next spec dispatch

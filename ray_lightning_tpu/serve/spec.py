@@ -68,8 +68,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_lightning_tpu.models.generate import (_prefill_impl, decode_step,
+                                               processed_logits_row,
                                                sample_logits_rows,
-                                               verify_step,
+                                               top_k_dispatch, verify_step,
                                                verify_step_paged)
 from ray_lightning_tpu.models.quant import materialize_for_program
 from ray_lightning_tpu.serve.pages import (dense_storage_commit,
@@ -90,20 +91,18 @@ _fold_rows = fold_rows
 def _row_probs(logits: jax.Array, temperature: jax.Array,
                top_k: jax.Array) -> jax.Array:
     """Per-row sampling distribution over (B, V) logits — softmax of
-    EXACTLY the processed logits :func:`sample_logits_rows`'s sampled
-    branch draws from (temperature scaling + dynamic rank-mask top_k),
-    so the rejection test's p/q match what the samplers actually did.
-    Greedy rows (t == 0) get a well-defined (unused) distribution."""
-    def row(l, t, tk):
-        scaled = l / jnp.where(t > 0, t, 1.0)
-        order = jnp.argsort(-l)
-        ranks = jnp.zeros_like(order).at[order].set(
-            jnp.arange(l.shape[0], dtype=order.dtype))
-        scaled = jnp.where((tk > 0) & (ranks >= tk),
-                           jnp.finfo(jnp.float32).min, scaled)
-        return jax.nn.softmax(scaled)
+    the processed logits :func:`sample_logits_rows`'s sampled branch
+    draws from (:func:`processed_logits_row`: the one definition of
+    temperature scaling + the top_k mask, under the same batch-level
+    :func:`top_k_dispatch`), so the rejection test's p/q are what the
+    samplers actually drew from. Greedy rows (t == 0) get a
+    well-defined (unused) distribution."""
+    def rows(top_k_path):
+        return jax.vmap(lambda l, t, tk: jax.nn.softmax(
+            processed_logits_row(l, t, tk, top_k_path)))(
+                logits, temperature, top_k)
 
-    return jax.vmap(row)(logits, temperature, top_k)
+    return top_k_dispatch(top_k, rows)
 
 
 def _spec_accept(L, draft_toks, draft_logits, cur, pos, active, remaining,
@@ -131,7 +130,7 @@ def _spec_accept(L, draft_toks, draft_logits, cur, pos, active, remaining,
         # the identity tests' regime): accept is an exact argmax match
         # and every fix IS the argmax — no distributions, no draws.
         # Batch-level lax.cond, the same gate sample_logits_rows uses,
-        # so the full-vocab softmax/argsort machinery below never
+        # so the full-vocab softmax machinery below never
         # executes on the greedy hot path.
         return (jnp.zeros((B, k), jnp.bool_),
                 jnp.zeros((B, k), jnp.int32))
