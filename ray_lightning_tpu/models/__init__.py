@@ -23,6 +23,8 @@ from ray_lightning_tpu.models.lora import (LoraConfig, adapter_bytes,
                                            extract_adapter, install_adapter,
                                            install_lora_bank, zero_adapter)
 from ray_lightning_tpu.models.sambay import SambaYConfig, SambaYLM
+from ray_lightning_tpu.models.olmo_hybrid import (OlmoHybridConfig,
+                                                  OlmoHybridLM)
 from ray_lightning_tpu.models.generate import (decode_step, generate,
                                                generate_full_scan, prefill,
                                                sample_logits,
@@ -40,7 +42,7 @@ __all__ = [
     "sample_logits", "sample_logits_rows", "latch_eos",
     "tensor_parallel_rule",
     "Seq2SeqModule", "Seq2SeqTransformer",
-    "SambaYConfig", "SambaYLM",
+    "SambaYConfig", "SambaYLM", "OlmoHybridConfig", "OlmoHybridLM",
     "LoraConfig", "adapter_bytes", "extract_adapter", "install_adapter",
     "install_lora_bank", "zero_adapter",
 ]

@@ -126,3 +126,55 @@ def inject_rows(pool, rows, layout, slots, valid):
         return lax.fori_loop(0, slots.shape[0], put, leaf)
 
     return jax.tree_util.tree_map(inject, pool, rows, layout)
+
+
+def take_rows(pool, layout, slots):
+    """The slots' rows of every per-slot leaf of ``pool``, as a cache of
+    ``len(slots)`` rows (a copy); leaves no slot owns are handed on."""
+    slots = slots.astype(jnp.int32)
+
+    def take(leaf, decl):
+        if not decl.per_slot:
+            return leaf
+        # a slice a row, each one contiguous stretch of its leaf: a
+        # gather (jnp.take) of whole K/V rows ran at a thirteenth of
+        # the memory's speed on a v5e (PERF.md section 6, PR 32)
+        return jnp.concatenate(
+            [lax.dynamic_slice_in_dim(leaf, slots[r], 1, decl.slot_axis)
+             for r in range(slots.shape[0])], axis=decl.slot_axis)
+
+    return jax.tree_util.tree_map(take, pool, layout)
+
+
+def inject_blocks(pool, rows, layout, slots, valid, start, width):
+    """:func:`inject_rows` for a *piece* of a prompt: a leaf that holds
+    positions (``kind == "global"``) takes only row ``r``'s positions
+    ``start[r] .. start[r] + width - 1`` — the bytes the piece wrote —
+    every other per-slot leaf (a recurrent state) its row whole. In
+    place on the donated pool, a loop trip a row, an invalid row writing
+    back what it reads."""
+    slots = slots.astype(jnp.int32)
+    start = start.astype(jnp.int32)
+
+    def inject(leaf, new, decl):
+        if not decl.per_slot:
+            return leaf
+        piece = decl.kind == "global"
+        sizes = list(leaf.shape)
+        sizes[decl.slot_axis] = 1
+        if piece:
+            sizes[decl.seq_axis] = width
+
+        def put(r, leaf):
+            at = [0] * leaf.ndim
+            src = list(at)
+            at[decl.slot_axis], src[decl.slot_axis] = slots[r], r
+            if piece:
+                at[decl.seq_axis] = src[decl.seq_axis] = start[r]
+            block = jnp.where(valid[r], lax.dynamic_slice(new, src, sizes),
+                              lax.dynamic_slice(leaf, at, sizes))
+            return lax.dynamic_update_slice(leaf, block, at)
+
+        return lax.fori_loop(0, slots.shape[0], put, leaf)
+
+    return jax.tree_util.tree_map(inject, pool, rows, layout)

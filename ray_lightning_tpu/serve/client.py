@@ -750,15 +750,15 @@ class ServeClient:
         return self._pending is not None or self._pending_front is not None
 
     def _dispatch_chunk(self, done: List[Completion]) -> None:
-        """One chunk-prefill dispatch, plus TTFT stamping for the request
-        (if any) whose final chunk just activated its decode row — the
-        engine hands it over directly (``chunk_activated``), no scan of
+        """One chunk-prefill dispatch, plus TTFT stamping for the requests
+        (if any) whose final chunk just activated their decode rows — the
+        engine hands them over directly (``chunk_activated``), no scan of
         ``active_requests``."""
         done.extend(self.engine.prefill_chunk_step())
         self._ops += 1
-        req = self.engine.chunk_activated
-        if req is not None and req.first_token_time is None:
-            self._stamp_first_token(req, self.now())
+        for req in self.engine.chunk_activated:
+            if req.first_token_time is None:
+                self._stamp_first_token(req, self.now())
 
     def _record_retirements(self, tel, done: List[Completion]) -> None:
         """Armed-path bookkeeping for one tick: retire events + the

@@ -113,7 +113,8 @@ from ray_lightning_tpu.models.quant import (DEFAULT_GROUP_SIZE,
                                             param_bytes, quantize_params)
 from ray_lightning_tpu.models.transformer import latch_eos
 from ray_lightning_tpu.obs.spans import NULL_SPAN
-from ray_lightning_tpu.ops.cache_write import inject_rows
+from ray_lightning_tpu.ops.cache_write import (inject_blocks, inject_rows,
+                                                take_rows)
 from ray_lightning_tpu.reliability import faults
 from ray_lightning_tpu.serve.adapters import (AdapterRegistry,
                                               UnknownAdapter)
@@ -229,8 +230,19 @@ def _engine_step_core(model, params, cache, cur, pos, active, remaining,
     unadapted programs are byte-for-byte the pre-LoRA ones.
     """
     with jax.named_scope("decode/forward"):
-        last, cache = decode_step(model, params, cache, cur, pos,
-                                  adapter_ids)
+        last, stepped = decode_step(model, params, cache, cur, pos,
+                                    adapter_ids)
+    if _continues_prefill(model):
+        # a row that is not decoding may hold a prompt that is still
+        # streaming in through the dense chunk program: its recurrent
+        # state stays as the last piece left it (its K/V write is parked
+        # past what the prompt has fed by the host, see _prefill_build)
+        with jax.named_scope("decode/freeze_rows"):
+            stepped = jax.tree_util.tree_map(
+                lambda new, old, decl: new if decl.kind != "recurrent"
+                else jnp.where(_per_row(active, new, decl), new, old),
+                stepped, cache, cache_layout(model, cache))
+    cache = stepped
     (cur, pos, active, remaining, stepno, emitted, finished) = \
         _advance_rows(model, last, cur, pos, active, remaining, temp,
                       top_k, eos, keys, stepno)
@@ -429,6 +441,61 @@ def _chunk_prefill_impl(model, params, arena, row_pages, tokens, offset,
     return arena, first
 
 
+def _per_row(flag, leaf, decl):
+    """``flag`` (one entry a row) shaped to broadcast against ``leaf``
+    along the axis its declaration gives the rows."""
+    shape = [1] * leaf.ndim
+    shape[decl.slot_axis] = -1
+    return jnp.reshape(flag, shape)
+
+
+def _continues_prefill(model) -> bool:
+    """Does the model take a prompt in pieces, each starting from the
+    cache the last one left (``models/olmo_hybrid.py``)?"""
+    return bool(getattr(model, "continues_prefill", False))
+
+
+def _chunk_prefill_dense_impl(model, params, pool_cache, tokens, offset,
+                              lengths, slots, valid, keys, temp, top_k,
+                              startno):
+    """One ``(rows, C)`` piece of ``rows <= prefill_batch`` prompts into
+    **dense slots**, for a model with declared recurrent state that
+    continues its prefill from the cache it is given
+    (:func:`_continues_prefill`): row ``r`` feeds ``lengths[r]`` tokens
+    at absolute ``offset[r]`` into slot ``slots[r]``.
+
+    The rows' cache is taken out of the pool (a row whose ``offset`` is 0
+    starts from a zero state, whatever the slot's last tenant left), the
+    model continues from it, and what the piece wrote goes back in place
+    (:func:`ops.cache_write.inject_blocks`): the recurrent leaves whole,
+    the K/V leaves' ``C`` new positions only. A candidate first token is
+    sampled at each row's last valid token; the host uses it on a final
+    piece only. Invalid rows (the warm-up of a row count) are computed
+    and written nowhere.
+    """
+    params = materialize_for_program(params, model.cfg)
+    layout = cache_layout(model, pool_cache)
+    with jax.named_scope("chunk/kv_take"):
+        rows = take_rows(pool_cache, layout, slots)
+        rows = jax.tree_util.tree_map(
+            lambda leaf, decl: leaf if decl.kind != "recurrent"
+            else jnp.where(_per_row(offset == 0, leaf, decl),
+                           jnp.zeros_like(leaf), leaf),
+            rows, layout)
+    with jax.named_scope("chunk/forward"):
+        outputs, updated = model.apply(
+            {"params": params, "cache": rows}, tokens, lengths=lengths,
+            offset=offset, deterministic=True, mutable=["cache"])
+        last = _logits_only(outputs)[:, -1]
+    with jax.named_scope("sample/keys"):
+        first_keys = _fold_rows(keys, startno)
+    first = sample_logits_rows(last, first_keys, temp, top_k)
+    with jax.named_scope("chunk/kv_inject"):
+        pool_cache = inject_blocks(pool_cache, updated["cache"], layout,
+                                   slots, valid, offset, tokens.shape[1])
+    return pool_cache, first
+
+
 def _page_native_step_impl(model, params, arena, page_table, cur, pos,
                            active, remaining, temp, top_k, eos, keys,
                            stepno, adapter_ids=None, *, steps):
@@ -493,6 +560,11 @@ _chunk_prefill_donated = partial(
         _chunk_prefill_impl)
 _chunk_prefill_plain = partial(
     jax.jit, static_argnames=("model",))(_chunk_prefill_impl)
+_chunk_dense_donated = partial(
+    jax.jit, static_argnames=("model",), donate_argnums=(2,))(
+        _chunk_prefill_dense_impl)
+_chunk_dense_plain = partial(
+    jax.jit, static_argnames=("model",))(_chunk_prefill_dense_impl)
 _page_native_step_donated = partial(
     jax.jit, static_argnames=("model", "steps"), donate_argnums=(2,))(
         _page_native_step_impl)
@@ -677,6 +749,13 @@ class ServeEngine:
             raise ValueError(
                 "page_native=True is a paged-KV mode (attention reads "
                 "K/V through the page table): pass page_size= too")
+        # chunked prefill into DENSE slots: a model with declared
+        # recurrent state whose prefill continues from the cache it is
+        # given (``continues_prefill``) takes a long prompt piece by
+        # piece with the state carried — _chunk_prefill_dense_impl
+        dense_chunk = (prefill_chunk is not None and page_size is None
+                       and getattr(model, "recurrent_state", False)
+                       and _continues_prefill(model))
         if getattr(model, "recurrent_state", False):
             # a slot of such a model holds state that is no K/V row at
             # absolute positions (a recurrence, a ring): what the engine
@@ -686,7 +765,9 @@ class ServeEngine:
                 ("page_size / page_native", page_size is not None),
                 ("kv_dtype='int8'", check_kv_dtype(kv_dtype)),
                 ("prefix_cache", prefix_cache),
-                ("prefill_chunk", prefill_chunk is not None),
+                ("prefill_chunk (the model has no continue mode)",
+                 prefill_chunk is not None
+                 and not _continues_prefill(model)),
                 ("draft_model (speculative decoding)",
                  draft_model is not None),
                 ("max_resident_adapters (the LoRA bank)",
@@ -696,8 +777,9 @@ class ServeEngine:
                     f"{type(model).__name__} declares recurrent state; "
                     f"the engine cannot give it {', '.join(refused)} yet "
                     "(pages, int8 storage, prefix reuse, chunked prefill "
-                    "and draft verification all assume K/V rows at "
-                    "absolute positions) — use the dense-slot engine")
+                    "of a state that cannot be continued and draft "
+                    "verification all assume K/V rows at absolute "
+                    "positions) — use the dense-slot engine")
         # attention_kernel selects the page-native read-side kernel
         # (models/pallas_attention.py): None inherits the model config
         # (default "xla"); "pallas" swaps in the hand-tiled paged
@@ -783,16 +865,19 @@ class ServeEngine:
                 f"steps_per_dispatch must be >= 1, got "
                 f"{steps_per_dispatch}")
         if page_size is None and (num_pages is not None
-                                  or prefill_chunk is not None
+                                  or (prefill_chunk is not None
+                                      and not dense_chunk)
                                   or prefix_cache):
             raise ValueError(
                 "num_pages / prefill_chunk / prefix_cache are paged-KV "
-                "features: pass page_size= to enable the page arena")
+                "features: pass page_size= to enable the page arena "
+                "(prefill_chunk alone also serves a model that declares "
+                "recurrent_state and continues_prefill, on dense slots)")
         if prefill_chunk is not None:
             if prefill_chunk < 1:
                 raise ValueError(
                     f"prefill_chunk must be >= 1, got {prefill_chunk}")
-            if prefill_chunk % page_size != 0:
+            if not dense_chunk and prefill_chunk % page_size != 0:
                 raise ValueError(
                     f"prefill_chunk ({prefill_chunk}) must be a multiple "
                     f"of page_size ({page_size})")
@@ -977,10 +1062,11 @@ class ServeEngine:
         # costs — filled by the first armed dispatch (_live_cache_bytes)
         self._cache_units: Optional[Dict[str, float]] = None
         self._chunk_queue: Deque[_ChunkState] = deque()
-        # the request whose FINAL chunk the last prefill_chunk_step
-        # dispatch activated into decode (None otherwise) — the driving
-        # client stamps TTFT off this without scanning active_requests
-        self.chunk_activated: Optional[Request] = None
+        # the requests whose FINAL chunk the last prefill_chunk_step
+        # dispatch activated into decode (the paged program feeds one
+        # row, the dense one up to prefill_batch) — the driving client
+        # stamps TTFT off this without scanning active_requests
+        self.chunk_activated: Tuple[Request, ...] = ()
         self._base_key = jax.random.PRNGKey(seed)
 
         B = num_slots
@@ -1565,9 +1651,25 @@ class ServeEngine:
                 self._check_slot_quota(req)
                 replay = list(req.replay_tokens or ())
                 fed = list(req.prompt) + replay
+                if self._routes_chunked(req) and not self.paged:
+                    # a dense slot (__init__ allows prefill_chunk without
+                    # pages only to a model that continues its prefill).
+                    # Until the last piece activates the row, the step
+                    # program runs it as an inactive row: its K/V write
+                    # is parked on the first position the prompt has
+                    # not fed yet (the next piece writes over it; a
+                    # parked row's position is also how far the step's
+                    # attention reads), and its recurrent state is
+                    # frozen in the program (_engine_step_core)
+                    slot = self.pool.acquire(req)
+                    acquired.append(slot)
+                    self._cur[slot, 0] = 0
+                    self._pos[slot, 0] = 0
+                    self._chunk_queue.append(_ChunkState(
+                        request=req, slot=slot, fed=fed, next_off=0))
+                    n_chunked += 1
+                    continue
                 if self._routes_chunked(req):
-                    # chunk routing requires the page arena (__init__
-                    # refuses prefill_chunk without page_size)
                     adopt = self._adoptable_prefix(fed)
                     slot = self._admit_paged(req, adopt)
                     acquired.append(slot)
@@ -1658,11 +1760,13 @@ class ServeEngine:
         row (or retires the request, eos-on-first/budget-of-one), and —
         prefix cache armed — the finished prompt's full pages are
         published for future adopters."""
-        self.chunk_activated = None
+        self.chunk_activated = ()
         if not self._chunk_queue:
             return []
         self._require_synced("prefill_chunk_step")
         faults.fire("serve.dispatch")
+        if not self.paged:
+            return self._dense_chunk_step()
         st = self._chunk_queue[0]
         faults.poison_check((st.request,))
         req = st.request
@@ -1718,9 +1822,111 @@ class ServeEngine:
             self.prefix.publish(list(req.prompt), st.slot)
         comp = self._activate(req, st.slot, int(first[0]), keys[0])
         if comp is None:
-            self.chunk_activated = req
+            self.chunk_activated = (req,)
             return []
         return [comp]
+
+    def _dense_chunk_step(self) -> List[Completion]:
+        """:meth:`prefill_chunk_step` on dense slots: ONE ``(rows,
+        prefill_chunk)`` dispatch that feeds the next piece of each of
+        the first ``rows <= prefill_batch`` prompts of the chunk queue,
+        every row at its own offset, the state carried in its slot
+        (:func:`_chunk_prefill_dense_impl`). Rows whose piece was their
+        last activate (or retire on their first token).
+
+        The program is compiled a row count — a lone pending prompt, the
+        common case under steady arrivals, does not pay for
+        ``prefill_batch`` rows — and the first dispatch runs every other
+        count once over rows that feed nothing, so that no count is left
+        to compile under load."""
+        rows = list(self._chunk_queue)[:self.prefill_batch]
+        faults.poison_check([st.request for st in rows])
+        C = self.prefill_chunk
+        fn = _pick(_chunk_dense_donated, _chunk_dense_plain)
+
+        def operands(rows, feed: bool = True):
+            n_rows = len(rows)
+            tokens = np.zeros((n_rows, C), np.int32)
+            offset = np.zeros((n_rows,), np.int32)
+            lengths = np.zeros((n_rows,), np.int32)
+            keys = np.zeros((n_rows, 2), np.uint32)
+            temp = np.zeros((n_rows,), np.float32)
+            top_k = np.zeros((n_rows,), np.int32)
+            startno = np.zeros((n_rows,), np.int32)
+            for r, st in enumerate(rows if feed else ()):
+                req, off = st.request, st.next_off
+                n = min(C, len(st.fed) - off)
+                tokens[r, :n] = st.fed[off:off + n]
+                offset[r], lengths[r] = off, n
+                keys[r] = np.asarray(
+                    jax.random.fold_in(self._base_key, req.seed))
+                temp[r] = req.temperature
+                top_k[r] = req.top_k or 0
+                startno[r] = len(req.replay_tokens or ())
+            slots = np.array([st.slot for st in rows], np.int32)
+            # a row that feeds nothing writes back what it reads
+            valid = np.full((n_rows,), feed)
+            return (tokens, offset, lengths, slots, valid, keys, temp,
+                    top_k, startno)
+
+        if not self.chunk_dispatches:
+            for n in range(1, self.prefill_batch + 1):
+                if n != len(rows):
+                    self.pool.cache, _ = fn(
+                        self.model, self.params, self.pool.cache,
+                        *operands(rows[:1] * n, feed=False))
+        args = operands(rows)
+        _, offset, lengths, _, _, keys, _, top_k, _ = args
+        n_rows, fed_now = len(rows), int(lengths.sum())
+        tel = self._tel
+        if tel is not None:
+            m = tel.metrics
+            m.counter("serve_chunk_tokens_total",
+                      help="valid prompt tokens fed to chunk-prefill "
+                      "dispatches").inc(fed_now)
+            m.counter("serve_chunk_program_tokens_total",
+                      help="tokens the chunk program was run over: "
+                      "rows x prefill_chunk per dense-slot "
+                      "dispatch").inc(n_rows * C)
+        with (tel.span("engine.chunk.call",
+                       ids=[st.request.id for st in rows],
+                       off=[int(o) for o in offset],
+                       lens=[int(n) for n in lengths],
+                       tokens=fed_now, program_tokens=n_rows * C,
+                       rows=n_rows, slot=[st.slot for st in rows],
+                       **self._count_topk_wide(tel, top_k),
+                       **self._span_extra)
+              if tel is not None else NULL_SPAN):
+            self.pool.cache, first = fn(
+                self.model, self.params, self.pool.cache, *args)
+        with (tel.span("engine.chunk.sync", slot=[st.slot for st in rows],
+                       **self._span_extra)
+              if tel is not None else NULL_SPAN):
+            first = np.asarray(first)
+        self.chunk_dispatches += 1
+        done: List[Completion] = []
+        activated: List[Request] = []
+        for r, st in enumerate(rows):
+            off, n = st.next_off, int(lengths[r])
+            st.next_off = off + n
+            final = st.next_off >= len(st.fed)
+            if tel is not None:
+                tel.event("engine.chunk", id=st.request.id, off=off, n=n,
+                          final=final)
+            if not final:
+                # where the step program parks this row's K/V write
+                # until its next piece (see _prefill_build)
+                self._pos[st.slot, 0] = st.next_off
+                continue
+            self._chunk_queue.remove(st)
+            comp = self._activate(st.request, st.slot, int(first[r]),
+                                  keys[r])
+            if comp is None:
+                activated.append(st.request)
+            else:
+                done.append(comp)
+        self.chunk_activated = tuple(activated)
+        return done
 
     def _activate(self, req: Request, slot: int, tok: int,
                   key: np.ndarray) -> Optional[Completion]:

@@ -282,3 +282,73 @@ def test_sambay_engine_programs_compile_and_fit(one_chip, program,
     # 8 layers: 1.9 GB of bf16 weights + 0.9 GB of cache; the published
     # depth reads 9.4 GB + 1.0 GB of temporaries (PERF.md section 6)
     assert total < 6e9, total
+
+
+# --------------------------------------------------------------------- #
+# Olmo-Hybrid at its published widths: the step and the dense-slot chunk
+# program of benchmark cell olmo-hybrid-7b.serve.longdoc16
+# --------------------------------------------------------------------- #
+@pytest.mark.parametrize("program", ["step", "chunk"])
+def test_olmo_hybrid_engine_programs_compile_and_fit(one_chip, program):
+    """Hidden 3840, 30 heads of 128, MLP 11008, 30 delta-rule heads of
+    96 x 192, the whole 100352-row vocabulary, 16 slots of 4608 positions
+    — at 4 layers (one period: three Gated-DeltaNet layers and the full
+    attention layer), a quarter of the cell's depth, to keep the compile
+    short. The chunkwise rule, its block inverse and the in-place piece
+    write must lower for a v5e and fit; and **no program may copy a K/V
+    leaf of the pool whole**: the step did, twice a leaf, while it wrote
+    through ``ops.cache_write.write_rows`` (whose position-minor view is
+    a transpose where a head is a lane row of 128; PERF.md section 6,
+    PR 32), and read 26 % of its roofline for it."""
+    from ray_lightning_tpu.models.olmo_hybrid import (OlmoHybridConfig,
+                                                      OlmoHybridLM)
+    from ray_lightning_tpu.serve import engine as E
+    S = _spec(one_chip)
+    slots, rows, piece = 16, 2, 512
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(lambda a: S(a.shape, a.dtype), tree)
+
+    cfg = OlmoHybridConfig(num_hidden_layers=4,
+                           layer_types=OlmoHybridConfig().layer_types[:4],
+                           max_seq_len=4608, decode=True)
+    model = OlmoHybridLM(cfg)
+    init = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((slots, 1), jnp.int32)))
+    params, cache = abstract(init["params"]), abstract(init["cache"])
+    if program == "step":
+        compiled = jax.jit(
+            E._engine_step_impl, static_argnames=("model", "steps"),
+            donate_argnums=(2,)).lower(
+                model, params, cache, S((slots, 1), jnp.int32),
+                S((slots, 1), jnp.int32), S((slots,), jnp.bool_),
+                S((slots,), jnp.int32), S((slots,), jnp.float32),
+                S((slots,), jnp.int32), S((slots,), jnp.int32),
+                S((slots, 2), jnp.uint32), S((slots,), jnp.int32), None,
+                steps=1).compile()
+    else:
+        compiled = jax.jit(
+            E._chunk_prefill_dense_impl, static_argnames=("model",),
+            donate_argnums=(2,)).lower(
+                model, params, cache, S((rows, piece), jnp.int32),
+                S((rows,), jnp.int32), S((rows,), jnp.int32),
+                S((rows,), jnp.int32), S((rows,), jnp.bool_),
+                S((rows, 2), jnp.uint32), S((rows,), jnp.float32),
+                S((rows,), jnp.int32), S((rows,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    assert not [line for line in text.splitlines()
+                if "bf16[16,4608,30,128]" in line.split(" = ")[-1][:40]
+                and " copy(" in line]
+    mem = compiled.memory_analysis()
+    leaf = slots * 4608 * 30 * 128 * 2
+    # the step's temporaries are its logits and a row's scores; the
+    # chunk's the two rows' K/V taken out of the pool, a block of
+    # scores and the rule's block products
+    assert mem.temp_size_in_bytes < (leaf // 8 if program == "step"
+                                     else 2 * leaf)
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    # 4 layers: 2.4 GB of bf16 weights + 1.3 GB of cache; the cell's 16
+    # layers read 13.3 GB + 1.1 GB of temporaries (PERF.md section 6)
+    assert total < 6e9, total
