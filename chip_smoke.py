@@ -18,7 +18,9 @@ published width and depth, in ONE process (a chip belongs to one process):
    ``matmul_kernel`` xla vs pallas: token agreement, first differing
    position, and the max abs logit difference of a teacher-forced probe
    (int4 weights: the probe only, no engine);
-   plus one train step with ``attention_impl="flash"`` against ``"dot"``.
+   plus train steps with ``attention_impl="flash"`` and with the default
+   seat (which takes the same kernel by itself on a TPU) against the dense
+   path.
    Every Pallas path must hold its kernel (``tpu_custom_call``) in the
    program it ran — never an interpreted expansion.
 
@@ -430,17 +432,32 @@ def one_chip(sz: Sizes, run: Run) -> None:
              params, "int4", group_size=sz.int4_group))])
 
     with run.phase("kernels.flash_attention") as f:
+        # the dense path for comparison: on a TPU the default seat takes
+        # the kernel by itself at this length (models/transformer.py::
+        # attention_seat), so the smoke steers it aside for one fit
+        from ray_lightning_tpu.models import transformer
+        seat = transformer.attention_seat
+        transformer.attention_seat = lambda *a, **k: (False, "smoke")
+        try:
+            _, _, dlosses, _ = fit(sz, train_config(sz), strategy(), 2)
+        finally:
+            transformer.attention_seat = seat
         fcfg = train_config(sz, attention_impl="flash")
         ftrainer, fmodule, flosses, _ = fit(sz, fcfg, strategy(), 2)
         text = train_step_text(ftrainer, fmodule)
         holds = "tpu_custom_call" in text
-        diff = abs(flosses[0] - state["dot_first_loss"])
+        diff = abs(flosses[0] - dlosses[0])
+        default_diff = abs(state["dot_first_loss"] - dlosses[0])
         f.update(steps=2, flash_first_loss=flosses[0],
-                 dot_first_loss=state["dot_first_loss"],
-                 first_loss_abs_diff=diff, tolerance=FLASH_TOL,
+                 dot_first_loss=dlosses[0],
+                 default_seat_first_loss=state["dot_first_loss"],
+                 first_loss_abs_diff=diff,
+                 default_seat_abs_diff=default_diff, tolerance=FLASH_TOL,
                  kernel_in_train_program=holds)
         check(all(np.isfinite(flosses)), flosses)
-        check(diff <= FLASH_TOL, (flosses[0], state["dot_first_loss"]))
+        check(diff <= FLASH_TOL, (flosses[0], dlosses[0]))
+        check(default_diff <= FLASH_TOL,
+              (state["dot_first_loss"], dlosses[0]))
         if on_tpu:
             check(holds, "attention_impl='flash' trained without the "
                          "Pallas kernel (no tpu_custom_call)")

@@ -31,6 +31,7 @@ from flax import serialization
 
 from ray_lightning_tpu import util as _util
 from ray_lightning_tpu.core.callbacks import Callback, ModelCheckpoint
+from ray_lightning_tpu.obs import seats
 from ray_lightning_tpu.obs.spans import NULL_SPAN
 from ray_lightning_tpu.reliability import faults as _faults
 from ray_lightning_tpu.reliability import log_suppressed
@@ -626,7 +627,8 @@ class Trainer:
                     module.on_before_optimizer_step(self._tx)
                     for cb in self.callbacks:
                         cb.on_before_optimizer_step(self, module, self._tx)
-                with self._span("trainer.train_step"), \
+                with self._span("trainer.train_step") as span_args, \
+                        seats.tally(span_args), \
                         self.profiler.profile("train_step"):
                     state, logs = self._train_step(state, batch)
                 if self.nonfinite_action is not None:

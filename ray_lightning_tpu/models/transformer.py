@@ -9,10 +9,14 @@ TPU-first choices baked in:
   the layer loop.
 - **`nn.remat`** (`remat=True`): rematerialize block activations in backward,
   trading MXU FLOPs for HBM — the standard memory lever for long sequences.
-- **Pluggable attention impl** (``attention_impl``): 'dot' (XLA-fused
-  reference), 'flash' (pallas blockwise kernel), 'ring' (sequence-parallel
-  ring attention over the ``sp`` mesh axis), 'ulysses' (all-to-all
-  head-sharded sequence parallelism over the same axis).
+- **Pluggable attention impl** (``attention_impl``): 'dot' (the default:
+  the seat reads its own call — :func:`attention_seat` — and runs the
+  pallas blockwise kernel for causal self-attention with no mask and no
+  dropout from ``FLASH_MIN_LEN`` positions up on a TPU, per device under a
+  mesh, and the dense XLA path for everything else), 'flash' (the
+  blockwise kernel asked for by name), 'ring' (sequence-parallel ring
+  attention over the ``sp`` mesh axis), 'ulysses' (all-to-all head-sharded
+  sequence parallelism over the same axis).
 
 Parameter-path naming is stable and load-bearing: tensor-parallel sharding
 rules (``MeshStrategy(param_rule=...)``) match on these names.
@@ -20,16 +24,22 @@ rules (``MeshStrategy(param_rule=...)``) match on these names.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from ray_lightning_tpu.models.quant import (kv_dequantize, kv_quantize,
                                             kv_scales)
+from ray_lightning_tpu.obs import seats
 from ray_lightning_tpu.ops.attention import dot_product_attention
 from ray_lightning_tpu.ops.cache_write import write_rows
+from ray_lightning_tpu.ops.pallas_flash import (head_lanes,
+                                                pallas_flash_attention)
+from ray_lightning_tpu.parallel import sharding as shardlib
 from ray_lightning_tpu.parallel.sharding import constrain_batch
 
 
@@ -63,6 +73,10 @@ class TransformerConfig:
     # max_seq_len in the "cache" variable collection and consumes ONE
     # token per call (see models/generate.py)
     decode: bool = False
+    # "dot" adapts: attention_seat (below) picks the blockwise pallas
+    # kernel or the dense XLA path from the call it traces. Attention
+    # dropout > 0 in training keeps the dense path (the kernel has no
+    # dropout). "flash" asks for the blockwise path by name.
     attention_impl: str = "dot"      # dot | flash | ring | ulysses
     # kernel for the PAGE-NATIVE cached-attention read side (serving
     # engines with page_native=True; inert everywhere else): "xla" =
@@ -162,7 +176,6 @@ def tensor_parallel_rule(path, leaf):
     leading ``layers`` dim the ``nn.scan`` adds) and unrolled blocks.
     Embeddings/layernorms replicate.
     """
-    from jax.sharding import PartitionSpec as P
 
     names = [str(getattr(p, "key", getattr(p, "name", p))) for p in path]
     shape = tuple(getattr(leaf, "shape", ()))
@@ -356,9 +369,82 @@ def _projection(cfg: TransformerConfig, *, features, in_features: int,
     return lambda x, adapter_ids: mod(x)
 
 
+#: Shortest causal self-attention the default seat hands to the blockwise
+#: kernel: the medium train cell's step program, timed at 1024, 512 and 256
+#: positions a row with the tokens of a step held, is faster on the kernel
+#: at all three (PERF.md section 6, PR 33); nothing shorter was timed.
+FLASH_MIN_LEN = 256
+
+
+def attention_seat(q_shape, k_shape, *, decode: bool, causal: bool, mask,
+                   dropout: bool, backend: Optional[str] = None):
+    """Which program computes the attention call being traced, from the
+    call itself and the ambient mesh: ``(kernel, how)``.
+
+    ``(True, "local")`` is the blockwise kernel as a plain call (no mesh,
+    one device's worth of data axes, or a region that is already manual);
+    ``(True, "sharded")`` the same kernel nested in a ``shard_map`` over
+    the mesh, batch rows split over its data axes (a bare ``pallas_call``
+    is opaque to the partitioner, which would gather the global batch onto
+    every chip). ``(False, reason)`` is the dense path; ``reason`` names
+    the first test the call fails: ``decode``, ``non_causal``, ``mask``,
+    ``dropout``, ``length`` (query and key lengths differ or are under
+    ``FLASH_MIN_LEN``), ``head_dim``, ``backend``, ``sequence_cut`` (an
+    ``sp`` axis: ring / ulysses own that case), ``batch_indivisible``.
+    """
+    if decode:
+        return False, "decode"
+    if not causal:
+        return False, "non_causal"
+    if mask is not None:
+        return False, "mask"
+    if dropout:
+        return False, "dropout"
+    if q_shape[1] != k_shape[1] or q_shape[1] < FLASH_MIN_LEN:
+        return False, "length"
+    if head_lanes(q_shape[-1]) is None:
+        return False, "head_dim"
+    if (backend or jax.default_backend()) != "tpu":
+        return False, "backend"
+    mesh = shardlib.ambient_mesh()
+    if mesh is None or jax.sharding.get_abstract_mesh().manual_axes:
+        return True, "local"
+    if mesh.shape.get("sp", 1) > 1:
+        return False, "sequence_cut"
+    if _mesh_cut(mesh, q_shape) == (None, None):
+        return True, "local"
+    if q_shape[0] % shardlib.data_axis_size(mesh):
+        return False, "batch_indivisible"
+    return True, "sharded"
+
+
+def _mesh_cut(mesh, q_shape):
+    """The axes a nested kernel call splits batch rows and heads over."""
+    rows = shardlib.data_axis_names(mesh) \
+        if shardlib.data_axis_size(mesh) > 1 else None
+    tp = mesh.shape.get("tp", 1)
+    heads = "tp" if tp > 1 and q_shape[2] % tp == 0 else None
+    return rows, heads
+
+
+def _blockwise_attention(q, k, v, how: str):
+    """The causal blockwise kernel on ``(B, T, H, D)``; ``"sharded"`` runs
+    it per device (the pattern of ``ring_attention.sp_sharded_attention``),
+    heads kept on ``tp`` where such an axis divides them."""
+    if how == "local":
+        return pallas_flash_attention(q, k, v, causal=True)
+    mesh = shardlib.ambient_mesh()
+    rows, heads = _mesh_cut(mesh, q.shape)
+    spec = P(rows, None, heads)
+    return jax.shard_map(
+        lambda a, b, c: pallas_flash_attention(a, b, c, causal=True),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False)(q, k, v)
+
+
 def _attention_fn(cfg: TransformerConfig):
     if cfg.attention_impl == "dot":
-        return dot_product_attention
+        return functools.partial(_adaptive_attention, cfg.decode)
     if cfg.attention_impl == "flash":
         from ray_lightning_tpu.ops.flash_attention import flash_attention
         return flash_attention
@@ -370,6 +456,21 @@ def _attention_fn(cfg: TransformerConfig):
         from ray_lightning_tpu.parallel.ulysses import ulysses_attention
         return ulysses_attention
     raise ValueError(f"Unknown attention_impl {cfg.attention_impl!r}")
+
+
+def _adaptive_attention(decode, q, k, v, *, causal, mask, dropout_rate,
+                        dropout_rng, **kw):
+    """The default seat: the blockwise kernel where :func:`attention_seat`
+    says so, ``dot_product_attention`` exactly as ever elsewhere."""
+    kernel, how = attention_seat(
+        q.shape, k.shape, decode=decode, causal=causal, mask=mask,
+        dropout=dropout_rate > 0.0 and dropout_rng is not None)
+    seats.note(kernel, how)
+    if kernel:
+        return _blockwise_attention(q, k, v, how)
+    return dot_product_attention(
+        q, k, v, causal=causal, mask=mask, dropout_rate=dropout_rate,
+        dropout_rng=dropout_rng, **kw)
 
 
 class MultiHeadAttention(nn.Module):

@@ -3,12 +3,15 @@
 The reference framework has no kernels of its own (its hot loop is torch
 DDP); a TPU-native framework owns its attention math. Two tiers:
 
-- :func:`dot_product_attention` — plain jnp einsum formulation. XLA already
-  fuses softmax chains well on TPU; this is the correctness baseline and the
-  CPU/test path.
+- :func:`dot_product_attention` — plain jnp einsum formulation: the
+  correctness baseline, the CPU/test path, and what every masked, cached,
+  non-causal, short or dropout-carrying call runs. It materialises the
+  ``(B, H, T, S)`` scores.
 - :mod:`ray_lightning_tpu.ops.flash_attention` — blockwise online-softmax
-  attention (XLA loop), with the hand-tiled pallas kernel in
-  ``ops/pallas_flash.py``; chosen via ``TransformerConfig.attention_impl``.
+  attention (XLA loop), with the hand-tiled pallas kernels in
+  ``ops/pallas_flash.py``; asked for by ``attention_impl="flash"``, and
+  picked by the default seat itself (``models/transformer.py::
+  attention_seat``) for causal self-attention on a TPU.
 """
 from __future__ import annotations
 

@@ -3,10 +3,12 @@
 Online-softmax formulation over KV blocks — the memory-efficient attention
 the reference never needed (its largest axis was parameter memory, SURVEY.md
 §5 "long-context: entirely absent") but a TPU-native framework must own for
-long sequences. This module is the XLA implementation (``lax.map`` over query
-blocks, ``lax.scan`` over KV blocks — compiles to a tight fused loop); the
-hand-tiled pallas kernel rides the same math (see ``ops/pallas_flash.py``)
-and is selected via ``flash_attention(..., use_pallas=True)`` on TPU.
+long sequences. This module is ``attention_impl="flash"``: on a TPU the
+hand-tiled pallas kernels (``ops/pallas_flash.py`` — the same kernels the
+default seat of ``models/transformer.py`` picks by itself for causal
+self-attention, ``PERF.md`` section 6, PR 33), elsewhere the XLA
+implementation below (a python loop over query blocks, ``lax.scan`` over
+KV blocks), which ring attention shares one step of.
 
 Falls back to :func:`dot_product_attention` for arbitrary additive masks or
 attention dropout (neither fits the blockwise accumulator cheaply).
@@ -68,8 +70,8 @@ def flash_attention(q: jax.Array,
                     mask: Optional[jax.Array] = None,
                     dropout_rate: float = 0.0,
                     dropout_rng: Optional[jax.Array] = None,
-                    block_q: int = 512,
-                    block_k: int = 1024,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
                     softmax_dtype=jnp.float32,
                     use_pallas: Optional[bool] = None) -> jax.Array:
     """Blockwise attention; signature-compatible with
@@ -90,7 +92,7 @@ def flash_attention(q: jax.Array,
 
     B, T, H, D = q.shape
     S = k.shape[1]
-    bq, bk = min(block_q, T), min(block_k, S)
+    bq, bk = min(block_q or 512, T), min(block_k or 1024, S)
     n_q, n_k = -(-T // bq), -(-S // bk)
     Tp, Sp = n_q * bq, n_k * bk
     scale = D ** -0.5

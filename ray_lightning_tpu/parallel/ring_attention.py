@@ -80,12 +80,10 @@ def sp_sharded_attention(q: jax.Array,
             "dropout=0.0 / drop the mask, or use attention_impl='dot'.")
     if q.shape[1] % mesh.shape[SP_AXIS_NAME] != 0:
         return ring_attention(q, k, v, causal=causal)
-    from ray_lightning_tpu.parallel.sharding import data_axis_names
+    from ray_lightning_tpu.parallel.sharding import (data_axis_names,
+                                                     data_axis_size)
     data_axes = data_axis_names(mesh)
-    data_size = 1
-    for a in data_axes:
-        data_size *= mesh.shape[a]
-    if data_size > 1 and q.shape[0] % data_size != 0:
+    if q.shape[0] % data_axis_size(mesh) != 0:
         return ring_attention(q, k, v, causal=causal)
     # keep heads tp-sharded through the ring when a tp axis exists (ring
     # attention is independent per head) — otherwise the shard_map boundary

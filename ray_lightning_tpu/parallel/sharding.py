@@ -54,6 +54,16 @@ def under_mesh(mesh: Mesh, fn: Callable) -> Callable:
     return traced
 
 
+def ambient_mesh() -> Optional[Mesh]:
+    """The mesh of the step being traced (:func:`under_mesh`), else None."""
+    return getattr(_AMBIENT, "mesh", None)
+
+
+def data_axis_size(mesh: Mesh) -> int:
+    """How many ways the mesh's data axes split a batch."""
+    return math.prod(mesh.shape[a] for a in data_axis_names(mesh))
+
+
 def constrain_batch(x: jax.Array) -> jax.Array:
     """Keep dim 0 of an activation split over the ambient mesh's data axes.
 
@@ -72,11 +82,11 @@ def constrain_batch(x: jax.Array) -> jax.Array:
     and inside a manual (``shard_map``) region, where the arrays are
     already per-device blocks.
     """
-    mesh = getattr(_AMBIENT, "mesh", None)
+    mesh = ambient_mesh()
     if mesh is None or not getattr(x, "ndim", 0):
         return x
     axes = data_axis_names(mesh)
-    size = math.prod(mesh.shape[a] for a in axes)
+    size = data_axis_size(mesh)
     if size == 1 or x.shape[0] % size \
             or jax.sharding.get_abstract_mesh().manual_axes:
         return x

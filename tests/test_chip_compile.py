@@ -75,8 +75,96 @@ def test_flash_attention_fwd_bwd_compiles(one_chip):
             jnp.float32).sum()
 
     text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), *qkv)
-    # forward kernel + the two backward kernels (dq; dk, dv)
-    assert text.count("tpu_custom_call") >= 3
+    # the forward kernel and the one backward kernel (dq, dk, dv)
+    assert text.count("tpu_custom_call") >= 2
+
+
+def test_default_seat_takes_the_kernel_at_the_medium_cells_shapes(
+        one_chip, monkeypatch):
+    """``gpt2-medium.train.b12-t1024``'s attention through the *default*
+    seat (no ``attention_impl`` named): the forward kernel and the
+    backward kernel, and no ``[12,16,1024,1024]`` scores anywhere."""
+    from ray_lightning_tpu.models import transformer
+    # the seat asks the backend; the test steers it (as for cache_write)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    S = _spec(one_chip)
+    qkv = [S((12, 1024, 16, 64), jnp.bfloat16)] * 3
+    seat = transformer._attention_fn(transformer.TransformerConfig())
+
+    def loss(q, k, v):
+        return seat(q, k, v, causal=True, mask=None, dropout_rate=0.0,
+                    dropout_rng=None).astype(jnp.float32).sum()
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), *qkv)
+    assert text.count("tpu_custom_call") == 2
+    assert "[12,16,1024,1024]" not in text
+    short = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)),
+                           *[S((12, 128, 16, 64), jnp.bfloat16)] * 3)
+    assert "tpu_custom_call" not in short       # under FLASH_MIN_LEN
+
+
+def test_fsdp4_step_runs_the_kernel_on_each_chips_rows(topo, one_chip,
+                                                       monkeypatch):
+    """An FSDP-over-4 train step on the described ``v5e:2x2`` at GPT-2 XL's
+    per-layer shapes (two layers, a global batch of 12 x 1024): the kernels
+    get each chip's 3 rows, and no collective carries the global batch."""
+    import optax
+    from jax.sharding import NamedSharding
+
+    from ray_lightning_tpu import FSDPStrategy
+    from ray_lightning_tpu.core.train_state import TrainState
+    from ray_lightning_tpu.models.transformer import (TransformerConfig,
+                                                      TransformerLM)
+    from ray_lightning_tpu.obs.census import collective_census, format_census
+    from ray_lightning_tpu.parallel.mesh import build_mesh
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rows, T = 12, 1024
+    strat = FSDPStrategy(num_workers=4)
+    strat._mesh = build_mesh(strat.mesh_spec(), topo.devices)
+    model = TransformerLM(TransformerConfig(
+        vocab_size=8192, max_seq_len=T, d_model=1600, n_heads=25,
+        n_layers=2, d_ff=6400, dtype=jnp.bfloat16, causal=True,
+        scan_layers=True, remat=True,
+        remat_policy="dots_with_no_batch_dims"))
+    tx = optax.adamw(3e-4)
+    tokens = jax.ShapeDtypeStruct((rows, T), jnp.int32)
+
+    def init(t):
+        params = model.init(jax.random.PRNGKey(0), t)["params"]
+        return TrainState.create(params, tx.init(params))
+
+    abstract = jax.eval_shape(init, tokens)
+    shardings = TrainState(
+        step=strat.scalar_sharding(),
+        params=strat.params_sharding(abstract.params),
+        opt_state=strat.opt_state_sharding(abstract.opt_state),
+        model_state={}, rng=strat.scalar_sharding())
+
+    def loss_fn(params, model_state, batch, rng):
+        x, y = batch
+        logp = jax.nn.log_softmax(
+            model.apply({"params": params}, x).astype(jnp.float32))
+        loss = -jnp.mean(jnp.take_along_axis(logp, y[..., None], axis=-1))
+        return loss, ({}, model_state)
+
+    step = strat.make_train_step(loss_fn, tx, shardings,
+                                 strat.batch_sharding(), donate=False)
+    place = lambda a, s: jax.ShapeDtypeStruct(  # noqa: E731
+        a.shape, a.dtype, sharding=s)
+    state = jax.tree_util.tree_map(place, abstract, shardings)
+    batch = (place(tokens, strat.batch_sharding()),) * 2
+    text = step.lower(state, batch).compile().as_text()
+
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln
+             and " custom-call(" in ln]
+    assert len(calls) >= 3, len(calls)     # forward, its remat, backward
+    per_chip = f"bf16[{rows // 4},{T},1600]"
+    assert all(per_chip in ln for ln in calls), calls[0][:400]
+    assert not any(f"[{rows},{T}," in ln for ln in calls)
+    census = collective_census(text, batch=rows)
+    moved = [c for c in census if c.carries_batch
+             and c.dtype not in ("s32", "u32", "pred")]
+    assert not moved, format_census(census)
 
 
 # --------------------------------------------------------------------- #

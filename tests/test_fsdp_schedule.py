@@ -99,6 +99,38 @@ def test_fsdp_step_gathers_weights_and_keeps_the_batch_split(
         assert n >= want, f"{name}: {n} gathers, want {want}\n{shown}"
 
 
+@pytest.mark.parametrize("strategy", ["fsdp4", "dp2_fsdp2"])
+def test_fsdp_step_on_the_blockwise_kernel_keeps_the_batch_split(
+        strategy, monkeypatch):
+    """The default seat on the kernel (as a TPU decides it; interpreted
+    here) nests it in a ``shard_map`` over the data axes: still no
+    activation at the global batch's size, no scores, the same gathers."""
+    from ray_lightning_tpu.ops.pallas_flash import pallas_flash_attention
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(transformer, "FLASH_MIN_LEN", T)
+    monkeypatch.setattr(
+        transformer, "pallas_flash_attention",
+        functools.partial(pallas_flash_attention, interpret=True))
+    seen = []
+    seat = transformer.attention_seat
+    monkeypatch.setattr(
+        transformer, "attention_seat",
+        lambda *a, **k: seen.append(seat(*a, **k)) or seen[-1])
+    jax.clear_caches()
+    strat = (FSDPStrategy(num_workers=4) if strategy == "fsdp4"
+             else MeshStrategy(axes={"dp": 2, "fsdp": 2}))
+    census = collective_census(_lowered_step(strat, 5).compile(), batch=B)
+    # (model.init traces the seat too, with no mesh ambient: "local")
+    assert seen[-1] == (True, "sharded") and all(k for k, _ in seen)
+    shown = format_census(census)
+    assert not _batch_activations(census), shown
+    assert not [c for c in census
+                if len(c.shape) == 4 and c.shape[-2:] == (T, T)], shown
+    d = 5 * HEAD_DIM
+    gathered = [c for c in census if c.kind == "all-gather" and c.in_loop]
+    assert sum(math.prod(c.shape) == d * 3 * d for c in gathered) >= 1, shown
+
+
 def test_identity_on_a_one_device_mesh(monkeypatch):
     """``RayStrategy(1)`` (the one-chip train cell): the lowered step is
     the same text with the helper as with the identity in its place."""

@@ -9,10 +9,12 @@ prints device time by named scope and the idle gaps by the program's own
 spans. ``--arm-trainer`` hands ``Trainer`` a wall-clock ``Telemetry`` (the
 train kind arms none), so a train cell's ``trainer.*`` spans are in the
 profile too, and ``--trace 0 --arm-trainer`` against ``--trace 0`` is what
-the trainer's spans cost a step. ``--census`` compiles a train cell's step
-once more after set-up and prints its collectives (``obs/census.py``: kind,
-result type, bytes, inside the layer loop or not, carrying the global batch
-or parameter-shaped). Chip only (``--rehearse`` walks it on the CPU)::
+the trainer's spans cost a step; the armed run also prints what the step's
+attention seats decided while it was traced (``obs/seats.py``: how many took
+the blockwise kernel, how many the dense path and why). ``--census``
+compiles a train cell's step once more after set-up and prints its
+collectives (``obs/census.py``: kind, result type, bytes, inside the layer
+loop or not, carrying the global batch or parameter-shaped). Chip only (``--rehearse`` walks it on the CPU)::
 
     python tools/chip_profile.py --workload gpt2-large.serve.closed40 \\
         --seed 11 --out chiprun_out/profile.serve
@@ -81,11 +83,12 @@ def main(argv=None) -> int:
             return reduce(self)
 
         harness.Slice.reduce = keep_then_reduce
+    tel = None
     if args.arm_trainer:
         import ray_lightning_tpu as rlt
         from ray_lightning_tpu.obs import Telemetry
-        rlt.Trainer = functools.partial(
-            rlt.Trainer, telemetry=Telemetry(clock=time.perf_counter))
+        tel = Telemetry(clock=time.perf_counter)
+        rlt.Trainer = functools.partial(rlt.Trainer, telemetry=tel)
 
     if args.census:
         print_census_at_setup()
@@ -97,6 +100,11 @@ def main(argv=None) -> int:
     if args.rehearse:
         argv.append("--rehearse")
     rc = run.main(argv)
+    if tel is not None:
+        for span in tel.spans.spans("trainer.train_step"):
+            if span.args:   # the call that traced the step
+                sys.stderr.write(
+                    f"attention seats as traced: {span.args}\n")
     if rc == 0 and args.trace and args.out:
         import trace_report  # tools/ is sys.path[0] when run as a script
         sys.stderr.write(trace_report.format_profile_report(
