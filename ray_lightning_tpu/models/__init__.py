@@ -25,6 +25,7 @@ from ray_lightning_tpu.models.lora import (LoraConfig, adapter_bytes,
 from ray_lightning_tpu.models.sambay import SambaYConfig, SambaYLM
 from ray_lightning_tpu.models.olmo_hybrid import (OlmoHybridConfig,
                                                   OlmoHybridLM)
+from ray_lightning_tpu.models.afmoe import AfmoeConfig, AfmoeLM
 from ray_lightning_tpu.models.generate import (decode_step, generate,
                                                generate_full_scan, prefill,
                                                sample_logits,
@@ -43,6 +44,7 @@ __all__ = [
     "tensor_parallel_rule",
     "Seq2SeqModule", "Seq2SeqTransformer",
     "SambaYConfig", "SambaYLM", "OlmoHybridConfig", "OlmoHybridLM",
+    "AfmoeConfig", "AfmoeLM",
     "LoraConfig", "adapter_bytes", "extract_adapter", "install_adapter",
     "install_lora_bank", "zero_adapter",
 ]

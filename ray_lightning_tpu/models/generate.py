@@ -411,10 +411,12 @@ def _prefill_impl(model, params, prompt_tokens, prompt_lengths,
                        jnp.zeros((B, 1), jnp.int32),
                        positions=jnp.zeros((B, 1), jnp.int32))["cache"]
     positions = jax.lax.broadcasted_iota(jnp.int32, (1, P), 1)
-    if getattr(model, "recurrent_state", False):
+    if getattr(model, "recurrent_state", False) \
+            or getattr(model, "continues_prefill", False):
         # a recurrence or a ring would eat the pad tail: the model takes
         # the row lengths, leaves every row's state as of its last valid
-        # position, and returns that position's logits only
+        # position, and returns that position's logits only (so does a
+        # model whose one prefill is its continue mode, state or none)
         lengths = (jnp.full((B,), P, jnp.int32) if prompt_lengths is None
                    else jnp.asarray(prompt_lengths, jnp.int32))
         outputs, updated = model.apply(

@@ -17,6 +17,13 @@ weight is 0, so they pass through the residual unchanged) — Switch
 Transformer's behavior, and the price of static shapes. The router aux loss
 (Switch eq. 4: ``E · Σ_e f_e · P_e``) pushes the load flat so drops stay
 rare.
+
+This is the **train-only capacity router**: a served token may not be
+dropped, and ``(N, E, C)`` dispatch tensors do not fit a decode step. The
+serve engine's expert layer is the dropless one of
+``ops/grouped_experts.py`` (sorted and grouped, one ragged product a
+projection, told which experts it holds), which ``models/afmoe.py`` runs
+behind ``ServeClient``.
 """
 from __future__ import annotations
 

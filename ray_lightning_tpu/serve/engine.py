@@ -180,6 +180,11 @@ class PendingDispatch:
     # ServeClient only — read back at step_sync to split decode time
     # from reconciliation in request traces (serve.retire `sync`)
     enqueued_tick: Optional[float] = None
+    # ARMED engines only, for a model that declares "counter" cache
+    # leaves (an expert layer's load): the leaves this dispatch left and
+    # the args of its ``engine.step.call`` span, which step_sync fills
+    counters: Optional[list] = None
+    call_args: Optional[dict] = None
 
 
 # shared serve-program plumbing (one copy for engine + spec programs)
@@ -334,9 +339,10 @@ def _prefill_inject_impl(model, params, pool_cache, prompts, lengths,
     # Shared bookkeeping (cache_index) the per-row kv_positions path
     # never reads: keep pool's.
     with jax.named_scope("prefill/kv_inject"):
-        pool_cache = inject_rows(pool_cache, pf_cache,
-                                 cache_layout(model, pool_cache), slots,
+        layout = cache_layout(model, pool_cache)
+        pool_cache = inject_rows(pool_cache, pf_cache, layout, slots,
                                  valid)
+        pool_cache = _carry_counters(pool_cache, pf_cache, layout)
     return dense_storage_commit(model, storage, pool_cache), first
 
 
@@ -455,6 +461,38 @@ def _continues_prefill(model) -> bool:
     return bool(getattr(model, "continues_prefill", False))
 
 
+def _carry_counters(pool_cache, fresh, layout):
+    """``pool_cache`` with every ``"counter"`` leaf (what a program's
+    last model call counted, owned by no slot: ``models/afmoe.py``'s
+    expert load) taken from ``fresh``. A cache that declares none — every
+    other model's — comes back as it is, leaf for leaf."""
+    return jax.tree_util.tree_map(
+        lambda held, new, decl: new if decl.kind == "counter" else held,
+        pool_cache, fresh, layout)
+
+
+def counter_leaves(model, cache) -> list:
+    """The ``"counter"`` leaves of ``cache``, in tree order."""
+    leaves = jax.tree_util.tree_leaves
+    return [leaf for leaf, decl in zip(
+        leaves(cache), leaves(cache_layout(model, cache)))
+        if decl.kind == "counter"]
+
+
+def expert_load_counts(counters: list) -> Dict[str, int]:
+    """Span args from a dispatch's expert-load leaves (one ``(held,)``
+    count a layer; THE host copy, armed engines only):
+    ``moe_assignments`` — local assignments, summed over layers —,
+    ``moe_experts_hit`` — (layer, expert) pairs that took a row —,
+    ``moe_load_max`` — the fullest expert's rows — and ``moe_experts``
+    — the pairs there are."""
+    load = np.stack([np.asarray(c) for c in counters])
+    return {"moe_assignments": int(load.sum()),
+            "moe_experts_hit": int((load > 0).sum()),
+            "moe_load_max": int(load.max()),
+            "moe_experts": int(load.size)}
+
+
 def _chunk_prefill_dense_impl(model, params, pool_cache, tokens, offset,
                               lengths, slots, valid, keys, temp, top_k,
                               startno):
@@ -493,6 +531,7 @@ def _chunk_prefill_dense_impl(model, params, pool_cache, tokens, offset,
     with jax.named_scope("chunk/kv_inject"):
         pool_cache = inject_blocks(pool_cache, updated["cache"], layout,
                                    slots, valid, offset, tokens.shape[1])
+        pool_cache = _carry_counters(pool_cache, updated["cache"], layout)
     return pool_cache, first
 
 
@@ -749,16 +788,17 @@ class ServeEngine:
             raise ValueError(
                 "page_native=True is a paged-KV mode (attention reads "
                 "K/V through the page table): pass page_size= too")
-        # chunked prefill into DENSE slots: a model with declared
-        # recurrent state whose prefill continues from the cache it is
-        # given (``continues_prefill``) takes a long prompt piece by
-        # piece with the state carried — _chunk_prefill_dense_impl
+        # chunked prefill into DENSE slots: a model whose prefill
+        # continues from the cache it is given (``continues_prefill``)
+        # takes a long prompt piece by piece, whatever state it declares
+        # carried in its slot — _chunk_prefill_dense_impl
         dense_chunk = (prefill_chunk is not None and page_size is None
-                       and getattr(model, "recurrent_state", False)
                        and _continues_prefill(model))
-        if getattr(model, "recurrent_state", False):
-            # a slot of such a model holds state that is no K/V row at
-            # absolute positions (a recurrence, a ring): what the engine
+        recurrent = bool(getattr(model, "recurrent_state", False))
+        if recurrent or _continues_prefill(model):
+            # a slot of such a model holds what is no K/V row at
+            # absolute positions (a recurrence, a ring, a latent row
+            # only the model's continue mode writes): what the engine
             # cannot carry it through yet refuses here, by name, and
             # never runs wrongly
             refused = [name for name, on in (
@@ -773,8 +813,10 @@ class ServeEngine:
                 ("max_resident_adapters (the LoRA bank)",
                  max_resident_adapters is not None)) if on]
             if refused:
+                declares = "recurrent state" if recurrent else \
+                    "a cache that only its continue mode writes"
                 raise ValueError(
-                    f"{type(model).__name__} declares recurrent state; "
+                    f"{type(model).__name__} declares {declares}; "
                     f"the engine cannot give it {', '.join(refused)} yet "
                     "(pages, int8 storage, prefix reuse, chunked prefill "
                     "of a state that cannot be continued and draft "
@@ -872,7 +914,7 @@ class ServeEngine:
                 "num_pages / prefill_chunk / prefix_cache are paged-KV "
                 "features: pass page_size= to enable the page arena "
                 "(prefill_chunk alone also serves a model that declares "
-                "recurrent_state and continues_prefill, on dense slots)")
+                "continues_prefill, on dense slots)")
         if prefill_chunk is not None:
             if prefill_chunk < 1:
                 raise ValueError(
@@ -1061,6 +1103,11 @@ class ServeEngine:
         # at-rest bytes a row (``global``: a position) of each cache kind
         # costs — filled by the first armed dispatch (_live_cache_bytes)
         self._cache_units: Optional[Dict[str, float]] = None
+        # does the model leave "counter" leaves in its dense cache (an
+        # expert layer's load)? An armed engine reads them a dispatch
+        self._has_counters = (
+            not self.paged and not check_kv_dtype(kv_dtype)
+            and bool(counter_leaves(self.model, self.pool.cache)))
         self._chunk_queue: Deque[_ChunkState] = deque()
         # the requests whose FINAL chunk the last prefill_chunk_step
         # dispatch activated into decode (the paged program feeds one
@@ -1896,13 +1943,16 @@ class ServeEngine:
                        rows=n_rows, slot=[st.slot for st in rows],
                        **self._count_topk_wide(tel, top_k),
                        **self._span_extra)
-              if tel is not None else NULL_SPAN):
+              if tel is not None else NULL_SPAN) as call_args:
             self.pool.cache, first = fn(
                 self.model, self.params, self.pool.cache, *args)
         with (tel.span("engine.chunk.sync", slot=[st.slot for st in rows],
                        **self._span_extra)
               if tel is not None else NULL_SPAN):
             first = np.asarray(first)
+            if tel is not None and self._has_counters:
+                call_args.update(expert_load_counts(
+                    counter_leaves(self.model, self.pool.cache)))
         self.chunk_dispatches += 1
         done: List[Completion] = []
         activated: List[Request] = []
@@ -2029,13 +2079,20 @@ class ServeEngine:
             fn, args = self._step_call()
         with (tel.span("engine.step.call", **self._count_step_rows(tel),
                        **self._span_extra)
-              if tel is not None else NULL_SPAN):
+              if tel is not None else NULL_SPAN) as call_args:
             (store, cur, pos, active, remaining, stepno, emitted,
              finished) = fn(*args, steps=self.steps_per_dispatch)
             if self.paged:
                 self.pool.arena = store
             else:
                 self.pool.cache = store
+        counters = None
+        if tel is not None and self._has_counters:
+            # read at step_sync; a pipelined dispatch's successor donates
+            # the cache first, so it keeps copies (armed only)
+            counters = counter_leaves(self.model, store)
+            if asynchronous:
+                counters = [jnp.copy(c) for c in counters]
         self._carry = (cur, pos, active, remaining, stepno)
         self.steps += 1
         self.decode_substeps += self.steps_per_dispatch
@@ -2047,7 +2104,8 @@ class ServeEngine:
             rounds=self.steps_per_dispatch, emitted=emitted,
             finished=finished, carry=self._carry,
             owner=self._engine_token, asynchronous=asynchronous,
-            enqueued_at=self._overlap_stamp(asynchronous))
+            enqueued_at=self._overlap_stamp(asynchronous),
+            counters=counters, call_args=call_args)
 
     def _overlap_stamp(self, asynchronous: bool) -> float:
         """``PendingDispatch.enqueued_at``: a wall stamp for
@@ -2214,6 +2272,9 @@ class ServeEngine:
             if pending.kind == "spec":
                 accepted = np.asarray(pending.accepted)   # (rounds, B)
                 rejected = np.asarray(pending.rejected)   # (rounds, B)
+            if pending.counters:
+                pending.call_args.update(
+                    expert_load_counts(pending.counters))
         # ---- commit point: everything below is host-side bookkeeping
         self._synced_dispatch = pending.dispatch
         self._cur, self._pos, self._active = cur, pos, active

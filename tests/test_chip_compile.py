@@ -440,3 +440,82 @@ def test_olmo_hybrid_engine_programs_compile_and_fit(one_chip, program):
     # 4 layers: 2.4 GB of bf16 weights + 1.3 GB of cache; the cell's 16
     # layers read 13.3 GB + 1.1 GB of temporaries (PERF.md section 6)
     assert total < 6e9, total
+
+
+# --------------------------------------------------------------------- #
+# Trinity / AFMoE at its published widths: the step and the dense-slot
+# chunk program of benchmark cell trinity-large-preview.serve.mixedlen32
+# --------------------------------------------------------------------- #
+@pytest.mark.parametrize("program", ["step", "chunk"])
+def test_afmoe_engine_programs_compile_and_fit(one_chip, program):
+    """Hidden 3072, 48 / 8 heads of 128, a window layer (a ring of 4096)
+    and a full layer (8192 positions), both with 32 held experts of 3072
+    out of 256 router outputs, an eighth of the vocabulary, 32 slots — 2
+    of the cell's 5 layers, to keep the compile short. The ragged
+    products must lower for a v5e as the compiler's own grouped product
+    (a ``ragged-dot`` custom call, not the expansion over every held
+    expert), and **no program may copy or transpose a K/V leaf of the
+    pool whole**: the one-position write and the piece inject go in
+    place, and the scores' product reads the leaf as it lies (heads
+    before positions; ``(B, L, 8 x 128)`` was transposed every step)."""
+    from ray_lightning_tpu.models.afmoe import (FULL, SLIDING, AfmoeConfig,
+                                                AfmoeLM)
+    from ray_lightning_tpu.serve import engine as E
+    S = _spec(one_chip)
+    slots, rows, piece, positions = 32, 2, 512, 8192
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(lambda a: S(a.shape, a.dtype), tree)
+
+    cfg = AfmoeConfig(num_hidden_layers=2, num_dense_layers=0,
+                      layer_types=(SLIDING, FULL), vocab_size=25024,
+                      experts_held=32, max_seq_len=positions, decode=True)
+    model = AfmoeLM(cfg)
+    init = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((slots, 1), jnp.int32)))
+    params, cache = abstract(init["params"]), abstract(init["cache"])
+    assert cache["layer_0_attn"]["ring_key"].shape == (slots, 8, 4096, 128)
+    assert cache["layer_1_attn"]["cached_key"].shape \
+        == (slots, 8, positions, 128)
+    if program == "step":
+        compiled = jax.jit(
+            E._engine_step_impl, static_argnames=("model", "steps"),
+            donate_argnums=(2,)).lower(
+                model, params, cache, S((slots, 1), jnp.int32),
+                S((slots, 1), jnp.int32), S((slots,), jnp.bool_),
+                S((slots,), jnp.int32), S((slots,), jnp.float32),
+                S((slots,), jnp.int32), S((slots,), jnp.int32),
+                S((slots, 2), jnp.uint32), S((slots,), jnp.int32), None,
+                steps=1).compile()
+    else:
+        compiled = jax.jit(
+            E._chunk_prefill_dense_impl, static_argnames=("model",),
+            donate_argnums=(2,)).lower(
+                model, params, cache, S((rows, piece), jnp.int32),
+                S((rows,), jnp.int32), S((rows,), jnp.int32),
+                S((rows,), jnp.int32), S((rows,), jnp.bool_),
+                S((rows, 2), jnp.uint32), S((rows,), jnp.float32),
+                S((rows,), jnp.int32), S((rows,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "ragged-dot" in text     # (the compiler's own custom call)
+    # a pool leaf, in whatever order of its axes, is the result of no copy
+    # and no transpose (32 x 8 x 4096 x 128 and 32 x 8 x 8192 x 128 values)
+    leaf_sizes = {slots * 8 * 4096 * 128, slots * 8 * positions * 128}
+    for line in text.splitlines():
+        head = line.split(" = ")[-1][:80]
+        if " copy(" not in line and " transpose(" not in line:
+            continue
+        dims = head[head.find("[") + 1:head.find("]")]
+        if dims.replace(",", "").isdigit():
+            assert int(np.prod([int(d) for d in dims.split(",")])) \
+                not in leaf_sizes, line[:200]
+    mem = compiled.memory_analysis()
+    # the step's temporaries are its logits and one layer's scores; the
+    # chunk's the two rows taken out of the pool, a block of scores and
+    # the grouped rows of the 4096 assignments
+    assert mem.temp_size_in_bytes < (0.1e9 if program == "step" else 0.6e9)
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    # 2 expert layers: 4.0 GB of bf16 weights + 0.15 GB of vocabulary +
+    # 1.6 GB of cache; the cell's 5 layers compile to 11.87 + 0.39 GB
+    assert total < 6.5e9, total
