@@ -19,8 +19,8 @@ Two clock modes:
 loop for the decode/verify hot path. When the scheduler's next action
 is another STEP (or spec-round) dispatch over the same resident rows,
 the tick ENQUEUES dispatch N+1 against the donated device carry first
-and only then syncs dispatch N's host copies — the scheduler, retire
-loop, deadline sweep and telemetry all run on N's tokens while N+1
+and only then syncs dispatch N's report (its one host copy) — the
+scheduler, retire loop, deadline sweep and telemetry all run on N's tokens while N+1
 computes, taking the fixed host round-trip (not measured on the
 current machine, ``docs/performance.md``) off the device's critical path.
 Admission/prefill/chunk dispatches stay synchronous barriers (they

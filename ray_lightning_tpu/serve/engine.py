@@ -81,7 +81,11 @@ The step/spec dispatch additionally splits into an **async seat**
 the device carry chains dispatch-to-dispatch without touching the
 host, so ``ServeClient(async_dispatch=True)`` overlaps all host work
 with the in-flight dispatch — see the class docs and
-``docs/serving.md#async-dispatch``.
+``docs/serving.md#async-dispatch``. Whichever driver runs it, a
+dispatch costs the host ONE device-to-host transfer: every step /
+spec-round program packs what ``step_sync`` reads into one flat int32
+**report** (:mod:`ray_lightning_tpu.serve.report`), its copy starts at
+enqueue, and ``step_sync`` waits for that buffer alone.
 """
 from __future__ import annotations
 
@@ -126,6 +130,7 @@ from ray_lightning_tpu.serve.pages import (PagePool, PrefixCache,
                                            gather_pages, pick_donated,
                                            quantize_dense_cache,
                                            scatter_pages, slot_leaves)
+from ray_lightning_tpu.serve.report import pack_report, unpack_report
 from ray_lightning_tpu.serve.spec import (SpecDecoder,
                                           _spec_page_native_donated,
                                           _spec_page_native_plain,
@@ -146,13 +151,17 @@ class PendingDispatch:
     """Deferred-sync handle for one enqueued step / spec-round dispatch.
 
     :meth:`ServeEngine.step_enqueue` returns one of these instead of
-    blocking on the host copies: ``emitted``/``finished`` (and the spec
-    accept ledgers) are still device arrays — futures under JAX's async
-    dispatch — and ``carry`` is the device-side engine state
+    blocking on the host copy: ``report`` is the program's one packed
+    int32 buffer (:mod:`~ray_lightning_tpu.serve.report`: the carry,
+    ``emitted``/``finished`` and, for a spec dispatch, the accept
+    ledgers), still a device array — a future under JAX's async
+    dispatch whose copy to the host was started at enqueue — and
+    ``carry`` is the device-side engine state
     (cur/pos/active/remaining/stepno) the NEXT enqueue chains on, so a
     second STEP dispatch can launch before this one's tokens ever touch
-    the host. :meth:`ServeEngine.step_sync` materializes the handle:
-    the host copy (THE blocking point), the retire loop, counters and
+    the host. The carry never leaves the device.
+    :meth:`ServeEngine.step_sync` materializes the handle: the wait for
+    that one buffer (THE blocking point), the retire loop, counters and
     telemetry. Handles must sync in enqueue order; an engine rebuild
     (crash recovery, fleet failover) DISCARDS outstanding handles — the
     synced frontier is the replay truth, an in-flight speculative
@@ -162,15 +171,12 @@ class PendingDispatch:
     kind: str          # "step" | "spec"
     dispatch: int      # engine.steps at enqueue (1-based)
     rounds: int        # steps_per_dispatch scanned inside the program
-    emitted: object    # (rounds, B) or (rounds, B, k+1) device array
-    finished: object   # (rounds, B) device array
+    report: object     # flat int32 device array: all step_sync fetches
     carry: tuple       # (cur, pos, active, remaining, stepno) on device
     owner: object = None       # identity nonce of the issuing engine —
     #                            a rebuilt engine refuses foreign
     #                            handles even when dispatch indices
     #                            realign (e.g. both at 1)
-    accepted: object = None    # spec only: (rounds, B) draft credits
-    rejected: object = None    # spec only: (rounds, B) real divergences
     asynchronous: bool = True  # False: the sync step() round-trip
     # host perf_counter stamp for serve_dispatch_overlap_ms — read only
     # by an ARMED engine (0.0 otherwise: a disarmed dispatch reads no
@@ -181,8 +187,9 @@ class PendingDispatch:
     # from reconciliation in request traces (serve.retire `sync`)
     enqueued_tick: Optional[float] = None
     # ARMED engines only, for a model that declares "counter" cache
-    # leaves (an expert layer's load): the leaves this dispatch left and
-    # the args of its ``engine.step.call`` span, which step_sync fills
+    # leaves (an expert layer's load): the leaves this dispatch left
+    # (step_sync's second fetch, one for the list) and the args of its
+    # ``engine.step.call`` span, which step_sync fills
     counters: Optional[list] = None
     call_args: Optional[dict] = None
 
@@ -272,8 +279,11 @@ def _engine_step_impl(model, params, cache, cur, pos, active, remaining,
     runs on the dequantized compute-dtype view and the result re-commits
     through the same storage — both fused into this one dispatch.
 
-    Returns the carried state plus ``emitted``/``finished`` stacked
-    ``(steps, B)`` — the host replays sub-steps in order.
+    Returns the carried state — it stays on the device, the next
+    dispatch chains on it — plus the dispatch's **report**
+    (:func:`~ray_lightning_tpu.serve.report.pack_report`): the carry and
+    ``emitted``/``finished`` stacked ``(steps, B)``, in one flat int32
+    buffer that is all the host fetches — it replays sub-steps in order.
     """
     # weight-quantized params dequantize ONCE per dispatch, here at the
     # program top (outside the step scan) — storage-only, same contract
@@ -295,7 +305,9 @@ def _engine_step_impl(model, params, cache, cur, pos, active, remaining,
         jax.lax.scan(body, (cache, cur, pos, active, remaining, stepno),
                      None, length=steps)
     cache = dense_storage_commit(model, storage, cache)
-    return (cache, cur, pos, active, remaining, stepno, emitted, finished)
+    return (cache, cur, pos, active, remaining, stepno,
+            pack_report(cur, pos, active, remaining, stepno, emitted,
+                        finished))
 
 
 def _prefill_inject_impl(model, params, pool_cache, prompts, lengths,
@@ -374,12 +386,12 @@ def _paged_step_impl(model, params, arena, page_table, cur, pos, active,
     """
     view = _gather_pages(model, arena, page_table)
     write_pt = jnp.where(active[:, None], page_table, -1)
-    (view, cur, pos, active, remaining, stepno, emitted, finished) = \
+    (view, cur, pos, active, remaining, stepno, report) = \
         _engine_step_impl(model, params, view, cur, pos, active,
                           remaining, temp, top_k, eos, keys, stepno,
                           adapter_ids, steps=steps)
     arena = _scatter_pages(model, arena, view, write_pt)
-    return (arena, cur, pos, active, remaining, stepno, emitted, finished)
+    return (arena, cur, pos, active, remaining, stepno, report)
 
 
 def _prefill_inject_paged_impl(model, params, arena, prompts, lengths,
@@ -479,14 +491,23 @@ def counter_leaves(model, cache) -> list:
         if decl.kind == "counter"]
 
 
+def _fetch(tree):
+    """A sync's device-to-host fetch: ONE wait for ``tree`` (an array,
+    or a list whose leaves travel together — ``jax.device_get`` starts
+    every leaf's copy before it reads the first). Every blocking copy of
+    ``engine.step.sync`` and ``engine.chunk.sync`` goes through this one
+    seam, so a test counts the fetches a sync makes, or fails one."""
+    return jax.device_get(tree)
+
+
 def expert_load_counts(counters: list) -> Dict[str, int]:
-    """Span args from a dispatch's expert-load leaves (one ``(held,)``
-    count a layer; THE host copy, armed engines only):
+    """Span args from a dispatch's expert-load leaves as fetched (one
+    ``(held,)`` count a layer; armed engines only):
     ``moe_assignments`` — local assignments, summed over layers —,
     ``moe_experts_hit`` — (layer, expert) pairs that took a row —,
     ``moe_load_max`` — the fullest expert's rows — and ``moe_experts``
     — the pairs there are."""
-    load = np.stack([np.asarray(c) for c in counters])
+    load = np.stack(counters)
     return {"moe_assignments": int(load.sum()),
             "moe_experts_hit": int((load > 0).sum()),
             "moe_load_max": int(load.max()),
@@ -571,7 +592,9 @@ def _page_native_step_impl(model, params, arena, page_table, cur, pos,
     (arena, cur, pos, active, remaining, stepno), (emitted, finished) = \
         jax.lax.scan(body, (arena, cur, pos, active, remaining, stepno),
                      None, length=steps)
-    return (arena, cur, pos, active, remaining, stepno, emitted, finished)
+    return (arena, cur, pos, active, remaining, stepno,
+            pack_report(cur, pos, active, remaining, stepno, emitted,
+                        finished))
 
 
 _engine_step_donated = partial(
@@ -1949,10 +1972,13 @@ class ServeEngine:
         with (tel.span("engine.chunk.sync", slot=[st.slot for st in rows],
                        **self._span_extra)
               if tel is not None else NULL_SPAN):
-            first = np.asarray(first)
             if tel is not None and self._has_counters:
-                call_args.update(expert_load_counts(
-                    counter_leaves(self.model, self.pool.cache)))
+                # one fetch for the first tokens and the expert load
+                first, load = _fetch(
+                    (first, counter_leaves(self.model, self.pool.cache)))
+                call_args.update(expert_load_counts(load))
+            else:
+                first = _fetch(first)
         self.chunk_dispatches += 1
         done: List[Completion] = []
         activated: List[Request] = []
@@ -2080,8 +2106,11 @@ class ServeEngine:
         with (tel.span("engine.step.call", **self._count_step_rows(tel),
                        **self._span_extra)
               if tel is not None else NULL_SPAN) as call_args:
-            (store, cur, pos, active, remaining, stepno, emitted,
-             finished) = fn(*args, steps=self.steps_per_dispatch)
+            (store, cur, pos, active, remaining, stepno,
+             report) = fn(*args, steps=self.steps_per_dispatch)
+            # the one transfer of this dispatch starts behind its
+            # program; step_sync waits for it and for nothing else
+            report.copy_to_host_async()
             if self.paged:
                 self.pool.arena = store
             else:
@@ -2093,6 +2122,8 @@ class ServeEngine:
             counters = counter_leaves(self.model, store)
             if asynchronous:
                 counters = [jnp.copy(c) for c in counters]
+            for c in counters:
+                c.copy_to_host_async()
         self._carry = (cur, pos, active, remaining, stepno)
         self.steps += 1
         self.decode_substeps += self.steps_per_dispatch
@@ -2101,8 +2132,8 @@ class ServeEngine:
                       kind="step")
         return PendingDispatch(
             kind="step", dispatch=self.steps,
-            rounds=self.steps_per_dispatch, emitted=emitted,
-            finished=finished, carry=self._carry,
+            rounds=self.steps_per_dispatch, report=report,
+            carry=self._carry,
             owner=self._engine_token, asynchronous=asynchronous,
             enqueued_at=self._overlap_stamp(asynchronous),
             counters=counters, call_args=call_args)
@@ -2221,10 +2252,14 @@ class ServeEngine:
         return fn.lower(*args, steps=self.steps_per_dispatch).as_text()
 
     def step_sync(self, pending: PendingDispatch) -> List[Completion]:
-        """Materialize one enqueued dispatch: copy its outputs to the
-        host (THE blocking point — everything the caller did since
-        :meth:`step_enqueue` overlapped the device), catch the synced
-        frontier up to its carry, and run the retire loop. Handles must
+        """Materialize one enqueued dispatch: wait for its report, the
+        one device-to-host transfer started at enqueue (THE blocking
+        point — everything the caller did since :meth:`step_enqueue`
+        overlapped the device; an armed engine whose model counts expert
+        load makes a second fetch, for those leaves), catch the synced
+        frontier up to the carry the report holds, and run the retire
+        loop. No other device array is read: the carry arrays stay on
+        the device for the next enqueue. Handles must
         sync in enqueue order; a handle from a rebuilt-away engine must
         be DISCARDED, never synced (its tokens were regenerated by
         replay)."""
@@ -2258,27 +2293,29 @@ class ServeEngine:
         # dispatch whose tokens were silently skipped
         with (tel.span("engine.step.sync", dispatch=pending.dispatch,
                        **self._span_extra)
-              if tel is not None else NULL_SPAN):
-            cur, pos, active, remaining, stepno = pending.carry
-            # np.array (copy): jax outputs view as read-only buffers, and
-            # the next prefill writes these rows in place
-            cur = np.array(cur)
-            pos = np.array(pos)
-            active = np.array(active)
-            remaining = np.array(remaining)
-            stepno = np.array(stepno)
-            emitted = np.asarray(pending.emitted)  # (steps, B), −1 = parked
-            finished = np.asarray(pending.finished)  # (steps, B)
-            if pending.kind == "spec":
-                accepted = np.asarray(pending.accepted)   # (rounds, B)
-                rejected = np.asarray(pending.rejected)   # (rounds, B)
+              if tel is not None else NULL_SPAN) as sync_args:
+            # np.array (copy): a fetched buffer is read-only, and the
+            # next prefill writes the carry rows in place
+            rep = unpack_report(
+                np.array(_fetch(pending.report)), self.num_slots,
+                pending.rounds,
+                self.spec.k + 1 if pending.kind == "spec" else None)
+            copies = 1
             if pending.counters:
                 pending.call_args.update(
-                    expert_load_counts(pending.counters))
+                    expert_load_counts(_fetch(pending.counters)))
+                copies = 2
+            if tel is not None:
+                sync_args["copies"] = copies
+                tel.metrics.counter(
+                    "serve_sync_copies_total",
+                    help="device-to-host fetches made by step/spec "
+                    "dispatch syncs (1 a dispatch: its report; 2 when "
+                    "expert-load counters are read)").inc(copies)
         # ---- commit point: everything below is host-side bookkeeping
         self._synced_dispatch = pending.dispatch
-        self._cur, self._pos, self._active = cur, pos, active
-        self._remaining, self._stepno = remaining, stepno
+        self._cur, self._pos, self._active = rep.cur, rep.pos, rep.active
+        self._remaining, self._stepno = rep.remaining, rep.stepno
         if self._carry is pending.carry:
             # frontier caught up with the newest enqueue — barrier
             # dispatches may run again
@@ -2286,11 +2323,12 @@ class ServeEngine:
         with (tel.span("engine.step.retire", **self._span_extra)
               if tel is not None else NULL_SPAN) as opened:
             if pending.kind == "spec":
-                done = self._sync_spec(pending, emitted, accepted,
-                                       rejected, finished, overlap_ms)
+                done = self._sync_spec(pending, rep.emitted, rep.accepted,
+                                       rep.rejected, rep.finished,
+                                       overlap_ms)
             else:
-                done = self._retire_rows(pending, emitted, finished,
-                                         overlap_ms)
+                done = self._retire_rows(pending, rep.emitted,
+                                         rep.finished, overlap_ms)
             if tel is not None:
                 opened["ids"] = [c.request_id for c in done]
         return done
@@ -2365,43 +2403,17 @@ class ServeEngine:
                             list(req.prompt) + self._tokens[slot][:-1])
             faults.fire("serve.verify")
             k, rounds = spec.k, self.steps_per_dispatch
-            cur, pos, act, remaining, stepno = self._carry_in()
+            fn, args = self._spec_call()
         with (tel.span("engine.spec.call", k=k,
                        **self._count_step_rows(tel), **self._span_extra)
               if tel is not None else NULL_SPAN):
-            if self.paged and self.page_native:
-                # the widened verify reads/writes target K/V through
-                # the page table too — spec and page-native compose on
-                # one engine (the draft cache stays dense either way)
-                fn = _pick(_spec_page_native_donated,
-                           _spec_page_native_plain)
-                (self.pool.arena, spec.cache, cur, pos, act, remaining,
-                 stepno, emitted, accepted, rejected, finished) = fn(
-                    self.model, spec.model, self.params, spec.params,
-                    self.pool.arena, self._write_masked_table(),
-                    spec.cache, cur, pos, act,
-                    remaining, self._temp, self._top_k, self._eos,
-                    self._keys, stepno, self._adapter_ids,
-                    k=k, rounds=rounds)
-            elif self.paged:
-                fn = _pick(_spec_paged_donated, _spec_paged_plain)
-                (self.pool.arena, spec.cache, cur, pos, act, remaining,
-                 stepno, emitted, accepted, rejected, finished) = fn(
-                    self.model, spec.model, self.params, spec.params,
-                    self.pool.arena, np.array(self.pool.page_table),
-                    spec.cache, cur, pos, act,
-                    remaining, self._temp, self._top_k, self._eos,
-                    self._keys, stepno, self._adapter_ids,
-                    k=k, rounds=rounds)
+            (store, spec.cache, cur, pos, act, remaining, stepno,
+             report) = fn(*args, k=k, rounds=rounds)
+            report.copy_to_host_async()
+            if self.paged:
+                self.pool.arena = store
             else:
-                fn = _pick(_spec_rounds_donated, _spec_rounds_plain)
-                (self.pool.cache, spec.cache, cur, pos, act, remaining,
-                 stepno, emitted, accepted, rejected, finished) = fn(
-                    self.model, spec.model, self.params, spec.params,
-                    self.pool.cache, spec.cache, cur, pos,
-                    act, remaining, self._temp,
-                    self._top_k, self._eos, self._keys, stepno,
-                    self._adapter_ids, k=k, rounds=rounds)
+                self.pool.cache = store
         self._carry = (cur, pos, act, remaining, stepno)
         self.steps += 1
         # one verify = one target param read, however many tokens it
@@ -2414,11 +2426,33 @@ class ServeEngine:
                       kind="spec")
         return PendingDispatch(
             kind="spec", dispatch=self.steps, rounds=rounds,
-            emitted=emitted, finished=finished, carry=self._carry,
-            owner=self._engine_token,
-            accepted=accepted, rejected=rejected,
-            asynchronous=asynchronous,
+            report=report, carry=self._carry,
+            owner=self._engine_token, asynchronous=asynchronous,
             enqueued_at=self._overlap_stamp(asynchronous))
+
+    def _spec_call(self) -> tuple:
+        """:meth:`_step_call` for a speculative dispatch: the jitted
+        spec-round program of this engine's storage layout and its
+        positional operands (the draft cache stays dense either way)."""
+        spec = self.spec
+        if self.paged and self.page_native:
+            # the widened verify reads/writes target K/V through the
+            # page table too — spec and page-native compose on one engine
+            fn = _pick(_spec_page_native_donated, _spec_page_native_plain)
+            store = (self.pool.arena, self._write_masked_table(),
+                     spec.cache)
+        elif self.paged:
+            fn = _pick(_spec_paged_donated, _spec_paged_plain)
+            store = (self.pool.arena, np.array(self.pool.page_table),
+                     spec.cache)
+        else:
+            fn = _pick(_spec_rounds_donated, _spec_rounds_plain)
+            store = (self.pool.cache, spec.cache)
+        cur, pos, active, remaining, stepno = self._carry_in()
+        return fn, (self.model, spec.model, self.params, spec.params,
+                    *store, cur, pos, active, remaining, self._temp,
+                    self._top_k, self._eos, self._keys, stepno,
+                    self._adapter_ids)
 
     def _sync_spec(self, pending: PendingDispatch, emitted, accepted,
                    rejected, finished,

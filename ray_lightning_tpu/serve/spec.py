@@ -78,6 +78,7 @@ from ray_lightning_tpu.serve.pages import (dense_storage_commit,
                                            fold_rows, gather_pages,
                                            pick_donated, scatter_pages,
                                            slot_leaves)
+from ray_lightning_tpu.serve.report import pack_report
 
 __all__ = ["SpecDecoder"]
 
@@ -268,6 +269,11 @@ def _spec_rounds_impl(model, draft_model, params, draft_params, cache,
     adapted ``p``, so the draft model stays UNADAPTED (one draft serves
     every adapter; a mismatched draft only costs acceptance rate, never
     correctness).
+
+    Returns both caches, the device carry and the dispatch's report
+    (:func:`~ray_lightning_tpu.serve.report.pack_report`: the carry,
+    ``emitted`` ``(rounds, B, k+1)``, ``finished`` and the accept
+    ledgers in one flat int32 buffer — all the host fetches).
     """
     params = materialize_for_program(params, model.cfg)
     draft_params = materialize_for_program(draft_params, draft_model.cfg)
@@ -298,7 +304,8 @@ def _spec_rounds_impl(model, draft_model, params, draft_params, cache,
             None, length=rounds)
     cache = dense_storage_commit(model, storage, cache)
     return (cache, draft_cache, cur, pos, active, remaining, stepno,
-            emitted, accepted, rejected, finished)
+            pack_report(cur, pos, active, remaining, stepno, emitted,
+                        finished, accepted, rejected))
 
 
 def _spec_rounds_paged_impl(model, draft_model, params, draft_params,
@@ -311,14 +318,14 @@ def _spec_rounds_paged_impl(model, draft_model, params, draft_params,
     write-masked exactly as in the plain paged step."""
     view = gather_pages(model, arena, page_table)
     write_pt = jnp.where(active[:, None], page_table, -1)
-    (view, draft_cache, cur, pos, active, remaining, stepno, emitted,
-     accepted, rejected, finished) = _spec_rounds_impl(
+    (view, draft_cache, cur, pos, active, remaining, stepno,
+     report) = _spec_rounds_impl(
         model, draft_model, params, draft_params, view, draft_cache,
         cur, pos, active, remaining, temp, top_k, eos, keys, stepno,
         adapter_ids, k=k, rounds=rounds)
     arena = scatter_pages(model, arena, view, write_pt)
     return (arena, draft_cache, cur, pos, active, remaining, stepno,
-            emitted, accepted, rejected, finished)
+            report)
 
 
 def _spec_rounds_page_native_impl(model, draft_model, params,
@@ -364,7 +371,8 @@ def _spec_rounds_page_native_impl(model, draft_model, params,
             (arena, draft_cache, cur, pos, active, remaining, stepno),
             None, length=rounds)
     return (arena, draft_cache, cur, pos, active, remaining, stepno,
-            emitted, accepted, rejected, finished)
+            pack_report(cur, pos, active, remaining, stepno, emitted,
+                        finished, accepted, rejected))
 
 
 def _draft_refill_impl(draft_model, draft_params, pool_cache, tokens,

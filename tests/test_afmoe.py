@@ -608,6 +608,37 @@ def test_expert_load_counters_on_the_dispatch_spans(params):
     assert client.engine._has_counters
 
 
+def test_armed_syncs_fetch_the_counters_in_one_batch(params, monkeypatch):
+    """Armed, a step's sync makes two fetches — the report, then the
+    counter leaves as one list — and says so (``copies``,
+    ``serve_sync_copies_total``); a chunk's sync makes one, the first
+    tokens and the leaves together. Unarmed, one each and no leaf."""
+    from ray_lightning_tpu.serve import engine as E
+    seen = []
+    real = E._fetch
+    monkeypatch.setattr(E, "_fetch",
+                        lambda tree: (seen.append(tree), real(tree))[1])
+    layers = SHAPE["num_hidden_layers"] - SHAPE["num_dense_layers"]
+    work = [(_tokens(40, 40), 5), (_tokens(41, 5), 5)]
+    tel = Telemetry()
+    client = _client(params, telemetry=tel)
+    _serve(client, work)
+    steps, chunks = client.engine.steps, client.engine.chunk_dispatches
+    assert steps and chunks and len(seen) == 2 * steps + chunks
+    lists = [t for t in seen if isinstance(t, list)]
+    pairs = [t for t in seen if isinstance(t, tuple)]
+    assert len(lists) == steps and all(len(t) == layers for t in lists)
+    assert len(pairs) == chunks and all(len(t[1]) == layers for t in pairs)
+    syncs = tel.spans.spans("engine.step.sync")
+    assert [s.args["copies"] for s in syncs] == [2] * steps
+    assert tel.metrics.snapshot()["serve_sync_copies_total"] == 2 * steps
+    del seen[:]
+    client = _client(params)
+    _serve(client, work)
+    assert len(seen) == client.engine.steps + client.engine.chunk_dispatches
+    assert not any(isinstance(t, (list, tuple)) for t in seen)
+
+
 def test_byte_counters_follow_the_leaf_kinds(params):
     """``window_bytes`` is a row's rings, whole, whatever its context;
     ``global_bytes`` grows with the context."""
