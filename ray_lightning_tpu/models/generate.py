@@ -5,8 +5,11 @@ production LM server (vLLM, TGI, JetStream) made canonical:
 
 1. **Prefill** (:func:`prefill`): the whole ``(B, P)`` prompt runs
    through the decode-mode model in ONE compiled forward — a length-P
-   block lands in the KV cache via ``dynamic_update_slice`` under an
-   intra-prompt causal mask, and the last-position logits come back.
+   block lands in the KV cache via ``dynamic_update_slice`` and attends
+   its own keys causally (the cache is built empty in the same program:
+   ``transformer.fresh_cache``; the blockwise kernel from
+   ``FLASH_MIN_LEN`` positions on a TPU), and the last-position logits
+   come back.
    Prompt cost is one matmul-rich pass instead of P sequential
    ~per-token dispatches (history and reasons: the
    ``docs/performance.md`` decode section; today's levels: ``PERF.md``,
@@ -74,7 +77,8 @@ import jax
 import jax.numpy as jnp
 
 from ray_lightning_tpu.models.quant import materialize_for_program
-from ray_lightning_tpu.models.transformer import latch_eos
+from ray_lightning_tpu.models.transformer import fresh_cache, latch_eos
+from ray_lightning_tpu.obs import seats
 
 
 @dataclasses.dataclass(frozen=True)
@@ -407,9 +411,10 @@ def _prefill_impl(model, params, prompt_tokens, prompt_lengths,
     params = materialize_for_program(params, model.cfg)
     B, P = prompt_tokens.shape
     prompt_tokens = prompt_tokens.astype(jnp.int32)
-    cache = model.init(jax.random.PRNGKey(0),
-                       jnp.zeros((B, 1), jnp.int32),
-                       positions=jnp.zeros((B, 1), jnp.int32))["cache"]
+    with seats.unseen():
+        cache = model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((B, 1), jnp.int32),
+                           positions=jnp.zeros((B, 1), jnp.int32))["cache"]
     positions = jax.lax.broadcasted_iota(jnp.int32, (1, P), 1)
     if getattr(model, "recurrent_state", False) \
             or getattr(model, "continues_prefill", False):
@@ -424,10 +429,13 @@ def _prefill_impl(model, params, prompt_tokens, prompt_lengths,
             positions=positions, lengths=lengths, deterministic=True,
             mutable=["cache"], **_adapter_kw(adapter_ids))
         return updated["cache"], _logits_only(outputs)[:, -1]
-    outputs, updated = model.apply(
-        {"params": params, "cache": cache}, prompt_tokens,
-        positions=positions, deterministic=True, mutable=["cache"],
-        **_adapter_kw(adapter_ids))
+    # the cache above is empty and this block lands at position 0: each
+    # attention call may attend the block itself (transformer.fresh_cache)
+    with fresh_cache():
+        outputs, updated = model.apply(
+            {"params": params, "cache": cache}, prompt_tokens,
+            positions=positions, deterministic=True, mutable=["cache"],
+            **_adapter_kw(adapter_ids))
     logits = _logits_only(outputs)
     if prompt_lengths is None:
         last = logits[:, -1]

@@ -13,7 +13,13 @@ TPU-first choices baked in:
   the seat reads its own call — :func:`attention_seat` — and runs the
   pallas blockwise kernel for causal self-attention with no mask and no
   dropout from ``FLASH_MIN_LEN`` positions up on a TPU, per device under a
-  mesh, and the dense XLA path for everything else), 'flash' (the
+  mesh, and the dense XLA path for everything else; in decode mode that
+  is one case only, a prompt block written at position 0 of a cache its
+  own program built empty — :func:`fresh_cache`, opened by
+  ``generate._prefill_impl`` — which attends its own keys and not the
+  whole cache under a mask; every other cached call, a one-token step, a
+  per-row ``kv_positions`` block, a block at an offset, the page-native
+  path, stays dense), 'flash' (the
   blockwise kernel asked for by name), 'ring' (sequence-parallel ring
   attention over the ``sp`` mesh axis), 'ulysses' (all-to-all head-sharded
   sequence parallelism over the same axis).
@@ -23,8 +29,10 @@ rules (``MeshStrategy(param_rule=...)``) match on these names.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
+import threading
 from typing import Any, Optional
 
 import flax.linen as nn
@@ -375,9 +383,30 @@ def _projection(cfg: TransformerConfig, *, features, in_features: int,
 #: at all three (PERF.md section 6, PR 33); nothing shorter was timed.
 FLASH_MIN_LEN = 256
 
+_FRESH = threading.local()
+
+
+@contextlib.contextmanager
+def fresh_cache():
+    """Trace the calls inside as writing the first block of a decode cache
+    that the program being traced built empty (``model.init`` in the same
+    program; ``generate._prefill_impl`` says so). A decode-mode attention
+    call there that writes a shared-index block with no caller mask
+    attends the block's own keys, causally and unmasked — positions past
+    the block are empty — and the seat may hand it to the kernel. A fact
+    of the trace, not of the configuration: nothing outside the block
+    changes."""
+    was = getattr(_FRESH, "open", False)
+    _FRESH.open = True
+    try:
+        yield
+    finally:
+        _FRESH.open = was
+
 
 def attention_seat(q_shape, k_shape, *, decode: bool, causal: bool, mask,
-                   dropout: bool, backend: Optional[str] = None):
+                   dropout: bool, backend: Optional[str] = None,
+                   fresh: bool = False):
     """Which program computes the attention call being traced, from the
     call itself and the ambient mesh: ``(kernel, how)``.
 
@@ -391,8 +420,12 @@ def attention_seat(q_shape, k_shape, *, decode: bool, causal: bool, mask,
     ``dropout``, ``length`` (query and key lengths differ or are under
     ``FLASH_MIN_LEN``), ``head_dim``, ``backend``, ``sequence_cut`` (an
     ``sp`` axis: ring / ulysses own that case), ``batch_indivisible``.
+
+    A ``decode`` call is refused unless it is ``fresh``: the first block
+    of a cache its program built empty (:func:`fresh_cache`), which
+    attends its own keys and goes through the other tests like any call.
     """
-    if decode:
+    if decode and not fresh:
         return False, "decode"
     if not causal:
         return False, "non_causal"
@@ -442,9 +475,18 @@ def _blockwise_attention(q, k, v, how: str):
         check_vma=False)(q, k, v)
 
 
-def _attention_fn(cfg: TransformerConfig):
+@jax.jit
+def _fresh_block_kernel(q, k, v):
+    """The kernel on a fresh prefill block, behind one ``jit``: a serve
+    program's unrolled layers call it at one shape, so it is traced and
+    lowered once, not once a layer (36 Mosaic lowerings put seconds on
+    every start of an engine)."""
+    return pallas_flash_attention(q, k, v, causal=True)
+
+
+def _attention_fn(cfg: TransformerConfig, fresh: bool = False):
     if cfg.attention_impl == "dot":
-        return functools.partial(_adaptive_attention, cfg.decode)
+        return functools.partial(_adaptive_attention, cfg.decode, fresh)
     if cfg.attention_impl == "flash":
         from ray_lightning_tpu.ops.flash_attention import flash_attention
         return flash_attention
@@ -458,14 +500,16 @@ def _attention_fn(cfg: TransformerConfig):
     raise ValueError(f"Unknown attention_impl {cfg.attention_impl!r}")
 
 
-def _adaptive_attention(decode, q, k, v, *, causal, mask, dropout_rate,
-                        dropout_rng, **kw):
+def _adaptive_attention(decode, fresh, q, k, v, *, causal, mask,
+                        dropout_rate, dropout_rng, **kw):
     """The default seat: the blockwise kernel where :func:`attention_seat`
     says so, ``dot_product_attention`` exactly as ever elsewhere."""
     kernel, how = attention_seat(
         q.shape, k.shape, decode=decode, causal=causal, mask=mask,
-        dropout=dropout_rate > 0.0 and dropout_rng is not None)
+        dropout=dropout_rate > 0.0 and dropout_rng is not None, fresh=fresh)
     seats.note(kernel, how)
+    if kernel and fresh and how == "local":
+        return _fresh_block_kernel(q, k, v)
     if kernel:
         return _blockwise_attention(q, k, v, how)
     return dot_product_attention(
@@ -503,17 +547,23 @@ class MultiHeadAttention(nn.Module):
                 in_features=cfg.n_heads * cfg.head_dim,
                 name="out")(out, adapter_ids)
         causal = cfg.causal
+        # the first block of a cache this program built empty attends
+        # itself (fresh_cache): a trace-time fact, never a traced index
+        fresh = cfg.decode and kv_positions is None and mask is None \
+            and getattr(_FRESH, "open", False)
         if cfg.decode:
-            k, v, cache_mask = self._decode_cache(k, v, kv_positions)
+            k, v, cache_mask = self._decode_cache(k, v, kv_positions, fresh)
             if cache_mask is not None:
                 # combine with any caller mask (e.g. left-pad masking for
                 # batched prompts) — both are additive 0/-inf biases
                 mask = cache_mask if mask is None else mask + cache_mask
-            causal = False  # the cache mask already encodes causality
+            # the cache mask already encodes causality; a fresh block
+            # comes back unmasked and is causal over its own keys
+            causal = fresh
         drop_rng = None
         if cfg.dropout > 0.0 and not deterministic:
             drop_rng = self.make_rng("dropout")
-        attn = _attention_fn(cfg)
+        attn = _attention_fn(cfg, fresh)
         kw = {}
         if cfg.attention_softmax_dtype != jnp.float32 and \
                 cfg.attention_impl in ("dot", "ulysses"):
@@ -534,7 +584,7 @@ class MultiHeadAttention(nn.Module):
             in_features=cfg.n_heads * cfg.head_dim,
             name="out")(out, adapter_ids)
 
-    def _decode_cache(self, k, v, kv_positions=None):
+    def _decode_cache(self, k, v, kv_positions=None, fresh=False):
         """KV-cache update (flax decode pattern): the "cache" collection
         holds keys/values for all ``max_seq_len`` positions. Two write
         modes:
@@ -544,7 +594,11 @@ class MultiHeadAttention(nn.Module):
           per-token decode step; T>1 is the prefill path writing the whole
           prompt in one ``dynamic_update_slice``). The returned additive
           mask is intra-block causal over the cache buffer: query ``q`` of
-          the block attends positions ``<= cache_index + q``.
+          the block attends positions ``<= cache_index + q``. When the
+          block is ``fresh`` (:func:`fresh_cache`: ``cache_index`` is 0
+          and the cache was built empty in this program) the write is the
+          same, and the block's own ``k``, ``v`` come back with no mask:
+          attended causally they give what the mask gives over the cache.
         - ``kv_positions`` (B, T) — per-row block write of ``T >= 1``
           tokens at each row's own absolute positions (ragged decode:
           rows sit at different sequence lengths; T=1 is the classic
@@ -594,6 +648,8 @@ class MultiHeadAttention(nn.Module):
             cv.value = jax.lax.dynamic_update_slice(cv.value, v,
                                                     (0, idx, 0, 0))
         ci.value = idx + T
+        if fresh:
+            return k, v, None
         if T == 1:
             mask = jnp.where(key_pos <= idx, 0.0, big_neg)      # (1,1,1,S)
         else:

@@ -24,6 +24,18 @@ def note(kernel: bool, how: str) -> None:
 
 
 @contextlib.contextmanager
+def unseen():
+    """Trace the block without counting its seats: a pass that only builds
+    shapes inside a program (a decode cache's ``model.init``) attends
+    nothing the program keeps."""
+    prev, _OPEN.seen = getattr(_OPEN, "seen", None), None
+    try:
+        yield
+    finally:
+        _OPEN.seen = prev
+
+
+@contextlib.contextmanager
 def tally(args: Optional[Dict[str, Any]]):
     """Count the seats traced inside the block onto ``args`` (a span's
     argument dict, ``None`` when telemetry is disarmed): ``attn_kernel``,

@@ -116,6 +116,7 @@ from ray_lightning_tpu.models.quant import (DEFAULT_GROUP_SIZE,
                                             materialize_for_program,
                                             param_bytes, quantize_params)
 from ray_lightning_tpu.models.transformer import latch_eos
+from ray_lightning_tpu.obs import seats
 from ray_lightning_tpu.obs.spans import NULL_SPAN
 from ray_lightning_tpu.ops.cache_write import (inject_blocks, inject_rows,
                                                 take_rows)
@@ -1643,8 +1644,11 @@ class ServeEngine:
                       help="tokens the prefill program was run over: "
                       "prefill_batch x prefill_len per dispatch"
                       ).inc(counts["program_tokens"])
+        # the call that traces the program also counts its attention
+        # seats onto the span (obs/seats.py); later calls add nothing
         with (tel.span("engine.prefill.call", **counts, **self._span_extra)
-              if tel is not None else NULL_SPAN):
+              if tel is not None else NULL_SPAN) as span_args, \
+                seats.tally(span_args):
             if self.paged:
                 fn = _pick(_prefill_paged_donated, _prefill_paged_plain)
                 self.pool.arena, first = fn(

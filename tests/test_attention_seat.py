@@ -6,6 +6,7 @@ Everything here runs on the CPU: the rule is asked with ``backend="tpu"``
 or through a monkeypatched ``jax.default_backend`` (the program has no
 option for it), the kernel runs in the pallas interpreter.
 """
+import dataclasses
 import functools
 
 import jax
@@ -26,6 +27,7 @@ from ray_lightning_tpu.parallel import sharding as shardlib
 
 QKV = (8, 1024, 16, 64)
 MASK = np.zeros((1, 1, 1024, 1024), np.float32)
+BLOCK = (4, 512, 20, 64)        # gpt2-large.serve.closed40's prefill block
 
 
 def _mesh(**axes):
@@ -80,6 +82,20 @@ def _ask(q=QKV, k=None, *, mesh=None, manual=False, backend="tpu", **kw):
      (False, "sequence_cut")),
     ("manual_region", dict(mesh=("fsdp", 4), manual=True),
      (True, "local")),
+    # a decode-mode call: only a fresh prefill block (transformer.
+    # fresh_cache) goes on to the other tests — closed40's 4 x 512 block
+    ("fresh_block", dict(q=BLOCK, decode=True, fresh=True), (True, "local")),
+    ("fresh_short", dict(q=(4, 128, 20, 64), decode=True, fresh=True),
+     (False, "length")),
+    ("fresh_masked", dict(q=BLOCK, decode=True, fresh=True,
+                          mask=MASK[..., :512, :512]), (False, "mask")),
+    ("fresh_on_cpu", dict(q=BLOCK, decode=True, fresh=True, backend="cpu"),
+     (False, "backend")),
+    ("fresh_fsdp4", dict(q=BLOCK, decode=True, fresh=True,
+                         mesh=("fsdp", 4)), (True, "sharded")),
+    ("cache_read", dict(q=BLOCK, k=(4, 1024, 20, 64), decode=True,
+                        causal=False, mask=MASK[..., :512, :]),
+     (False, "decode")),
 ], ids=lambda v: v if isinstance(v, str) else "")
 def test_the_seat_decides_from_its_call_and_the_mesh(case, kwargs, want):
     kwargs = dict(kwargs)
@@ -211,3 +227,174 @@ def test_dropout_in_training_keeps_the_dense_path(monkeypatch):
     with seats.tally(took):     # evaluation: no active dropout
         jax.eval_shape(lambda p: model.apply({"params": p}, tokens), params)
     assert took["attn_kernel"] >= 1 and took["attn_dense"] == 0
+
+
+# --------------------------------------------------------------------- #
+# decode mode: a fresh prefill block is the one cached call the seat may
+# hand to the kernel (transformer.fresh_cache, opened by _prefill_impl)
+# --------------------------------------------------------------------- #
+@pytest.fixture
+def seat_on_tpu(monkeypatch):
+    """The seat alone asked as a TPU would ask it from 128 positions, the
+    kernel interpreted; the rest of the program (cache writes, donation)
+    stays the CPU's. Compiled programs traced either way are dropped on
+    both sides of the test."""
+    monkeypatch.setattr(transformer, "attention_seat",
+                        functools.partial(attention_seat, backend="tpu"))
+    monkeypatch.setattr(transformer, "FLASH_MIN_LEN", 128)
+    monkeypatch.setattr(
+        transformer, "pallas_flash_attention",
+        functools.partial(pallas_flash_attention, interpret=True))
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+def _decode_nano(L=512, scan=False):
+    """Nano widths with heads of 64 (the kernel's case), bf16 compute."""
+    return TransformerConfig(
+        vocab_size=97, max_seq_len=L, d_model=128, n_heads=2, n_layers=2,
+        d_ff=256, dtype=jnp.bfloat16, causal=True, decode=True,
+        scan_layers=scan)
+
+
+def _params(cfg):
+    train = dataclasses.replace(cfg, decode=False)
+    return TransformerLM(train).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
+
+
+def _cache_at(model, B, offset):
+    """An empty cache whose shared index points at ``offset``: what the
+    chunk program hands the model for a piece of a prompt."""
+    with seats.unseen():        # the shape-building pass is no call
+        cache = model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((B, 1), jnp.int32),
+                           positions=jnp.zeros((B, 1), jnp.int32))["cache"]
+    return jax.tree_util.tree_map_with_path(
+        lambda p, leaf: (jnp.full(leaf.shape, offset, leaf.dtype)
+                         if "cache_index" in jax.tree_util.keystr(p)
+                         else leaf), cache)
+
+
+def _continuation(kind, model, params, T=256):
+    """One decode-mode call after a prefill of ``T`` positions, traced."""
+    from ray_lightning_tpu.models.generate import decode_step, verify_step
+    B = 2
+    cache = _cache_at(model, B, T)
+    one = jnp.zeros((B, 1), jnp.int32)
+    block = jnp.zeros((B, 128), jnp.int32)
+    at = jnp.full((B, 1), T, jnp.int32)
+    if kind == "step":              # a shared-index T = 1 step
+        return model.apply({"params": params, "cache": cache}, one,
+                           positions=at, mutable=["cache"])
+    if kind == "per_row_step":      # the engine's and generate()'s step
+        return decode_step(model, params, cache, one, at)
+    if kind == "per_row_block":     # a speculative verify block
+        return verify_step(model, params, cache, block,
+                           T + jnp.arange(128)[None, :].repeat(B, 0))
+    if kind == "block_after_prefill":   # a shared-index block at T
+        return model.apply({"params": params, "cache": cache}, block,
+                           positions=T + jnp.arange(128)[None, :],
+                           mutable=["cache"])
+    assert kind == "chunk_at_offset"    # the chunk program's traced index
+
+    def piece(offset):
+        return model.apply(
+            {"params": params, "cache": _cache_at(model, B, offset)}, block,
+            positions=offset + jnp.arange(128)[None, :], mutable=["cache"])
+    return piece(jnp.int32(T))
+
+
+@pytest.mark.parametrize("kind", ["step", "per_row_step", "per_row_block",
+                                  "block_after_prefill", "chunk_at_offset"])
+def test_every_continuation_keeps_the_dense_path(seat_on_tpu, kind):
+    """Only the prefill's first block is fresh: a call that attends what an
+    earlier call cached is refused with ``decode``, whatever its length."""
+    from ray_lightning_tpu.models.generate import prefill
+    model = TransformerLM(_decode_nano())
+    params = _params(model.cfg)
+    took = {}
+    with seats.tally(took):
+        jax.eval_shape(lambda p: prefill(
+            model, p, jnp.zeros((2, 256), jnp.int32)), params)
+    assert took["attn_kernel"] == 2 and took["attn_dense"] == 0
+    took = {}
+    with seats.tally(took):
+        jax.eval_shape(lambda p: _continuation(kind, model, p), params)
+    assert took["attn_kernel"] == 0 and took["attn_dense"] == 2
+    assert took["attn_dense_reason"] == "decode"
+
+
+@pytest.mark.parametrize("seat", ["kernel", "dense"])
+@pytest.mark.parametrize("scan,L", [(False, 512), (True, 256)],
+                         ids=["unrolled-cache512", "scanned-cache256"])
+def test_fresh_prefill_against_the_masked_cache_read(request, monkeypatch,
+                                                    seat, scan, L):
+    """``prefill()`` on ragged rows of 256 positions: the fresh block on
+    the kernel (or dense over its own keys) against the read of the whole
+    cache under its mask — the last logits, and K / V at every valid
+    position of every layer, to the train test's tolerance."""
+    import contextlib
+    import importlib
+
+    from ray_lightning_tpu.models.generate import _prefill_impl, prefill
+    gen = importlib.import_module("ray_lightning_tpu.models.generate")
+    model = TransformerLM(_decode_nano(L, scan))
+    params = _params(model.cfg)
+    B, P = 3, 256
+    tokens = jax.random.randint(jax.random.PRNGKey(2), (B, P), 0, 97)
+    lengths = jnp.array([256, 200, 131], jnp.int32)
+    with monkeypatch.context() as m:    # no block is fresh: the mask path
+        m.setattr(gen, "fresh_cache", contextlib.nullcontext)
+        want_cache, want = jax.jit(
+            lambda p, t, n: _prefill_impl(model, p, t, n))(
+                params, tokens, lengths)
+    if seat == "kernel":
+        request.getfixturevalue("seat_on_tpu")
+    took = {}
+    with seats.tally(took):
+        got_cache, got = prefill(model, params, tokens, lengths)
+    if seat == "kernel":
+        assert took["attn_kernel"] >= 1 and took["attn_dense"] == 0
+    else:
+        assert took["attn_kernel"] == 0
+        assert took["attn_dense_reason"] == "backend"
+
+    def close(g, w, what):
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        assert np.abs(g - w).max() <= 3e-2 * (np.abs(w).max() + 1e-6), what
+
+    close(got, want, "last logits")
+    valid = np.arange(L)[None, :] < np.asarray(lengths)[:, None]   # (B, L)
+    flat, _ = jax.tree_util.tree_flatten_with_path(want_cache)
+    for (path, w), g in zip(flat, jax.tree_util.tree_leaves(got_cache)):
+        name = jax.tree_util.keystr(path)
+        if "cache_index" in name:
+            assert int(np.asarray(g).ravel()[0]) == P, name
+            continue
+        g, w = np.asarray(g), np.asarray(w)
+        if scan:                            # (layers, B, L, H, D)
+            g, w = g[:, valid], w[:, valid]
+        else:                               # (B, L, H, D)
+            g, w = g[valid], w[valid]
+        close(g, w, name)
+
+
+@pytest.mark.parametrize("kind", ["dense", "paged"])
+def test_the_prefill_span_counts_the_seats(seat_on_tpu, kind):
+    """The engine's inject program, dense or paged, traced by the armed
+    ``engine.prefill.call``: every seat on the kernel; a later prefill
+    traces nothing and says nothing."""
+    from ray_lightning_tpu.serve import Request, ServeEngine
+    model = TransformerLM(_decode_nano())
+    tel = Telemetry()
+    engine = ServeEngine(model, _params(model.cfg), num_slots=2,
+                         prefill_len=256, prefill_batch=2, telemetry=tel,
+                         **({"page_size": 64} if kind == "paged" else {}))
+    for ids in ((0, 1), (2,)):
+        engine.prefill([Request(id=i, prompt=list(range(1, 40 + i)),
+                                max_new_tokens=1) for i in ids])
+    first, second = tel.spans.spans("engine.prefill.call")
+    assert first.args["attn_kernel"] == 2 and first.args["attn_dense"] == 0
+    assert "attn_kernel" not in second.args

@@ -103,6 +103,51 @@ def test_default_seat_takes_the_kernel_at_the_medium_cells_shapes(
     assert "tpu_custom_call" not in short       # under FLASH_MIN_LEN
 
 
+@pytest.mark.parametrize("min_len", [256, 1024], ids=["seat", "dense"])
+def test_closed40_prefill_takes_the_kernel_for_its_fresh_block(
+        one_chip, monkeypatch, min_len):
+    """``gpt2-large.serve.closed40``'s prefill inject program (GPT-2 large
+    widths, 4 x 512 prompts into 40 slots of 1024 positions; 2 of its 36
+    layers, to keep the compile short) through the default seat: each
+    layer's fresh block runs the flash forward kernel and no
+    ``f32[4,20,512,1024]`` scores are built. With the kernel held off
+    (``FLASH_MIN_LEN`` past the block) the fresh block is dense over its
+    own 512 keys — ``[4,20,512,512]`` — never over the whole cache."""
+    from ray_lightning_tpu.models import transformer
+    from ray_lightning_tpu.serve import engine as E
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(transformer, "FLASH_MIN_LEN", min_len)
+    jax.clear_caches()          # the seat decides while the program traces
+    S = _spec(one_chip)
+    slots, rows, plen, layers = 40, 4, 512, 2
+    cfg = transformer.TransformerConfig(
+        vocab_size=50257, max_seq_len=1024, d_model=1280, n_heads=20,
+        n_layers=layers, d_ff=5120, dtype=jnp.bfloat16, decode=True,
+        scan_layers=False)
+    model = transformer.TransformerLM(cfg)
+    init = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((slots, 1), jnp.int32),
+        positions=jnp.zeros((slots, 1), jnp.int32)))
+    params, cache = (
+        jax.tree_util.tree_map(lambda a: S(a.shape, a.dtype), init[k])
+        for k in ("params", "cache"))
+    text = jax.jit(
+        E._prefill_inject_impl, static_argnames=("model",),
+        donate_argnums=(2,)).lower(
+            model, params, cache, S((rows, plen), jnp.int32),
+            S((rows,), jnp.int32), S((rows,), jnp.int32),
+            S((rows,), jnp.bool_), S((rows, 2), jnp.uint32),
+            S((rows,), jnp.float32), S((rows,), jnp.int32),
+            S((rows,), jnp.int32), None).compile().as_text()
+    assert "f32[4,20,512,1024]" not in text
+    if min_len == 256:
+        assert text.count("flash_attention_fwd") >= layers
+        assert "f32[4,20,512,512]" not in text
+    else:
+        assert "flash_attention_fwd" not in text
+        assert "f32[4,20,512,512]" in text
+
+
 def test_fsdp4_step_runs_the_kernel_on_each_chips_rows(topo, one_chip,
                                                        monkeypatch):
     """An FSDP-over-4 train step on the described ``v5e:2x2`` at GPT-2 XL's

@@ -12,9 +12,10 @@ profile too, and ``--trace 0 --arm-trainer`` against ``--trace 0`` is what
 the trainer's spans cost a step (``--trace 0 --arm-serve`` against
 ``--trace 0`` is what a serve cell's armed ``Telemetry`` costs: the serve
 kinds arm one only in a traced run, so this hands them a slice that
-profiles nothing); the armed run also prints what the step's
-attention seats decided while it was traced (``obs/seats.py``: how many took
-the blockwise kernel, how many the dense path and why). ``--census``
+profiles nothing); an armed run also prints what the train step's or the
+serve engine's prefill program's attention seats decided while it was
+traced (``obs/seats.py``: how many took the blockwise kernel, how many the
+dense path and why). ``--census``
 compiles a train cell's step once more after set-up and prints its
 collectives (``obs/census.py``: kind, result type, bytes, inside the layer
 loop or not, carrying the global batch or parameter-shaped). Chip only (``--rehearse`` walks it on the CPU)::
@@ -127,11 +128,15 @@ def main(argv=None) -> int:
     if args.rehearse:
         argv.append("--rehearse")
     rc = run.main(argv)
-    if tel is not None:
-        for span in tel.spans.spans("trainer.train_step"):
-            if span.args:   # the call that traced the step
-                sys.stderr.write(
-                    f"attention seats as traced: {span.args}\n")
+    from ray_lightning_tpu.obs import last_telemetry
+    armed = last_telemetry()    # --arm-trainer's, or a serve kind's own
+    for name in ("trainer.train_step", "engine.prefill.call"):
+        for span in (armed.spans.spans(name) if armed is not None else ()):
+            if "attn_kernel" in span.args:  # the call that traced it
+                seat = {k: v for k, v in span.args.items()
+                        if k.startswith("attn_")}
+                sys.stderr.write(f"attention seats as traced ({name}): "
+                                 f"{seat}\n")
     if rc == 0 and args.trace and args.out:
         import trace_report  # tools/ is sys.path[0] when run as a script
         sys.stderr.write(trace_report.format_profile_report(
