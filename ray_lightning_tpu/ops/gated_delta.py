@@ -57,6 +57,27 @@ def hold(state, dtype):
     return lax.reduce_precision(state, info.nexp, info.nmant)
 
 
+def to_blocks(x, block: int):
+    """``(B, T, H, ...)`` -> ``(n, B, H, block, ...)`` float32: the
+    positions cut into ``n`` blocks (the last one padded with zeros), the
+    blocks first and each head's positions beside its features — the
+    layout the chunkwise forms scan over (here and ``ops/ssd.py``)."""
+    B, T = x.shape[:2]
+    n = -(-T // block)
+    x = jnp.pad(x.astype(F32), ((0, 0), (0, n * block - T)) + ((0, 0),) * (
+        x.ndim - 2))
+    x = x.reshape((B, n, block) + x.shape[2:])
+    return jnp.moveaxis(jnp.moveaxis(x, 3, 1), 2, 0)
+
+
+def from_blocks(o, T: int):
+    """``(n, B, H, block, d)`` -> ``(B, T, H, d)``: :func:`to_blocks`
+    undone, the pad tail cut."""
+    n, B, H, block, d = o.shape
+    o = jnp.moveaxis(jnp.moveaxis(o, 0, 2), 1, 3)   # (B, n, block, H, d)
+    return o.reshape(B, n * block, H, d)[:, :T]
+
+
 def unit_lower_inverse(a):
     """``(I + a)^-1`` for strictly lower-triangular ``a (..., n, n)``,
     ``n`` a power of two: the inverses of the diagonal blocks of size
@@ -108,17 +129,10 @@ def gated_delta_chunk(q, k, v, log_alpha, beta, state, lengths,
     mean nothing). ``T`` need not be a multiple of :data:`BLOCK`.
     ``state_dtype`` is the precision the state is held in between
     blocks. Returns ``(o (B, T, H, d_v) float32, state')``."""
-    B, T, H = q.shape[:3]
+    T = q.shape[1]
     dv = v.shape[-1]
-    n = -(-T // BLOCK)
-    pad = n * BLOCK - T
     valid = jnp.arange(T)[None, :] < lengths[:, None]
-
-    def blocks(x):          # (B, T, H, ...) -> (n, B, H, BLOCK, ...)
-        x = jnp.pad(x.astype(F32), ((0, 0), (0, pad)) + ((0, 0),) * (
-            x.ndim - 2))
-        x = x.reshape((B, n, BLOCK) + x.shape[2:])
-        return jnp.moveaxis(jnp.moveaxis(x, 3, 1), 2, 0)
+    blocks = lambda x: to_blocks(x, BLOCK)              # noqa: E731
 
     q, k, v = blocks(q), blocks(k), blocks(v)
     beta = blocks(jnp.where(valid[..., None], beta, 0.0))  # (n, B, H, C)
@@ -152,5 +166,4 @@ def gated_delta_chunk(q, k, v, log_alpha, beta, state, lengths,
 
     state, o = lax.scan(body, state.astype(F32),
                         (u_v, w, attn, q_in, k_out, g_end))
-    o = jnp.moveaxis(jnp.moveaxis(o, 0, 2), 1, 3)       # (B, n, C, H, dv)
-    return o.reshape(B, n * BLOCK, H, dv)[:, :T], state
+    return from_blocks(o, T), state

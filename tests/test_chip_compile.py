@@ -564,3 +564,82 @@ def test_afmoe_engine_programs_compile_and_fit(one_chip, program):
     # 2 expert layers: 4.0 GB of bf16 weights + 0.15 GB of vocabulary +
     # 1.6 GB of cache; the cell's 5 layers compile to 11.87 + 0.39 GB
     assert total < 6.5e9, total
+
+
+# --------------------------------------------------------------------- #
+# Falcon-H1 at its published widths: the step and the dense-slot chunk
+# program of benchmark cell falcon-h1-34b-instruct.serve.chat48
+# --------------------------------------------------------------------- #
+@pytest.mark.parametrize("program", ["step", "chunk"])
+def test_falcon_h1_engine_programs_compile_and_fit(one_chip, program):
+    """Hidden 5120, 20 / 4 heads of 128, MLP 21504, a Mamba-2 mixer of 32
+    heads of 128 x 256 in 2 groups, the whole 261120-row vocabulary, 48
+    slots of 3072 positions — 2 of the cell's 6 layers, to keep the
+    compile short. The chunkwise SSD and its one-position step must lower
+    for a v5e and fit; the pool is donated whole; and **no program may
+    copy or transpose a leaf of the pool** — the float32 state, the conv
+    tail or the K/V, each layer holding all of them."""
+    from ray_lightning_tpu.models.falcon_h1 import FalconH1Config, FalconH1LM
+    from ray_lightning_tpu.serve import engine as E
+    S = _spec(one_chip)
+    slots, rows, piece, positions = 48, 2, 512, 3072
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(lambda a: S(a.shape, a.dtype), tree)
+
+    model = FalconH1LM(FalconH1Config(num_hidden_layers=2,
+                                      max_seq_len=positions, decode=True))
+    init = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((slots, 1), jnp.int32)))
+    params, cache = abstract(init["params"]), abstract(init["cache"])
+    assert cache["layer_0_ssm"]["ssm_state"].shape == (slots, 32, 128, 256)
+    assert cache["layer_0_attn"]["cached_key"].shape \
+        == (slots, 4, positions, 128)
+    if program == "step":
+        compiled = jax.jit(
+            E._engine_step_impl, static_argnames=("model", "steps"),
+            donate_argnums=(2,)).lower(
+                model, params, cache, S((slots, 1), jnp.int32),
+                S((slots, 1), jnp.int32), S((slots,), jnp.bool_),
+                S((slots,), jnp.int32), S((slots,), jnp.float32),
+                S((slots,), jnp.int32), S((slots,), jnp.int32),
+                S((slots, 2), jnp.uint32), S((slots,), jnp.int32), None,
+                steps=1).compile()
+    else:
+        compiled = jax.jit(
+            E._chunk_prefill_dense_impl, static_argnames=("model",),
+            donate_argnums=(2,)).lower(
+                model, params, cache, S((rows, piece), jnp.int32),
+                S((rows,), jnp.int32), S((rows,), jnp.int32),
+                S((rows,), jnp.int32), S((rows,), jnp.bool_),
+                S((rows, 2), jnp.uint32), S((rows,), jnp.float32),
+                S((rows,), jnp.int32), S((rows,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    # a pool leaf, in whatever order of its axes, is the result of no copy
+    # and no transpose
+    leaf_sizes = {slots * 32 * 128 * 256, slots * 3 * 5120,
+                  slots * 4 * positions * 128}
+    for line in text.splitlines():
+        head = line.split(" = ")[-1][:80]
+        if " copy(" not in line and " transpose(" not in line:
+            continue
+        dims = head[head.find("[") + 1:head.find("]")]
+        if dims.replace(",", "").isdigit():
+            assert int(np.prod([int(d) for d in dims.split(",")])) \
+                not in leaf_sizes, line[:200]
+    mem = compiled.memory_analysis()
+    pool = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+               for a in jax.tree_util.tree_leaves(cache))
+    assert pool == 2 * slots * (4 * (32 * 128 * 256 + 3 * 5120)
+                                + 2 * 4 * positions * 128 * 2)
+    assert mem.alias_size_in_bytes >= pool
+    # the step's temporaries are its logits and a layer's new state; the
+    # chunk's the two rows taken out of the pool and a block's products
+    assert mem.temp_size_in_bytes < 0.3e9
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    # 2 layers: 1.72 GB of bf16 layer weights + 5.35 GB of embedding and
+    # head + 1.01 GB of cache; the cell's 6 layers compile to 13.55 GB +
+    # 0.22-0.27 GB of temporaries (PERF.md section 6)
+    assert total < 8.5e9, total
