@@ -13,13 +13,16 @@ TPU-first choices baked in:
   the seat reads its own call — :func:`attention_seat` — and runs the
   pallas blockwise kernel for causal self-attention with no mask and no
   dropout from ``FLASH_MIN_LEN`` positions up on a TPU, per device under a
-  mesh, and the dense XLA path for everything else; in decode mode that
-  is one case only, a prompt block written at position 0 of a cache its
-  own program built empty — :func:`fresh_cache`, opened by
-  ``generate._prefill_impl`` — which attends its own keys and not the
-  whole cache under a mask; every other cached call, a one-token step, a
-  per-row ``kv_positions`` block, a block at an offset, the page-native
-  path, stays dense), 'flash' (the
+  mesh, and the dense XLA path for everything else; in decode mode the
+  blockwise kernel takes one case only, a prompt block written at
+  position 0 of a cache its own program built empty — :func:`fresh_cache`,
+  opened by ``generate._prefill_impl`` — which attends its own keys and
+  not the whole cache under a mask; a per-row one-token step
+  (``kv_positions``) goes to :func:`decode_seat`, which picks the per-row
+  decode kernel of ``ops/pallas_decode.py`` — each row reads its K/V only
+  to its own length — or the dense read; every other cached call, a
+  shared-index step, a per-row block, a block at an offset, the
+  page-native path, stays dense), 'flash' (the
   blockwise kernel asked for by name), 'ring' (sequence-parallel ring
   attention over the ``sp`` mesh axis), 'ulysses' (all-to-all head-sharded
   sequence parallelism over the same axis).
@@ -45,6 +48,8 @@ from ray_lightning_tpu.models.quant import (kv_dequantize, kv_quantize,
 from ray_lightning_tpu.obs import seats
 from ray_lightning_tpu.ops.attention import dot_product_attention
 from ray_lightning_tpu.ops.cache_write import write_rows
+from ray_lightning_tpu.ops.pallas_decode import (decode_attention,
+                                                 decode_block)
 from ray_lightning_tpu.ops.pallas_flash import (head_lanes,
                                                 pallas_flash_attention)
 from ray_lightning_tpu.parallel import sharding as shardlib
@@ -383,7 +388,9 @@ def _projection(cfg: TransformerConfig, *, features, in_features: int,
 #: at all three (PERF.md section 6, PR 33); nothing shorter was timed.
 FLASH_MIN_LEN = 256
 
-_FRESH = threading.local()
+#: trace-time facts the calls being traced read (fresh_cache,
+#: quantized_cache)
+_TRACE = threading.local()
 
 
 @contextlib.contextmanager
@@ -396,12 +403,26 @@ def fresh_cache():
     the block are empty — and the seat may hand it to the kernel. A fact
     of the trace, not of the configuration: nothing outside the block
     changes."""
-    was = getattr(_FRESH, "open", False)
-    _FRESH.open = True
+    was = getattr(_TRACE, "fresh", False)
+    _TRACE.fresh = True
     try:
         yield
     finally:
-        _FRESH.open = was
+        _TRACE.fresh = was
+
+
+@contextlib.contextmanager
+def quantized_cache(on: bool = True):
+    """Trace the calls inside as reading a decode cache whose storage is
+    quantized when ``on`` (the engine's ``kv_dtype="int8"`` storage,
+    dequantized at the program's entry): :func:`decode_seat` keeps such a
+    cache on the dense read. An enclosing ``on`` holds inside."""
+    was = getattr(_TRACE, "quantized", False)
+    _TRACE.quantized = was or on
+    try:
+        yield
+    finally:
+        _TRACE.quantized = was
 
 
 def attention_seat(q_shape, k_shape, *, decode: bool, causal: bool, mask,
@@ -451,6 +472,39 @@ def attention_seat(q_shape, k_shape, *, decode: bool, causal: bool, mask,
     return True, "sharded"
 
 
+def decode_seat(q_shape, cache_len: int, *, mask, quantized: bool,
+                backend: Optional[str] = None):
+    """Which program reads the cache for a per-row decode call (the
+    engine's step and ``generate()``'s ragged scan: ``kv_positions`` given,
+    the cache built), from the call itself: ``(kernel, how)``.
+
+    ``(True, "local")`` is ``ops/pallas_decode.py``'s kernel, which reads
+    each row's K/V only as far as the row's own length; ``(False,
+    reason)`` is the dense read of the whole cache under the per-row mask,
+    and ``reason`` names the first test the call fails: ``verify`` (more
+    than one query a row: a speculative verify block, a chunk), ``mask``
+    (a caller's mask), ``quantized`` (:func:`quantized_cache`),
+    ``head_dim`` (heads the lane layout does not tile), ``length`` (a slot
+    of no whole number of the kernel's blocks), ``backend``, ``mesh``.
+    """
+    B, T, H, D = q_shape
+    if T != 1:
+        return False, "verify"
+    if mask is not None:
+        return False, "mask"
+    if quantized:
+        return False, "quantized"
+    if head_lanes(D) is None or (H * D) % 128:
+        return False, "head_dim"
+    if decode_block(cache_len) is None:
+        return False, "length"
+    if (backend or jax.default_backend()) != "tpu":
+        return False, "backend"
+    if shardlib.ambient_mesh() is not None:
+        return False, "mesh"
+    return True, "local"
+
+
 def _mesh_cut(mesh, q_shape):
     """The axes a nested kernel call splits batch rows and heads over."""
     rows = shardlib.data_axis_names(mesh) \
@@ -482,6 +536,20 @@ def _fresh_block_kernel(q, k, v):
     lowered once, not once a layer (36 Mosaic lowerings put seconds on
     every start of an engine)."""
     return pallas_flash_attention(q, k, v, causal=True)
+
+
+@jax.jit
+def _decode_rows_kernel(q, k, v, lengths):
+    """The per-row decode kernel behind one ``jit``, as
+    :func:`_fresh_block_kernel`: the unrolled layers lower it once."""
+    return decode_attention(q, k, v, lengths)
+
+
+def _decode_rows(kv_positions, q, k, v, **_):
+    """:func:`_decode_rows_kernel` in the seat of an attention function:
+    each row attends its cache up to its just-written position."""
+    return _decode_rows_kernel(q, k, v,
+                               kv_positions[:, 0].astype(jnp.int32) + 1)
 
 
 def _attention_fn(cfg: TransformerConfig, fresh: bool = False):
@@ -550,7 +618,16 @@ class MultiHeadAttention(nn.Module):
         # the first block of a cache this program built empty attends
         # itself (fresh_cache): a trace-time fact, never a traced index
         fresh = cfg.decode and kv_positions is None and mask is None \
-            and getattr(_FRESH, "open", False)
+            and getattr(_TRACE, "fresh", False)
+        # a per-row call over a cache already built: the decode seat reads
+        # it whole under the mask, or each row only to its length
+        rows = None
+        if cfg.decode and kv_positions is not None \
+                and cfg.attention_impl == "dot" \
+                and self.has_variable("cache", "cached_key"):
+            rows = decode_seat(q.shape, cfg.max_seq_len, mask=mask,
+                               quantized=getattr(_TRACE, "quantized", False))
+            seats.note(*rows)
         if cfg.decode:
             k, v, cache_mask = self._decode_cache(k, v, kv_positions, fresh)
             if cache_mask is not None:
@@ -564,6 +641,9 @@ class MultiHeadAttention(nn.Module):
         if cfg.dropout > 0.0 and not deterministic:
             drop_rng = self.make_rng("dropout")
         attn = _attention_fn(cfg, fresh)
+        if rows is not None:
+            attn = (functools.partial(_decode_rows, kv_positions)
+                    if rows[0] else dot_product_attention)
         kw = {}
         if cfg.attention_softmax_dtype != jnp.float32 and \
                 cfg.attention_impl in ("dot", "ulysses"):

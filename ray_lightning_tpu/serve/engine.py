@@ -115,11 +115,12 @@ from ray_lightning_tpu.models.quant import (DEFAULT_GROUP_SIZE,
                                             check_weight_dtype,
                                             materialize_for_program,
                                             param_bytes, quantize_params)
-from ray_lightning_tpu.models.transformer import latch_eos
+from ray_lightning_tpu.models.transformer import latch_eos, quantized_cache
 from ray_lightning_tpu.obs import seats
 from ray_lightning_tpu.obs.spans import NULL_SPAN
 from ray_lightning_tpu.ops.cache_write import (inject_blocks, inject_rows,
                                                 take_rows)
+from ray_lightning_tpu.ops.pallas_decode import blocks_read, decode_block
 from ray_lightning_tpu.reliability import faults
 from ray_lightning_tpu.serve.adapters import (AdapterRegistry,
                                               UnknownAdapter)
@@ -302,9 +303,10 @@ def _engine_step_impl(model, params, cache, cur, pos, active, remaining,
         return ((cache, cur, pos, active, remaining, stepno),
                 (emitted, finished))
 
-    (cache, cur, pos, active, remaining, stepno), (emitted, finished) = \
-        jax.lax.scan(body, (cache, cur, pos, active, remaining, stepno),
-                     None, length=steps)
+    with quantized_cache(isinstance(storage, tuple)):
+        (cache, cur, pos, active, remaining, stepno), (emitted, finished) \
+            = jax.lax.scan(body, (cache, cur, pos, active, remaining,
+                                  stepno), None, length=steps)
     cache = dense_storage_commit(model, storage, cache)
     return (cache, cur, pos, active, remaining, stepno,
             pack_report(cur, pos, active, remaining, stepno, emitted,
@@ -387,10 +389,11 @@ def _paged_step_impl(model, params, arena, page_table, cur, pos, active,
     """
     view = _gather_pages(model, arena, page_table)
     write_pt = jnp.where(active[:, None], page_table, -1)
-    (view, cur, pos, active, remaining, stepno, report) = \
-        _engine_step_impl(model, params, view, cur, pos, active,
-                          remaining, temp, top_k, eos, keys, stepno,
-                          adapter_ids, steps=steps)
+    with quantized_cache(isinstance(arena, tuple)):
+        (view, cur, pos, active, remaining, stepno, report) = \
+            _engine_step_impl(model, params, view, cur, pos, active,
+                              remaining, temp, top_k, eos, keys, stepno,
+                              adapter_ids, steps=steps)
     arena = _scatter_pages(model, arena, view, write_pt)
     return (arena, cur, pos, active, remaining, stepno, report)
 
@@ -2107,9 +2110,12 @@ class ServeEngine:
         with (tel.span("engine.step.build", **self._span_extra)
               if tel is not None else NULL_SPAN):
             fn, args = self._step_call()
+        # the call that traces the program counts its attention seats
+        # onto the span (obs/seats.py), as engine.prefill.call does
         with (tel.span("engine.step.call", **self._count_step_rows(tel),
                        **self._span_extra)
-              if tel is not None else NULL_SPAN) as call_args:
+              if tel is not None else NULL_SPAN) as call_args, \
+                seats.tally(call_args):
             (store, cur, pos, active, remaining, stepno,
              report) = fn(*args, steps=self.steps_per_dispatch)
             # the one transfer of this dispatch starts behind its
@@ -2163,10 +2169,23 @@ class ServeEngine:
         m.counter("serve_step_slots_total",
                   help="num_slots summed over step dispatches (the rows "
                   "the step program runs over)").inc(self.num_slots)
+        contexts = self._pos[self._active, 0].astype(np.int64) + 1
         return {"active": active, "slots": self.num_slots,
-                **self._live_cache_bytes(
-                    self._pos[self._active, 0].astype(np.int64) + 1),
+                **self._live_cache_bytes(contexts),
+                **self._kv_blocks(contexts),
                 **self._count_topk_wide(tel, self._top_k)}
+
+    def _kv_blocks(self, contexts) -> Dict[str, int]:
+        """Armed only: the key blocks ``ops/pallas_decode.py`` copies for
+        rows holding ``contexts`` positions, against the blocks of the
+        whole slot pool (what the masked dense read streams). From the
+        synced frontier; empty for a slot of no whole number of blocks."""
+        L = getattr(self.model.cfg, "max_seq_len", 0)
+        block = decode_block(L) if L else None
+        if block is None:
+            return {}
+        return {"kv_blocks_read": int(blocks_read(contexts, block).sum()),
+                "kv_blocks_slot": self.num_slots * (L // block)}
 
     def _count_topk_wide(self, tel, top_k) -> Dict[str, int]:
         """Armed only: whether the ``top_k`` operand of this dispatch

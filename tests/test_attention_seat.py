@@ -20,8 +20,10 @@ from ray_lightning_tpu.models import transformer
 from ray_lightning_tpu.models.gpt import GPTModule
 from ray_lightning_tpu.models.transformer import (TransformerConfig,
                                                   TransformerLM,
-                                                  attention_seat)
+                                                  attention_seat,
+                                                  decode_seat)
 from ray_lightning_tpu.obs import Telemetry, seats
+from ray_lightning_tpu.ops.pallas_decode import decode_attention
 from ray_lightning_tpu.ops.pallas_flash import pallas_flash_attention
 from ray_lightning_tpu.parallel import sharding as shardlib
 
@@ -235,16 +237,20 @@ def test_dropout_in_training_keeps_the_dense_path(monkeypatch):
 # --------------------------------------------------------------------- #
 @pytest.fixture
 def seat_on_tpu(monkeypatch):
-    """The seat alone asked as a TPU would ask it from 128 positions, the
-    kernel interpreted; the rest of the program (cache writes, donation)
-    stays the CPU's. Compiled programs traced either way are dropped on
-    both sides of the test."""
+    """The seats alone asked as a TPU would ask them (the blockwise one from
+    128 positions), the kernels interpreted; the rest of the program
+    (cache writes, donation) stays the CPU's. Compiled programs traced
+    either way are dropped on both sides of the test."""
     monkeypatch.setattr(transformer, "attention_seat",
                         functools.partial(attention_seat, backend="tpu"))
+    monkeypatch.setattr(transformer, "decode_seat",
+                        functools.partial(decode_seat, backend="tpu"))
     monkeypatch.setattr(transformer, "FLASH_MIN_LEN", 128)
     monkeypatch.setattr(
         transformer, "pallas_flash_attention",
         functools.partial(pallas_flash_attention, interpret=True))
+    monkeypatch.setattr(transformer, "decode_attention",
+                        functools.partial(decode_attention, interpret=True))
     jax.clear_caches()
     yield
     jax.clear_caches()
@@ -310,7 +316,10 @@ def _continuation(kind, model, params, T=256):
                                   "block_after_prefill", "chunk_at_offset"])
 def test_every_continuation_keeps_the_dense_path(seat_on_tpu, kind):
     """Only the prefill's first block is fresh: a call that attends what an
-    earlier call cached is refused with ``decode``, whatever its length."""
+    earlier call cached is refused by the blockwise seat with ``decode``,
+    whatever its length. The per-row calls go to the decode seat instead:
+    a one-token step takes the per-row decode kernel, a block stays dense
+    with ``verify``."""
     from ray_lightning_tpu.models.generate import prefill
     model = TransformerLM(_decode_nano())
     params = _params(model.cfg)
@@ -322,8 +331,12 @@ def test_every_continuation_keeps_the_dense_path(seat_on_tpu, kind):
     took = {}
     with seats.tally(took):
         jax.eval_shape(lambda p: _continuation(kind, model, p), params)
+    if kind == "per_row_step":
+        assert took == {"attn_kernel": 2, "attn_dense": 0}
+        return
     assert took["attn_kernel"] == 0 and took["attn_dense"] == 2
-    assert took["attn_dense_reason"] == "decode"
+    assert took["attn_dense_reason"] == (
+        "verify" if kind == "per_row_block" else "decode")
 
 
 @pytest.mark.parametrize("seat", ["kernel", "dense"])
@@ -398,3 +411,110 @@ def test_the_prefill_span_counts_the_seats(seat_on_tpu, kind):
     first, second = tel.spans.spans("engine.prefill.call")
     assert first.args["attn_kernel"] == 2 and first.args["attn_dense"] == 0
     assert "attn_kernel" not in second.args
+
+
+# --------------------------------------------------------------------- #
+# decode mode, per-row one-token steps: the decode seat (transformer.
+# decode_seat) and the per-row kernel of ops/pallas_decode.py
+# --------------------------------------------------------------------- #
+ROW = (40, 1, 20, 64)           # gpt2-large.serve.closed40's step
+
+
+@pytest.mark.parametrize("case,kwargs,want", [
+    ("kernel", {}, (True, "local")),
+    ("verify", dict(q=(40, 5, 20, 64)), (False, "verify")),
+    ("mask", dict(mask=MASK[..., :1, :]), (False, "mask")),
+    ("quantized", dict(quantized=True), (False, "quantized")),
+    ("head_lanes_not_a_row", dict(q=(40, 1, 3, 64)), (False, "head_dim")),
+    ("odd_head_dim", dict(q=(40, 1, 16, 80)), (False, "head_dim")),
+    ("head_dim_128", dict(q=(16, 1, 30, 128), cache_len=4608),
+     (True, "local")),
+    ("ragged_slot", dict(cache_len=1000), (False, "length")),
+    ("cpu_backend", dict(backend="cpu"), (False, "backend")),
+    ("default_backend_here", dict(backend=None), (False, "backend")),
+    ("one_device_mesh", dict(mesh=("dp", 1)), (False, "mesh")),
+    ("fsdp4", dict(mesh=("fsdp", 4)), (False, "mesh")),
+], ids=lambda v: v if isinstance(v, str) else "")
+def test_the_decode_seat_decides_from_its_call(case, kwargs, want):
+    call = dict(q=ROW, cache_len=1024, mask=None, quantized=False,
+                backend="tpu")
+    call.update(kwargs)
+    q, cache_len, mesh = call.pop("q"), call.pop("cache_len"), \
+        call.pop("mesh", None)
+
+    def ask():
+        return decode_seat(q, cache_len, **call)
+
+    if mesh is not None:
+        ask = shardlib.under_mesh(_mesh(**dict(zip(mesh[::2], mesh[1::2]))),
+                                  ask)
+    assert ask() == want
+
+
+@pytest.mark.parametrize("seat", ["kernel", "dense"])
+@pytest.mark.parametrize("scan", [False, True], ids=["unrolled", "scanned"])
+def test_decode_step_kernel_against_the_masked_read(request, seat, scan):
+    """A nano GPT's ``decode_step`` over ragged rows — one writing position
+    0 of its slot, one at the end of a block, one past it, one deep in the
+    slot — through the per-row kernel and through the dense masked read:
+    the logits and the written cache (a layer's K/V follow the layers
+    below it) agree to the train test's tolerance."""
+    from ray_lightning_tpu.models.generate import decode_step, prefill
+    model = TransformerLM(_decode_nano(256, scan))
+    params = _params(model.cfg)
+    lengths = jnp.array([1, 127, 128, 200], jnp.int32)
+    prompts = jax.random.randint(jax.random.PRNGKey(4), (4, 200), 0, 97)
+    cache, _ = prefill(model, params, prompts, lengths)
+    tokens = jax.random.randint(jax.random.PRNGKey(5), (4, 1), 0, 97)
+    at = jnp.array([[0], [127], [128], [200]], jnp.int32)
+    want, want_cache = jax.jit(
+        lambda p, c: decode_step(model, p, c, tokens, at))(params, cache)
+    if seat == "kernel":
+        request.getfixturevalue("seat_on_tpu")
+    took = {}
+    with seats.tally(took):
+        got, got_cache = jax.jit(
+            lambda p, c: decode_step(model, p, c, tokens, at))(params, cache)
+    if seat == "kernel":
+        assert took["attn_kernel"] >= 1 and took["attn_dense"] == 0
+    else:
+        assert took["attn_kernel"] == 0
+        assert took["attn_dense_reason"] == "backend"
+    for g, w in zip(jax.tree_util.tree_leaves((got, got_cache)),
+                    jax.tree_util.tree_leaves((want, want_cache))):
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        assert np.abs(g - w).max() <= 3e-2 * (np.abs(w).max() + 1e-6)
+
+
+@pytest.mark.parametrize("kind,took", [
+    ("dense", {"attn_kernel": 2, "attn_dense": 0}),
+    ("paged", {"attn_kernel": 2, "attn_dense": 0}),
+    ("int8", {"attn_kernel": 0, "attn_dense": 2,
+              "attn_dense_reason": "quantized"}),
+])
+def test_the_step_span_counts_the_decode_seats(seat_on_tpu, kind, took):
+    """The engine's step program traced by the armed ``engine.step.call``:
+    a dense pool and a paged pool's gathered view take the per-row kernel,
+    int8 storage keeps the dense read with ``quantized``; a later step
+    traces nothing and says nothing. Every step names the key blocks its
+    live rows hold against the pool's."""
+    from ray_lightning_tpu.serve import Request, ServeEngine
+    model = TransformerLM(_decode_nano())
+    tel = Telemetry()
+    extra = {"paged": {"page_size": 64}, "int8": {"kv_dtype": "int8"},
+             "dense": {}}[kind]
+    engine = ServeEngine(model, _params(model.cfg), num_slots=2,
+                         prefill_len=256, prefill_batch=2, telemetry=tel,
+                         **extra)
+    engine.prefill([Request(id=i, prompt=list(range(1, 128 + i)),
+                            max_new_tokens=4) for i in (0, 1)])
+    engine.step()
+    engine.step()
+    first, second = tel.spans.spans("engine.step.call")[:2]
+    assert {k: v for k, v in first.args.items()
+            if k.startswith("attn_")} == took
+    assert "attn_kernel" not in second.args
+    # contexts 128 and 129 after the prefill: one block and two, of 2 x 4
+    assert (first.args["kv_blocks_read"],
+            first.args["kv_blocks_slot"]) == (3, 8)
+    assert second.args["kv_blocks_read"] == 4

@@ -234,6 +234,75 @@ def test_paged_attention_compiles(one_chip, kv, T):
 
 
 # --------------------------------------------------------------------- #
+# per-row decode attention (serve path): ops/pallas_decode.py
+# --------------------------------------------------------------------- #
+#: gpt2-large.serve.closed40's step: 40 slots of 1024, 20 heads of 64
+_ROWS = (40, 1024, 20, 64)
+
+
+def test_decode_attention_compiles_at_the_closed40_shapes(one_chip):
+    """The kernel alone at closed40's step (one query a row): the K/V
+    views it takes are bitcasts of the chip's position-minor cache, so
+    nothing of the cache's size is copied around it."""
+    from ray_lightning_tpu.ops.pallas_decode import decode_attention
+    S = _spec(one_chip)
+    Bc, L, Hc, Dc = _ROWS
+    text = _compiled_text(
+        lambda *a: decode_attention(*a, interpret=False),
+        S((Bc, 1, Hc, Dc), jnp.bfloat16), S((Bc, L, Hc, Dc), jnp.bfloat16),
+        S((Bc, L, Hc, Dc), jnp.bfloat16), S((Bc,), jnp.int32))
+    assert text.count("tpu_custom_call") == 1
+    assert not [line for line in text.splitlines()
+                if f"[{Bc},{L}" in line.split(" = ")[-1][:40]
+                and (" copy(" in line or " transpose(" in line)]
+
+
+def test_closed40_step_reads_each_row_to_its_length(one_chip, monkeypatch):
+    """``gpt2-large.serve.closed40``'s step program (GPT-2 large widths,
+    40 slots of 1024; 2 of its 36 layers and a 128th of its vocabulary,
+    to keep the compile short)
+    through the decode seat: a decode kernel a layer beside the cache
+    write, no ``f32[40,1024,20]`` scores of the masked read (every head
+    over every position), and no leaf of the pool copied or
+    transposed."""
+    from ray_lightning_tpu.models import transformer
+    from ray_lightning_tpu.serve import engine as E
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.clear_caches()          # the seat decides while the program traces
+    S = _spec(one_chip)
+    slots, L, layers = 40, 1024, 2
+    cfg = transformer.TransformerConfig(
+        vocab_size=50257 // 128, max_seq_len=L, d_model=1280, n_heads=20,
+        n_layers=layers, d_ff=5120, dtype=jnp.bfloat16, decode=True,
+        scan_layers=False)
+    model = transformer.TransformerLM(cfg)
+    init = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((slots, 1), jnp.int32),
+        positions=jnp.zeros((slots, 1), jnp.int32)))
+    params, cache = (
+        jax.tree_util.tree_map(lambda a: S(a.shape, a.dtype), init[k])
+        for k in ("params", "cache"))
+    rows = S((slots,), jnp.int32)
+    text = jax.jit(
+        E._engine_step_impl, static_argnames=("model", "steps"),
+        donate_argnums=(2,)).lower(
+            model, params, cache, S((slots, 1), jnp.int32),
+            S((slots, 1), jnp.int32), S((slots,), jnp.bool_), rows,
+            S((slots,), jnp.float32), rows, rows,
+            S((slots, 2), jnp.uint32), rows, None, steps=1
+        ).compile().as_text()
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line]
+    assert sum("decode_attention" in k for k in kernels) == layers
+    assert sum("cache_write_rows" in k for k in kernels) == layers
+    assert "f32[40,1024,20]" not in text and "f32[40,20,1024]" not in text
+    assert not [line for line in text.splitlines()
+                if ("[40,1024,20,64]" in line.split(" = ")[-1][:40]
+                    or "[40,20,64,1024]" in line.split(" = ")[-1][:40])
+                and (" copy(" in line or " transpose(" in line)]
+
+
+# --------------------------------------------------------------------- #
 # quantized matmul (serve path): models/pallas_matmul.py
 # --------------------------------------------------------------------- #
 def _qtensor(S, shape, bits, group_size=64):

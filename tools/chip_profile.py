@@ -13,9 +13,10 @@ the trainer's spans cost a step (``--trace 0 --arm-serve`` against
 ``--trace 0`` is what a serve cell's armed ``Telemetry`` costs: the serve
 kinds arm one only in a traced run, so this hands them a slice that
 profiles nothing); an armed run also prints what the train step's or the
-serve engine's prefill program's attention seats decided while it was
-traced (``obs/seats.py``: how many took the blockwise kernel, how many the
-dense path and why). ``--census``
+serve engine's prefill and step programs' attention seats decided while
+they were traced (``obs/seats.py``: how many took a kernel, how many the
+dense path and why), and the key blocks the step's per-row decode kernel
+read against the slot pool's (``engine.step.call``). ``--census``
 compiles a train cell's step once more after set-up and prints its
 collectives (``obs/census.py``: kind, result type, bytes, inside the layer
 loop or not, carrying the global batch or parameter-shaped). Chip only (``--rehearse`` walks it on the CPU)::
@@ -130,13 +131,23 @@ def main(argv=None) -> int:
     rc = run.main(argv)
     from ray_lightning_tpu.obs import last_telemetry
     armed = last_telemetry()    # --arm-trainer's, or a serve kind's own
-    for name in ("trainer.train_step", "engine.prefill.call"):
+    for name in ("trainer.train_step", "engine.prefill.call",
+                 "engine.step.call"):
         for span in (armed.spans.spans(name) if armed is not None else ()):
             if "attn_kernel" in span.args:  # the call that traced it
                 seat = {k: v for k, v in span.args.items()
                         if k.startswith("attn_")}
                 sys.stderr.write(f"attention seats as traced ({name}): "
                                  f"{seat}\n")
+    steps = [s.args for s in (armed.spans.spans("engine.step.call")
+                              if armed is not None else ())
+             if "kv_blocks_slot" in s.args]
+    if steps:
+        read = sum(a["kv_blocks_read"] for a in steps)
+        slot = sum(a["kv_blocks_slot"] for a in steps)
+        sys.stderr.write(f"decode key blocks read / slot blocks "
+                         f"(engine.step.call, {len(steps)} dispatches): "
+                         f"{read} / {slot} = {read / slot:.3f}\n")
     if rc == 0 and args.trace and args.out:
         import trace_report  # tools/ is sys.path[0] when run as a script
         sys.stderr.write(trace_report.format_profile_report(
