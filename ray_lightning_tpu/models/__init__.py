@@ -27,6 +27,8 @@ from ray_lightning_tpu.models.olmo_hybrid import (OlmoHybridConfig,
                                                   OlmoHybridLM)
 from ray_lightning_tpu.models.afmoe import AfmoeConfig, AfmoeLM
 from ray_lightning_tpu.models.falcon_h1 import FalconH1Config, FalconH1LM
+from ray_lightning_tpu.models.deepseek_v2 import (DeepseekV2Config,
+                                                  DeepseekV2LM)
 from ray_lightning_tpu.models.generate import (decode_step, generate,
                                                generate_full_scan, prefill,
                                                sample_logits,
@@ -46,6 +48,7 @@ __all__ = [
     "Seq2SeqModule", "Seq2SeqTransformer",
     "SambaYConfig", "SambaYLM", "OlmoHybridConfig", "OlmoHybridLM",
     "AfmoeConfig", "AfmoeLM", "FalconH1Config", "FalconH1LM",
+    "DeepseekV2Config", "DeepseekV2LM",
     "LoraConfig", "adapter_bytes", "extract_adapter", "install_adapter",
     "install_lora_bank", "zero_adapter",
 ]

@@ -13,11 +13,18 @@ nothing from this function (its caller adds the shared expert). No token
 is dropped, no capacity exists, and nothing stands in for the absent
 chips or their exchange.
 
-The router is the sigmoid one (DeepSeek-V3's, which ``afmoe`` shares):
-``s = sigmoid(logits)``; the ``k`` experts with the largest ``s + b``
-(``b``: a per-expert bias that balances the load and enters the *choice*
-only); weights ``s[e_k]`` without ``b``, divided by their sum
-(``route_norm``) and multiplied by ``route_scale``.
+Two routers (:func:`route`). The sigmoid one (DeepSeek-V3's, which
+``afmoe`` shares): ``s = sigmoid(logits)``; the ``k`` experts with the
+largest ``s + b`` (``b``: a per-expert bias that balances the load and
+enters the *choice* only); weights ``s[e_k]`` without ``b``, divided by
+their sum (``route_norm``) and multiplied by ``route_scale``. The softmax
+one (DeepSeek-V2's ``group_limited_greedy``, ``models/deepseek_v2.py``):
+``s = softmax(logits)`` over every expert; the experts are ``n_group``
+groups of consecutive numbers, each scored by its best ``s``; the
+``topk_group`` best groups are kept and the ``k`` best experts among them
+taken; weights ``s[e_k]`` times ``routed_scaling_factor`` where they are
+not normalised. A tie goes to the lower number, of a group and of an
+expert (``jax.lax.top_k``'s order).
 
 The product: the ``N k`` assignments are ordered by local expert (those
 that live elsewhere, and the pad tokens of a ragged piece, sort behind
@@ -35,19 +42,46 @@ import jax.numpy as jnp
 
 
 def route(logits, bias, top_k: int, normalise: bool = True,
-          scale: float = 1.0) -> Tuple[jax.Array, jax.Array]:
+          scale: float = 1.0, scoring: str = "sigmoid", n_group: int = 1,
+          topk_group: int = 1) -> Tuple[jax.Array, jax.Array]:
     """Router logits ``(N, E)`` float32 over **all** experts and the
-    choice bias ``(E,)`` -> the chosen experts ``(N, k)`` int32 and
-    their weights ``(N, k)`` float32: sigmoid scores, the top ``k`` of
-    ``s + b``, the weights ``s`` alone, divided by their sum + 1e-20
-    (``route_norm``), times ``route_scale``."""
-    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
-    _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    choice bias ``(E,)`` (``None``: none) -> the chosen experts ``(N,
+    k)`` int32 and their weights ``(N, k)`` float32: ``scoring`` scores
+    (``"sigmoid"`` or ``"softmax"``), the top ``k`` of ``s + b`` — among
+    the ``topk_group`` best of ``n_group`` groups where ``n_group > 1``
+    (:func:`group_limit`) —, the weights ``s`` alone, divided by their
+    sum + 1e-20 (``route_norm``), times ``route_scale``."""
+    logits = logits.astype(jnp.float32)
+    if scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    elif scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"unknown scoring {scoring!r}")
+    choice = scores if bias is None else scores + bias.astype(jnp.float32)
+    if n_group > 1:
+        choice = group_limit(choice, n_group, topk_group)
+    _, experts = jax.lax.top_k(choice, top_k)
     weights = jnp.take_along_axis(scores, experts, axis=-1)
     if normalise:
         weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
                              + 1e-20)
     return experts.astype(jnp.int32), weights * scale
+
+
+def group_limit(choice, n_group: int, topk_group: int):
+    """``choice (N, E)`` with every expert outside the ``topk_group``
+    best of ``n_group`` groups of ``E / n_group`` consecutive experts set
+    to ``-inf``; a group is scored by its best expert (DeepSeek-V2's
+    ``group_limited_greedy``)."""
+    N, E = choice.shape
+    if E % n_group:
+        raise ValueError(f"{E} experts do not make {n_group} groups")
+    groups = choice.reshape(N, n_group, E // n_group)
+    _, kept = jax.lax.top_k(jnp.max(groups, axis=-1), topk_group)
+    keep = jnp.any(kept[:, :, None] == jnp.arange(n_group)[None, None, :],
+                   axis=1)                                   # (N, n_group)
+    return jnp.where(keep[:, :, None], groups, -jnp.inf).reshape(N, E)
 
 
 def group(experts, held: int, offset: int = 0, valid=None):
@@ -98,14 +132,21 @@ def combine(grouped, order, weights, here):
 def held_experts(x, logits, bias, w_gate_up, w_down, *, top_k: int,
                  offset: int = 0, normalise: bool = True,
                  scale: float = 1.0, valid: Optional[jax.Array] = None,
-                 dtype=jnp.bfloat16):
+                 dtype=jnp.bfloat16, scoring: str = "sigmoid",
+                 n_group: int = 1, topk_group: int = 1):
     """The routed part of the layer that the held experts give, and the
     load: ``(y (N, d) float32, sizes (held,) int32)``. Scopes
     ``moe/dispatch`` (scores, top-k, sort, gather), ``moe/experts`` (the
     two ragged products), ``moe/combine``."""
     held = w_down.shape[0]
     with jax.named_scope("moe/dispatch"):
-        experts, weights = route(logits, bias, top_k, normalise, scale)
+        # the sigmoid router with no group limit is called as it always
+        # was: its program, and whatever stands in for it, stay the same
+        limits = {} if (scoring, n_group, topk_group) == ("sigmoid", 1, 1) \
+            else dict(scoring=scoring, n_group=n_group,
+                      topk_group=topk_group)
+        experts, weights = route(logits, bias, top_k, normalise, scale,
+                                 **limits)
         order, sizes, here = group(experts, held, offset, valid)
     with jax.named_scope("moe/experts"):
         grouped = grouped_swiglu(x, order, sizes, w_gate_up, w_down, top_k,

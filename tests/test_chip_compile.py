@@ -16,6 +16,7 @@ A kernel the compiler refuses keeps its test as ``xfail(strict=True)`` —
 the day it lowers, the marker must go (none is refused today).
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -712,3 +713,92 @@ def test_falcon_h1_engine_programs_compile_and_fit(one_chip, program):
     # head + 1.01 GB of cache; the cell's 6 layers compile to 13.55 GB +
     # 0.22-0.27 GB of temporaries (PERF.md section 6)
     assert total < 8.5e9, total
+
+
+# --------------------------------------------------------------------- #
+# DeepSeek-V2 at its published widths: the step and the dense-slot chunk
+# program of benchmark cell deepseek-v2.serve.ragdoc40
+# --------------------------------------------------------------------- #
+@pytest.mark.parametrize("program", ["step", "chunk"])
+def test_deepseek_v2_engine_programs_compile_and_fit(one_chip, monkeypatch,
+                                                     program):
+    """Hidden 5120, 128 heads of 128 + 64 rotary dims over a latent of
+    512, a query rank of 1536, the dense layer and one expert layer with
+    its 20 held experts of 1536 out of 160 router outputs and the shared
+    pair, an eighth of the vocabulary, 40 slots of 16384 — 2 of the
+    cell's 5 layers, to keep the compile short. On a TPU the step reads
+    the latent through ``ops/mla_decode.py``'s kernel and writes the
+    rotary key through ``ops/cache_write.py``'s, and the chunk program
+    expands and attends through ``ops/mla_prefill.py``'s; **the step
+    holds no expanded K/V and no score over the slot** (nothing of 128
+    heads beside 16384 positions), the chunk program **no score over the
+    whole context** (its scores stay in the kernel, a head and a block of
+    256 latents at a time), and no program copies or transposes a leaf
+    of the pool."""
+    from ray_lightning_tpu.models.deepseek_v2 import (DeepseekV2Config,
+                                                      DeepseekV2LM)
+    from ray_lightning_tpu.serve import engine as E
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    S = _spec(one_chip)
+    slots, rows, piece, positions = 40, 2, 2048, 16384
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(lambda a: S(a.shape, a.dtype), tree)
+
+    model = DeepseekV2LM(DeepseekV2Config(
+        num_hidden_layers=2, vocab_size=12800, experts_held=20,
+        max_seq_len=positions, decode=True))
+    init = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((slots, 1), jnp.int32)))
+    params, cache = abstract(init["params"]), abstract(init["cache"])
+    assert cache["layer_0_attn"]["latent"].shape == (slots, positions, 512)
+    assert cache["layer_0_attn"]["rope_key"].shape == (slots, 64, positions)
+    if program == "step":
+        compiled = jax.jit(
+            E._engine_step_impl, static_argnames=("model", "steps"),
+            donate_argnums=(2,)).lower(
+                model, params, cache, S((slots, 1), jnp.int32),
+                S((slots, 1), jnp.int32), S((slots,), jnp.bool_),
+                S((slots,), jnp.int32), S((slots,), jnp.float32),
+                S((slots,), jnp.int32), S((slots,), jnp.int32),
+                S((slots, 2), jnp.uint32), S((slots,), jnp.int32), None,
+                steps=1).compile()
+    else:
+        compiled = jax.jit(
+            E._chunk_prefill_dense_impl, static_argnames=("model",),
+            donate_argnums=(2,)).lower(
+                model, params, cache, S((rows, piece), jnp.int32),
+                S((rows,), jnp.int32), S((rows,), jnp.int32),
+                S((rows,), jnp.int32), S((rows,), jnp.bool_),
+                S((rows, 2), jnp.uint32), S((rows,), jnp.float32),
+                S((rows,), jnp.int32), S((rows,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "ragged-dot" in text
+    assert ("mla_decode" in text) == (program == "step")
+    assert ("mla_prefill" in text) == (program == "chunk")
+    leaf_sizes = {slots * positions * 512, slots * 64 * positions}
+    for line in text.splitlines():
+        shape = re.match(r"\w+\[([\d,]+)\]", line.split(" = ")[-1])
+        if shape is None:
+            continue
+        dims = [int(d) for d in shape.group(1).split(",")]
+        # an expanded key or value, or a score, of every head over the
+        # slot: 128 heads beside its 16384 positions
+        assert not (128 in dims and positions in dims), line[:200]
+        if " copy(" in line or " transpose(" in line:
+            assert int(np.prod(dims)) not in leaf_sizes, line[:200]
+    mem = compiled.memory_analysis()
+    # the latent leaves are donated and updated in place (the expert
+    # layer's load counter is replaced, not aliased)
+    pool = 2 * slots * positions * (512 + 64) * 2
+    assert mem.alias_size_in_bytes >= pool
+    # the step's temporaries are its logits and a layer's queries; the
+    # chunk's the grouped rows of the 4096 x 6 assignments, the dense
+    # layer's 2 x 2048 x 24576 products and the two rows taken out of the
+    # pool
+    assert mem.temp_size_in_bytes < (0.2e9 if program == "step" else 2e9)
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    # 2 layers: 1.49 GB of bf16 weights, 0.13 GB of vocabulary, 1.51 GB
+    # of cache
+    assert total < 6e9, total
